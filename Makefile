@@ -78,6 +78,7 @@ lint:
 	$(PY) tools/lint.py
 
 bench:
+	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 	$(PY) -m benchmarks.run
 
 verify: lint test-fast docs-check test-tp test-attn test-serving test-obs test-dit test-chaos audit

@@ -15,16 +15,13 @@ trajectory file.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
-import textwrap
 import time
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.launch.mesh import model_mesh
 
 KEY = jax.random.PRNGKey(0)
 BENCH_JSON = "BENCH_kernels.json"
@@ -189,17 +186,15 @@ def bench_kernels():
 
     # ------------------------------------------------------------------
     # Tensor-parallel fused MLP (QuantPlan mlp under a model-axis mesh):
-    # the shard_map pipeline at 1 vs 2 vs 4 shards.  Runs in a
-    # subprocess because the shard count needs forced host devices
-    # before jax initializes; on CPU the numbers time the interpreter +
+    # the shard_map pipeline at 1 vs 2 vs 4 shards, on this process's
+    # own devices (4 needed; on CPU the numbers time the interpreter +
     # collectives, but the 1-shard row doubles as the shard_map-overhead
-    # baseline against kernel_gated_mlp_fused.
+    # baseline against kernel_gated_mlp_fused).
     # ------------------------------------------------------------------
     rows.extend(bench_tp_mlp())
 
-    # The full-plan DiT block under a 1/2-way model mesh (same
-    # subprocess pattern; the paper's Design B partitions the DiT
-    # weight-stationary arrays the same way).
+    # The full-plan DiT block under a 1/2-way model mesh (the paper's
+    # Design B partitions the DiT weight-stationary arrays the same way).
     rows.extend(bench_tp_dit())
 
     # flash attention 2x256x4x32
@@ -321,113 +316,62 @@ def bench_dit_block():
              f"out-proj + 3 MLP); vs_unfused={t_unfused/t_fused:.2f}x")]
 
 
+def _time_tp(f, mesh, *args) -> float:
+    """Mean µs per call of ``f`` traced under ``mesh`` (3 timed calls)."""
+    from repro.parallel.context import sharding_context
+    with sharding_context(mesh):
+        jax.block_until_ready(f(*args))       # compile
+        t0 = time.perf_counter()
+        for _ in range(3):
+            r = f(*args)
+        jax.block_until_ready(r)
+    return (time.perf_counter() - t0) / 3 * 1e6
+
+
 def bench_tp_dit():
     """`dit_tp_s{1,2}` rows: the full-plan fused DiT block under a
-    model-axis mesh at 1 vs 2 shards (subprocess with forced host
-    devices, same pattern as `bench_tp_mlp`)."""
-    code = textwrap.dedent("""
-        import json, time
-        import jax, jax.numpy as jnp
-        from repro.configs import get_dit_config
-        from repro.models.dit import DiTModel, dit_block_apply
-        from repro.parallel.context import sharding_context
-        from repro.quant import kernel_mode
+    model-axis mesh at 1 vs 2 shards."""
+    from repro.configs import get_dit_config
+    from repro.models.dit import DiTModel, dit_block_apply
+    from repro.quant import kernel_mode
 
-        cfg = get_dit_config("dit-test")
-        model = DiTModel(cfg)
-        qparams = model.quantize(model.init(jax.random.PRNGKey(0)))
-        block = jax.tree.map(lambda a: a[0], qparams["blocks"])
-        B, T, d = 2, cfg.tokens, cfg.d_model
-        x = jax.random.normal(jax.random.PRNGKey(1), (B, T, d)) * 0.5
-        c = jax.random.normal(jax.random.PRNGKey(2), (B, d)) * 0.5
-        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-        out = {}
-        with kernel_mode(True):
-            for p in (1, 2):
-                mesh = jax.make_mesh((p,), ("model",))
-                f = jax.jit(lambda a, cc: dit_block_apply(
-                    block, a, cc, cfg, pos))
-                with sharding_context(mesh):
-                    jax.block_until_ready(f(x, c))      # compile
-                    t0 = time.perf_counter()
-                    for _ in range(3):
-                        r = f(x, c)
-                    jax.block_until_ready(r)
-                out[p] = (time.perf_counter() - t0) / 3 * 1e6
-        print("TPROWS " + json.dumps(out))
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env.setdefault("PYTHONPATH", "src")
-    try:
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=540,
-                              env=env)
-        line = next(ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("TPROWS "))
-        times = json.loads(line[len("TPROWS "):])
-    except Exception as e:                                  # noqa: BLE001
-        print(f"# dit_tp bench skipped: subprocess failed ({e})",
-              file=sys.stderr)
-        return []
-    t1 = times["1"]
-    return [(f"dit_tp_s{p}", times[str(p)],
+    cfg = get_dit_config("dit-test")
+    model = DiTModel(cfg)
+    qparams = model.quantize(model.init(KEY))
+    block = jax.tree.map(lambda a: a[0], qparams["blocks"])
+    B, T, d = 2, cfg.tokens, cfg.d_model
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, T, d)) * 0.5
+    c = jax.random.normal(jax.random.PRNGKey(2), (B, d)) * 0.5
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    times = {}
+    with kernel_mode(True):
+        for p in (1, 2):
+            f = jax.jit(lambda a, cc: dit_block_apply(block, a, cc, cfg, pos))
+            times[p] = _time_tp(f, model_mesh(p), x, c)
+    return [(f"dit_tp_s{p}", times[p],
              f"full-plan DiT block shard_map {p}-way model mesh"
-             + ("" if p == 1 else f"; vs_1shard={t1/times[str(p)]:.2f}x"))
+             + ("" if p == 1 else f"; vs_1shard={times[1]/times[p]:.2f}x"))
             for p in (1, 2)]
 
 
 def bench_tp_mlp():
     """`tp_fused_mlp` rows: the tensor-parallel fused MLP pipeline at
-    1/2/4 shards (subprocess with 4 forced host devices; the parent
-    process has already initialized jax with its own device count)."""
-    code = textwrap.dedent("""
-        import json, time
-        import jax, jax.numpy as jnp
-        from repro.models.layers import param_values, mlp_init
-        from repro.parallel.context import sharding_context
-        from repro.quant import quantize_mlp, quantized_mlp_apply
+    1/2/4 shards."""
+    from repro.models.layers import mlp_init, param_values
+    from repro.quant import quantize_mlp, quantized_mlp_apply
 
-        d, ff = 256, 512
-        qp = quantize_mlp(param_values(mlp_init(
-            jax.random.PRNGKey(0), d, ff, "geglu", dtype=jnp.float32)))
-        x = jax.random.normal(jax.random.PRNGKey(1), (256, d),
-                              jnp.float32) * 0.5
-        out = {}
-        for p in (1, 2, 4):
-            mesh = jax.make_mesh((p,), ("model",))
-            f = jax.jit(lambda a: quantized_mlp_apply(
-                qp, a, "geglu", use_kernel=True))
-            with sharding_context(mesh):
-                jax.block_until_ready(f(x))       # compile
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    r = f(x)
-                jax.block_until_ready(r)
-            out[p] = (time.perf_counter() - t0) / 3 * 1e6
-        print("TPROWS " + json.dumps(out))
-    """)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env.setdefault("PYTHONPATH", "src")
-    try:
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=540,
-                              env=env)
-        line = next(ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("TPROWS "))
-        times = json.loads(line[len("TPROWS "):])
-    except Exception as e:                                  # noqa: BLE001
-        # No fake rows: report nothing rather than a 0.0 "measurement"
-        # (a full run will prune the stale tp rows, which is honest —
-        # they were not measured this run).
-        print(f"# tp_fused_mlp bench skipped: subprocess failed ({e})",
-              file=sys.stderr)
-        return []
-    t1 = times["1"]
-    return [(f"kernel_tp_fused_mlp_s{p}", times[str(p)],
+    d, ff = 256, 512
+    qp = quantize_mlp(param_values(mlp_init(
+        jax.random.PRNGKey(0), d, ff, "geglu", dtype=jnp.float32)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (256, d), jnp.float32) * 0.5
+    times = {}
+    for p in (1, 2, 4):
+        f = jax.jit(lambda a: quantized_mlp_apply(qp, a, "geglu",
+                                                  use_kernel=True))
+        times[p] = _time_tp(f, model_mesh(p), x)
+    return [(f"kernel_tp_fused_mlp_s{p}", times[p],
              f"geglu 256x256x512 shard_map {p}-way model mesh"
-             + ("" if p == 1 else f"; vs_1shard={t1/times[str(p)]:.2f}x"))
+             + ("" if p == 1 else f"; vs_1shard={times[1]/times[p]:.2f}x"))
             for p in (1, 2, 4)]
 
 

@@ -2,6 +2,10 @@
 
     PYTHONPATH=src python -m benchmarks.run [--skip-kernels] [--json PATH]
 
+The tensor-parallel kernel rows need four devices in this process (on
+the CPU: ``XLA_FLAGS=--xla_force_host_platform_device_count=4``, as
+``make bench`` sets).
+
 Prints ``name,us_per_call,derived`` CSV rows (derived holds the
 claim-relevant numbers, ours vs the paper's) and **merges** the rows into
 ``BENCH_kernels.json`` (name -> µs + metadata) so the perf trajectory is
@@ -45,6 +49,8 @@ def main() -> None:
                          "(default: ./BENCH_kernels.json)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.bench_kernels import BENCH_JSON, write_bench_json
     from benchmarks.paper_tables import ALL_BENCHES
 

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.mesh import model_mesh
 from repro.models import build_model
 from repro.quant import QuantPlan
 from repro.serving import Request, ServingEngine
@@ -39,12 +40,7 @@ def main():
         if not int8:
             raise SystemExit("--tp shards the fused INT8 pipeline; "
                              "pass --int8 as well")
-        if jax.device_count() < tp:
-            raise SystemExit(
-                f"--tp {tp} needs {tp} devices but only "
-                f"{jax.device_count()} are visible; on CPU set "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count={tp}")
-        mesh = jax.make_mesh((tp,), ("model",))
+        mesh = model_mesh(tp)
     cfg = reduced_config(get_config("gemma-2b"))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -18,6 +18,7 @@ from collections import Counter
 import jax
 import jax.numpy as jnp
 
+from repro.launch.mesh import make_mesh
 from . import jaxpr_tools as jt
 from . import manifest, passes
 from .passes import Violation
@@ -126,7 +127,7 @@ def _mesh(tp: int):
             f"TP-{tp} audit needs {tp} devices "
             f"(run under XLA_FLAGS=--xla_force_host_platform_device_"
             f"count={tp}, as `make audit` does)")
-    return jax.make_mesh((tp,), (manifest.TP_AXIS,))
+    return make_mesh((tp,), (manifest.TP_AXIS,))
 
 
 def trace_lm_step(model, phase: str, paged: bool = False, tp: int = 1,

@@ -53,19 +53,24 @@ def unwrap(jx):
     return getattr(jx, "jaxpr", jx)
 
 
+def _kernel_debug_info(eqn):
+    return getattr(eqn.params.get("jaxpr"), "debug_info", None)
+
+
 def kernel_name(eqn) -> str:
-    """The Pallas kernel function name of a ``pallas_call`` eqn."""
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", None)
-    if not name:
-        name = str(info).split(" at ")[0]
-    return name
+    """The Pallas kernel name of a ``pallas_call`` eqn: the explicit
+    ``name=`` when one was given, else the kernel function's name."""
+    name = eqn.params.get("name")
+    if name:
+        return name
+    info = _kernel_debug_info(eqn)
+    return getattr(info, "func_name", None) or eqn.primitive.name
 
 
 def src_info(eqn) -> str:
     """Best-effort ``kernel_fn at file:line`` string for reports."""
-    info = eqn.params.get("name_and_src_info")
-    return str(info) if info is not None else eqn.primitive.name
+    info = _kernel_debug_info(eqn)
+    return getattr(info, "func_src_info", None) or kernel_name(eqn)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,36 +104,31 @@ class PallasSite:
         return sum(b.nbytes for b in self.blocks) + self.scratch_bytes
 
 
+def _block_dim(d) -> int:
+    """One block dim as an int: plain ints, ``Blocked(block_size=n)``,
+    and squeezed/mapped dims (``None`` / ``Squeezed``) as 1."""
+    if isinstance(d, int):
+        return d
+    size = getattr(d, "block_size", None)
+    return size if isinstance(size, int) else 1
+
+
 def _block_infos(eqn) -> tuple:
     gm = eqn.params.get("grid_mapping")
     out = []
     for bm in getattr(gm, "block_mappings", ()) or ():
-        sds = getattr(bm, "array_shape_dtype", None)
-        raw = tuple(getattr(bm, "block_shape", ()) or ())
-        shape = tuple(d if isinstance(d, int) else 1 for d in raw)
+        aval = bm.array_aval
         out.append(BlockInfo(
-            block_shape=shape,
-            array_shape=tuple(getattr(sds, "shape", ())),
-            dtype=getattr(sds, "dtype", jnp.float32)))
+            block_shape=tuple(_block_dim(d) for d in bm.block_shape),
+            array_shape=tuple(aval.shape),
+            dtype=aval.dtype))
     return tuple(out)
 
 
 def _scratch_bytes(eqn) -> int:
     gm = eqn.params.get("grid_mapping")
-    n = getattr(gm, "num_scratch_operands", 0) or 0
-    if not n:
-        return 0
-    kjx = unwrap(eqn.params.get("jaxpr"))
-    if kjx is None or not hasattr(kjx, "invars"):
-        return 0
-    total = 0
-    for v in kjx.invars[-n:]:
-        aval = getattr(v, "aval", None)
-        shape = getattr(aval, "shape", ())
-        dtype = getattr(aval, "dtype", None)
-        if dtype is not None:
-            total += math.prod(shape) * jnp.dtype(dtype).itemsize
-    return total
+    return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
+               for a in getattr(gm, "scratch_avals", ()) or ())
 
 
 def pallas_sites(jx) -> list:

@@ -87,12 +87,9 @@ CORE_N = 256
 MAX_FUSED_QUANT_N = 8192
 
 # Above this many input columns the quantize-in-kernel GEMM variant
-# (``cim_gemm_int8_fused_qin``) would hold a full f32 activation row
-# block in VMEM; fall back to a separate quantize dispatch.  At the
-# default block_m=256 a (256, 4096) f32 block is 4 MiB — double-buffered
-# that's half of a ~16 MiB VMEM before weights/outputs, so this is the
-# practical ceiling (like MAX_FUSED_QUANT_N, an interpret-mode guess
-# pending on-TPU validation).
+# (``cim_gemm_int8_fused_qin``) holds a full-K activation row block plus
+# its f32 copy in VMEM and has to shrink its row block to fit; wider K
+# falls back to a separate quantize dispatch.
 MAX_FUSED_QUANT_K = 4096
 
 
@@ -103,20 +100,29 @@ def _fit(dim: int, block: int) -> int:
     return max(1, block)
 
 
-# Static per-dispatch VMEM ceiling the block pickers respect: blocks +
-# scratch stay at or below half of the 16 MiB TPU VMEM so the scheduler
-# keeps double-buffering headroom.  The jaxpr auditor
-# (repro.analysis, `make audit`) enforces the full budget on every
-# traced step, so a picker that busts this shows up before it ships.
-VMEM_TARGET_BYTES = 8 * 1024 * 1024
+# Mosaic's scoped-VMEM limit per kernel (16 MiB on v5e).  Pallas
+# double-buffers every BlockSpec'd input and output, so the block
+# pickers count each block twice, plus scratch and the kernel body's
+# f32 temporaries, against VMEM_BUDGET_BYTES — 3/4 of the limit, the
+# rest left to the compiler's own scratch.  Blocks are counted at their
+# VMEM tile size: a [rows, 1] f32 column occupies 128 lanes, a [1, N]
+# row 8 sublanes.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+VMEM_BUDGET_BYTES = SCOPED_VMEM_BYTES * 3 // 4
+_COL_BYTES = 2 * 128 * 4      # one double-buffered [rows, 1] f32 row
+_ROW_BYTES = 2 * 8 * 4        # one double-buffered [1, N] f32 column
 
 
-def _fit_rows(m_dim: int, block_m: int, row_bytes: int) -> int:
-    """Shrink ``block_m`` (floor 8 rows) until ``block_m * row_bytes``
-    fits the VMEM target, then fit it to divide ``m_dim``.  Row-wise
-    kernels are bit-identical under any row blocking, so this only
-    trades dispatch-grid granularity for footprint."""
-    while block_m > 8 and block_m * row_bytes > VMEM_TARGET_BYTES:
+def _fit_rows(m_dim: int, block_m: int, row_bytes: int,
+              fixed_bytes: int = 0) -> int:
+    """Shrink ``block_m`` (floor 8 rows) until ``fixed_bytes + block_m
+    * row_bytes`` fits the VMEM budget, then fit it to divide ``m_dim``.
+    ``row_bytes`` is everything one row of the block costs (double
+    buffers and temporaries included).  Row-wise kernels are
+    bit-identical under any row blocking, so this only trades
+    dispatch-grid granularity for footprint."""
+    while block_m > 8 and fixed_bytes + block_m * row_bytes \
+            > VMEM_BUDGET_BYTES:
         block_m //= 2
     return _fit(m_dim, block_m)
 
@@ -130,16 +136,24 @@ def _fit_qout_blocks(M: int, K: int, N: int, block_m: int, block_k: int,
     ``block_m`` (rows in flight, floor 8).  ``n_mats`` is the number of
     weight matrices streamed (2 for the gated kernel), which also sets
     the int32 scratch accumulator count."""
+    def fixed(bk: int) -> int:
+        # double-buffered weight and scale (+ bias) blocks
+        return n_mats * (2 * bk * N + _ROW_BYTES * N) \
+            + (_ROW_BYTES * N if has_bias else 0)
+
+    def per_row(bk: int) -> int:
+        # x block + x_scale in, int8 row + scale out (all doubled),
+        # int32 accumulators, f32 epilogue temporaries
+        return 2 * bk * x_bytes + _COL_BYTES + 2 * N + _COL_BYTES \
+            + n_mats * 4 * N + (n_mats + 1) * 4 * N
+
     def fp(bm: int, bk: int) -> int:
-        fixed = n_mats * bk * N + n_mats * 4 * N + (4 * N if has_bias
-                                                   else 0)
-        per_row = bk * x_bytes + 4 + N + 4 + n_mats * 4 * N
-        return fixed + bm * per_row
-    while block_k > CORE_K and fp(block_m, block_k) > VMEM_TARGET_BYTES:
+        return fixed(bk) + bm * per_row(bk)
+
+    while block_k > CORE_K and fp(block_m, block_k) > VMEM_BUDGET_BYTES:
         block_k //= 2
-    while block_m > 8 and fp(block_m, block_k) > VMEM_TARGET_BYTES:
-        block_m //= 2
-    return _fit(M, block_m), _fit(K, block_k)
+    block_m = _fit_rows(M, block_m, per_row(block_k), fixed(block_k))
+    return block_m, _fit(K, block_k)
 
 
 def _apply_activation(x: jax.Array, activation: str | None) -> jax.Array:
@@ -157,7 +171,7 @@ def _apply_activation(x: jax.Array, activation: str | None) -> jax.Array:
 def _rowquant(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Row absmax int8 quantization of an f32 tile: (q, scale [rows, 1])."""
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True) + 1e-12
-    scale = amax / 127.0
+    scale = amax * (1.0 / 127.0)
     q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
@@ -236,9 +250,12 @@ def quantize_rows_int8(x: jax.Array, block_m: int = 256,
     extent sits in one block (the absmax is a row reduction).
     """
     M, K = x.shape
-    # full-K row blocks: cap rows in flight so huge hidden dims (the
-    # standalone requant for d_ff > MAX_FUSED_QUANT_N) stay in budget
-    block_m = _fit_rows(M, block_m, K * (x.dtype.itemsize + 1) + 4)
+    # full-K row blocks: cap rows in flight so wide rows stay in budget —
+    # double-buffered input and int8 output, the scale column, and the
+    # body's two f32 row temporaries (the widened row and its quotient)
+    block_m = _fit_rows(M, block_m,
+                        2 * K * (x.dtype.itemsize + 1) + 2 * _COL_BYTES
+                        + 2 * 4 * K)
     grid = (M // block_m,)
     return pl.pallas_call(
         _rowquant_kernel,
@@ -445,8 +462,16 @@ def cim_gemm_int8_fused_qin(x: jax.Array, w: jax.Array, w_scale: jax.Array,
     assert K == K2, (K, K2)
     assert w_scale.shape == (1, N), w_scale.shape
 
-    block_m = _fit(M, block_m)
     block_n = _fit(N, block_n)
+    out_bytes = jnp.dtype(out_dtype).itemsize
+    # double-buffered weight/scale/bias blocks; per row the full-K
+    # activation block (doubled) with its f32 copy and int8 quantization,
+    # the output (+ residual) block, and the f32/int32 epilogue values
+    fixed = 2 * K * block_n + _ROW_BYTES * block_n * (1 + (bias is not None))
+    per_row = 2 * K * x.dtype.itemsize + 5 * K + 2 * block_n * out_bytes \
+        + (2 * block_n * residual.dtype.itemsize if residual is not None
+           else 0) + 3 * 4 * block_n
+    block_m = _fit_rows(M, block_m, per_row, fixed)
     grid = (M // block_m, N // block_n)
 
     in_specs = [

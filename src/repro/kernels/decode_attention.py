@@ -30,7 +30,12 @@ Three additions over the plain streaming kernel:
   renormalization terms are exact identities (``exp(0) == 1``), so it
   matches the single-dispatch kernel bit-for-bit.
 
-Grid: (B, KH, kv_blocks) — kv innermost (splitkv: (B, KH, NS, blocks)).
+Grid: (B, kv_blocks) — kv innermost (splitkv: (B, NS, blocks)).  One
+grid step takes every KV head of a KV block: Mosaic tiles the last two
+dims of a block, so a block may not take one head of the KV-head axis
+(second-to-last in [.., KH, D]).  A static loop over the heads runs the
+online-softmax step per head; positions ride as a [.., 1, block_k] view
+for the same reason.
 q:   [B, KH, G, D]    (GQA groups factored)
 k,v: [B, S, KH, D]    (bf16/f32, or int8 with [B, S, KH] f32 scales)
 pos: [B, S] int32     (slot positions; 2**30 = empty)
@@ -77,12 +82,13 @@ def _block_keep(pos: jax.Array, q_pos: jax.Array, window,
     return _keep_blocks(pos.reshape(B, S // block_k, block_k), q_pos, window)
 
 
-def _attend_block(q, k, v, kpos, qpos, m_ref, l_ref, acc_ref, *,
+def _attend_block(q, k, v, kpos, qpos, m_prev, l_prev, acc_prev, *,
                   scale: float, window, k_scale=None, v_scale=None):
-    """One online-softmax step over a KV block, updating (m, l, acc).
+    """One online-softmax step of one head over a KV block.
 
-    q [G, D]; k/v [block_k, D]; kpos [block_k]; scales [block_k] or None
-    (int8 K/V — dequantized here, scales factored out of the dots).
+    q [G, D]; k/v [block_k, D]; kpos [block_k]; running state m/l
+    [G, 1], acc [G, D]; scales [block_k] or None (int8 K/V — dequantized
+    here, scales factored out of the dots).  Returns the new (m, l, acc).
     """
     quantized = k_scale is not None
     if quantized:
@@ -99,91 +105,125 @@ def _attend_block(q, k, v, kpos, qpos, m_ref, l_ref, acc_ref, *,
         ok &= kpos[None, :] > qpos - window
     s = jnp.where(ok, s, NEG_INF)          # [G, block_k]
 
-    m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-    m_ref[...] = m_new
+    l_new = l_prev * corr + jnp.sum(p, -1, keepdims=True)
     if quantized:
         p = p * v_scale[None, :]
     pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
+    return m_new, l_new, acc_prev * corr + pv
+
+
+def _attend_heads(q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, qpos,
+                  m_ref, l_ref, acc_ref, *, scale: float, window):
+    """The online-softmax step for every KV head of one KV block.
+
+    Blocks: q [1, KH, G, D]; k/v [1, block_k, KH, D]; pos [1, 1,
+    block_k]; scales [1, block_k, KH] or None.  Scratch m/l [KH, G, 1],
+    acc [KH, G, D].
+    """
+    kpos = pos_ref[0, 0]
+    for h in range(q_ref.shape[1]):
+        m, l, acc = _attend_block(
+            q_ref[0, h], k_ref[0, :, h, :], v_ref[0, :, h, :], kpos, qpos,
+            m_ref[h], l_ref[h], acc_ref[h], scale=scale, window=window,
+            k_scale=None if ks_ref is None else ks_ref[0, :, h],
+            v_scale=None if vs_ref is None else vs_ref[0, :, h])
+        m_ref[h] = m
+        l_ref[h] = l
+        acc_ref[h] = acc
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _state_scratch(KH: int, G: int, D: int) -> list:
+    return [pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, D), jnp.float32)]
+
+
+def _split_refs(refs, quantized: bool, n_out: int):
+    """(q, k, v, pos, k_scale|None, v_scale|None, outs, m, l, acc)."""
+    q_ref, k_ref, v_ref, pos_ref = refs[:4]
+    ks_ref = vs_ref = None
+    rest = refs[4:]
+    if quantized:
+        ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
+    outs, (m_ref, l_ref, acc_ref) = rest[:n_out], rest[n_out:]
+    return q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, outs, m_ref, \
+        l_ref, acc_ref
 
 
 def _decode_kernel(qpos_ref, skip_ref, *refs, scale: float, window,
                    n_kv_steps: int, quantized: bool):
-    if quantized:
-        (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        ks_ref = vs_ref = None
-    b, ki = pl.program_id(0), pl.program_id(2)
+    (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, (o_ref,),
+     m_ref, l_ref, acc_ref) = _split_refs(refs, quantized, 1)
+    b, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     def _step():
-        _attend_block(
-            q_ref[0, 0], k_ref[0][:, 0], v_ref[0][:, 0], pos_ref[0],
-            qpos_ref[b], m_ref, l_ref, acc_ref, scale=scale, window=window,
-            k_scale=None if ks_ref is None else ks_ref[0][:, 0],
-            v_scale=None if vs_ref is None else vs_ref[0][:, 0])
+        _attend_heads(q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref,
+                      qpos_ref[b], m_ref, l_ref, acc_ref, scale=scale,
+                      window=window)
 
     pl.when(skip_ref[b, ki] > 0)(_step)
 
     @pl.when(ki == n_kv_steps - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _kv_specs(block_k: int, G: int, D: int, quantized: bool,
+def _kv_specs(block_k: int, KH: int, G: int, D: int, quantized: bool,
               nk_per_split: int | None = None):
     """in_specs shared by the single-dispatch and split partial kernels.
 
     Index maps take the grid indices plus the two prefetched scalar refs
-    (q_pos, skip).  With ``nk_per_split`` the grid is (B, KH, NS, ki)
-    and the maps fold the (split, block) pair into the global kv-block
-    index.  int8 K/V blocks stream through VMEM; their per-slot scale
-    rows ride along as skinny [block_k, 1] f32 blocks.
+    (q_pos, skip).  With ``nk_per_split`` the grid is (B, NS, ki) and
+    the maps fold the (split, block) pair into the global kv-block
+    index.  Every block spans all KV heads; int8 K/V blocks stream
+    through VMEM with their [block_k, KH] f32 scale blocks alongside.
     """
     if nk_per_split is None:
-        def blk(b, h, ki, qp, sk):
+        def blk(ki):
             return ki
 
-        def im_q(b, h, ki, qp, sk):
-            return (b, h, 0, 0)
+        def im_q(b, ki, qp, sk):
+            return (b, 0, 0, 0)
     else:
-        def blk(b, h, si, ki, qp, sk):
+        def blk(si, ki):
             return si * nk_per_split + ki
 
-        def im_q(b, h, si, ki, qp, sk):
-            return (b, h, 0, 0)
+        def im_q(b, si, ki, qp, sk):
+            return (b, 0, 0, 0)
 
-    def im_kv(b, h, *rest):
-        return (b, blk(b, h, *rest), h, 0)
+    def im_kv(b, *rest):
+        return (b, blk(*rest[:-2]), 0, 0)
 
-    def im_pos(b, h, *rest):
-        return (b, blk(b, h, *rest))
+    def im_pos(b, *rest):
+        return (b, 0, blk(*rest[:-2]))
 
-    def im_scale(b, h, *rest):
-        return (b, blk(b, h, *rest), h)
+    def im_scale(b, *rest):
+        return (b, blk(*rest[:-2]), 0)
 
     specs = [
-        pl.BlockSpec((1, 1, G, D), im_q),
-        pl.BlockSpec((1, block_k, 1, D), im_kv),
-        pl.BlockSpec((1, block_k, 1, D), im_kv),
-        pl.BlockSpec((1, block_k), im_pos),
+        pl.BlockSpec((1, KH, G, D), im_q),
+        pl.BlockSpec((1, block_k, KH, D), im_kv),
+        pl.BlockSpec((1, block_k, KH, D), im_kv),
+        pl.BlockSpec((1, 1, block_k), im_pos),
     ]
     if quantized:
-        specs += [pl.BlockSpec((1, block_k, 1), im_scale),
-                  pl.BlockSpec((1, block_k, 1), im_scale)]
+        specs += [pl.BlockSpec((1, block_k, KH), im_scale),
+                  pl.BlockSpec((1, block_k, KH), im_scale)]
     return specs
 
 
@@ -199,7 +239,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``k_scale``/``v_scale`` [B, S, KH] f32 turn on the int8-KV path
     (K/V must then be int8).  S must be a multiple of ``block_k`` —
-    ``ops.decode_attention`` pads with the empty-slot sentinel.
+    ``ops.decode_attention`` pads with the empty-slot sentinel.  On the
+    TPU ``block_k`` must be a multiple of 128 or all of S (the position
+    block's lane dim).
     """
     B, KH, G, D = q.shape
     S = k.shape[1]
@@ -212,17 +254,14 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, nk),
-        in_specs=_kv_specs(block_k, G, D, quantized),
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ki, qp, sk: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        grid=(B, nk),
+        in_specs=_kv_specs(block_k, KH, G, D, quantized),
+        out_specs=pl.BlockSpec((1, KH, G, D),
+                               lambda b, ki, qp, sk: (b, 0, 0, 0)),
+        scratch_shapes=_state_scratch(KH, G, D),
     )
-    operands = (q, k, v, pos) + ((k_scale, v_scale) if quantized else ())
+    operands = (q, k, v, pos.reshape(B, 1, S)) \
+        + ((k_scale, v_scale) if quantized else ())
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, window=window,
                           n_kv_steps=nk, quantized=quantized),
@@ -237,51 +276,42 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 def _decode_splitkv_kernel(qpos_ref, skip_ref, *refs, scale: float, window,
                            n_kv_steps: int, quantized: bool):
-    """Partial kernel: grid (B, KH, NS, blocks-per-split); each split
-    walks its KV slice with the same online-softmax step and emits its
-    raw (o, m, l) state — no division, the combine renormalizes."""
-    if quantized:
-        (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref,
-         o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, pos_ref,
-         o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    b, si, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    """Partial kernel: grid (B, NS, blocks-per-split); each split walks
+    its KV slice with the same online-softmax step and emits its raw
+    (o, m, l) state — no division, the combine renormalizes."""
+    (q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref, (o_ref, mo_ref, lo_ref),
+     m_ref, l_ref, acc_ref) = _split_refs(refs, quantized, 3)
+    b, si, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     def _step():
-        _attend_block(
-            q_ref[0, 0], k_ref[0][:, 0], v_ref[0][:, 0], pos_ref[0],
-            qpos_ref[b], m_ref, l_ref, acc_ref, scale=scale, window=window,
-            k_scale=None if ks_ref is None else ks_ref[0][:, 0],
-            v_scale=None if vs_ref is None else vs_ref[0][:, 0])
+        _attend_heads(q_ref, k_ref, v_ref, pos_ref, ks_ref, vs_ref,
+                      qpos_ref[b], m_ref, l_ref, acc_ref, scale=scale,
+                      window=window)
 
     pl.when(skip_ref[b, si * n_kv_steps + ki] > 0)(_step)
 
     @pl.when(ki == n_kv_steps - 1)
     def _finish():
-        o_ref[0, 0, 0] = acc_ref[...]
-        mo_ref[0, 0, 0] = m_ref[...]
-        lo_ref[0, 0, 0] = l_ref[...]
+        o_ref[0, 0] = acc_ref[...]
+        mo_ref[0, 0] = m_ref[...]
+        lo_ref[0, 0] = l_ref[...]
 
 
 def _combine_kernel(o_ref, m_ref, l_ref, out_ref):
-    """Combine dispatch: grid (B, KH); renormalize the NS partial states
+    """Combine dispatch: grid (B,); renormalize the NS partial states
     against the global running max and emit the final output row."""
-    o = o_ref[0, 0]                        # [NS, G, D] f32
-    m = m_ref[0, 0]                        # [NS, G, 1] f32
-    l = l_ref[0, 0]
-    m_g = jnp.max(m, axis=0)               # [G, 1]
-    w = jnp.exp(m - m_g[None])             # [NS, G, 1]
+    o = o_ref[0]                           # [NS, KH, G, D] f32
+    m = m_ref[0]                           # [NS, KH, G, 1] f32
+    l = l_ref[0]
+    m_g = jnp.max(m, axis=0)               # [KH, G, 1]
+    w = jnp.exp(m - m_g[None])             # [NS, KH, G, 1]
     l_g = jnp.sum(l * w, axis=0)
-    acc = jnp.sum(o * w, axis=0)           # [G, D]
-    out_ref[0, 0] = (acc / jnp.maximum(l_g, 1e-30)).astype(out_ref.dtype)
+    acc = jnp.sum(o * w, axis=0)           # [KH, G, D]
+    out_ref[0] = (acc / jnp.maximum(l_g, 1e-30)).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
@@ -308,46 +338,46 @@ def decode_attention_splitkv(q: jax.Array, k: jax.Array, v: jax.Array,
     quantized = k_scale is not None
     skip = _block_keep(pos, q_pos, window, block_k)
 
-    def im_part(b, h, si, ki, qp, sk):
-        return (b, h, si, 0, 0)
+    def im_part(b, si, ki, qp, sk):
+        return (b, si, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, n_splits, nk_s),
-        in_specs=_kv_specs(block_k, G, D, quantized, nk_per_split=nk_s),
+        grid=(B, n_splits, nk_s),
+        in_specs=_kv_specs(block_k, KH, G, D, quantized, nk_per_split=nk_s),
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G, D), im_part),
-            pl.BlockSpec((1, 1, 1, G, 1), im_part),
-            pl.BlockSpec((1, 1, 1, G, 1), im_part),
+            pl.BlockSpec((1, 1, KH, G, D), im_part),
+            pl.BlockSpec((1, 1, KH, G, 1), im_part),
+            pl.BlockSpec((1, 1, KH, G, 1), im_part),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        scratch_shapes=_state_scratch(KH, G, D),
     )
-    operands = (q, k, v, pos) + ((k_scale, v_scale) if quantized else ())
+    operands = (q, k, v, pos.reshape(B, 1, S)) \
+        + ((k_scale, v_scale) if quantized else ())
     o_part, m_part, l_part = pl.pallas_call(
         functools.partial(_decode_splitkv_kernel, scale=scale, window=window,
                           n_kv_steps=nk_s, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, KH, n_splits, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, KH, n_splits, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, KH, n_splits, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, KH, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, KH, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, KH, G, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q_pos.astype(jnp.int32), skip, *operands)
 
+    def im_all(b):
+        return (b, 0, 0, 0, 0)
+
     return pl.pallas_call(
         _combine_kernel,
-        grid=(B, KH),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, n_splits, G, D), lambda b, h: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, n_splits, G, 1), lambda b, h: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, n_splits, G, 1), lambda b, h: (b, h, 0, 0, 0)),
+            pl.BlockSpec((1, n_splits, KH, G, D), im_all),
+            pl.BlockSpec((1, n_splits, KH, G, 1), im_all),
+            pl.BlockSpec((1, n_splits, KH, G, 1), im_all),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, G, D), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
     )(o_part, m_part, l_part)
@@ -383,54 +413,50 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     self-mask); q_pos: [B].
 
     ``k_scale_pages``/``v_scale_pages`` [NB, bs, KH] f32 turn on the
-    int8-KV path (pools must then be int8).  Grid (B, KH, nb): block ki
-    of row b streams pool block ``block_tables[b, ki]`` via the
-    scalar-prefetched table, runs the ring kernel's online-softmax step,
-    and the skip list (computed from the gathered per-block positions)
-    elides fully-masked blocks exactly as on the ring path.
+    int8-KV path (pools must then be int8).  Grid (B, nb): block ki of
+    row b streams pool block ``block_tables[b, ki]`` (all KV heads) via
+    the scalar-prefetched table, runs the ring kernel's online-softmax
+    step, and the skip list (computed from the gathered per-block
+    positions) elides fully-masked blocks exactly as on the ring path.
     """
     B, KH, G, D = q.shape
-    bs = pos_pages.shape[1]
+    NB, bs = pos_pages.shape
     nb = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
     quantized = k_scale_pages is not None
     bt = block_tables.astype(jnp.int32)
     skip = _keep_blocks(pos_pages[bt], q_pos, window)
 
-    def im_q(b, h, ki, qp, sk, bt):
-        return (b, h, 0, 0)
+    def im_q(b, ki, qp, sk, bt):
+        return (b, 0, 0, 0)
 
-    def im_kv(b, h, ki, qp, sk, bt):
-        return (bt[b, ki], 0, h, 0)
+    def im_kv(b, ki, qp, sk, bt):
+        return (bt[b, ki], 0, 0, 0)
 
-    def im_pos(b, h, ki, qp, sk, bt):
-        return (bt[b, ki], 0)
+    def im_pos(b, ki, qp, sk, bt):
+        return (bt[b, ki], 0, 0)
 
-    def im_scale(b, h, ki, qp, sk, bt):
-        return (bt[b, ki], 0, h)
+    def im_scale(b, ki, qp, sk, bt):
+        return (bt[b, ki], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), im_q),
-        pl.BlockSpec((1, bs, 1, D), im_kv),
-        pl.BlockSpec((1, bs, 1, D), im_kv),
-        pl.BlockSpec((1, bs), im_pos),
+        pl.BlockSpec((1, KH, G, D), im_q),
+        pl.BlockSpec((1, bs, KH, D), im_kv),
+        pl.BlockSpec((1, bs, KH, D), im_kv),
+        pl.BlockSpec((1, 1, bs), im_pos),
     ]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, 1), im_scale),
-                     pl.BlockSpec((1, bs, 1), im_scale)]
+        in_specs += [pl.BlockSpec((1, bs, KH), im_scale),
+                     pl.BlockSpec((1, bs, KH), im_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KH, nb),
+        grid=(B, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D), im_q),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KH, G, D), im_q),
+        scratch_shapes=_state_scratch(KH, G, D),
     )
-    operands = (q, k_pages, v_pages, pos_pages) \
+    operands = (q, k_pages, v_pages, pos_pages.reshape(NB, 1, bs)) \
         + ((k_scale_pages, v_scale_pages) if quantized else ())
     return pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale, window=window,

@@ -46,7 +46,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int):
 def quantize_weights_int8(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-output-channel symmetric int8: w [K, N] -> (w_q, scale [N])."""
     amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=0) + 1e-12
-    scale = amax / 127.0
+    scale = amax * (1.0 / 127.0)  # not / 127.0: see quantize_rows_int8_ref
     w_q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale), -127,
                    127).astype(jnp.int8)
     return w_q, scale.astype(jnp.float32)
@@ -62,7 +62,7 @@ def cim_quantized_matmul(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
     interpret = _on_cpu() if interpret is None else interpret
     x32 = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True) + 1e-12
-    x_scale = amax / 127.0
+    x_scale = amax * (1.0 / 127.0)
     x_q = jnp.clip(jnp.round(x32 / x_scale), -127, 127).astype(jnp.int8)
 
     x_q, M = _pad_to(x_q, 0, 256)

@@ -17,7 +17,9 @@ def quantize_rows_int8_ref(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Dynamic per-row symmetric int8: x [M, K] -> (q, scale [M, 1])."""
     x32 = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True) + 1e-12
-    scale = amax / 127.0
+    # a multiply: under jit XLA turns ``/ 127.0`` into this multiply, so
+    # only this form gives the same scale eagerly and jitted
+    scale = amax * (1.0 / 127.0)
     q = jnp.clip(jnp.round(x32 / scale), -127, 127).astype(jnp.int8)
     return q, scale
 

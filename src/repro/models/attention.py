@@ -366,7 +366,7 @@ def _quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-(batch, position, head) symmetric int8 (paper's INT8 CIM mode
     applied to the decode state).  x: [B, S, KH, D]."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = (amax / 127.0 + 1e-12).astype(jnp.float32)
+    scale = (amax * (1.0 / 127.0) + 1e-12).astype(jnp.float32)
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
     return q.astype(jnp.int8), scale[..., 0]
 

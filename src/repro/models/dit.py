@@ -162,10 +162,13 @@ def adaln_apply(params: dict, c: jax.Array, n_chunks: int) -> list[jax.Array]:
     """
     h = jax.nn.silu(c.astype(jnp.float32))
     w = params["kernel"]
-    if isinstance(w, QuantizedLinear):
-        out = quantized_matmul(h, w, use_kernel=None, bias=params["bias"])
-    else:
-        out = h.astype(w.dtype) @ w + params["bias"]
+    # the named scope tags the GEMM (and its kernel) in compiled HLO
+    with jax.named_scope("adaln"):
+        if isinstance(w, QuantizedLinear):
+            out = quantized_matmul(h, w, use_kernel=None,
+                                   bias=params["bias"])
+        else:
+            out = h.astype(w.dtype) @ w + params["bias"]
     out = out.astype(jnp.float32)
     return jnp.split(out, n_chunks, axis=-1)
 

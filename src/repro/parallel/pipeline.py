@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_loop(stage_fn: Callable, stage_params, micro_x: jax.Array,
@@ -75,14 +74,14 @@ def pipeline_apply(mesh: Mesh, axis_name: str, stage_fn: Callable,
     micro = x.reshape(microbatches, B // microbatches, *x.shape[1:])
 
     param_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p, mx: gpipe_loop(
             lambda pp, xx: stage_fn(jax.tree.map(lambda a: a[0], pp), xx),
             p, mx, axis_name, n_stages=mesh.shape[axis_name]),
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(stacked_params, micro)
     return out.reshape(B, *out.shape[2:])

@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops as kops
@@ -91,7 +90,7 @@ def _global_rowquant(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     x32 = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
     amax = jax.lax.pmax(amax, TP_AXIS) + 1e-12
-    scale = amax / 127.0
+    scale = amax * (1.0 / 127.0)
     q = jnp.clip(jnp.round(x32 / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
@@ -107,9 +106,9 @@ def matmul_column(mesh: Mesh, x2: jax.Array, w_q: jax.Array,
                                                    activation=activation)
         return kref.fused_matmul_ref(xl, wl, sl, activation=activation)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(None, TP_AXIS), P(TP_AXIS)),
-                     out_specs=P(None, TP_AXIS), check_rep=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(None, TP_AXIS), P(TP_AXIS)),
+                         out_specs=P(None, TP_AXIS), check_vma=False)(
                          x2, w_q, w_scale)
 
 
@@ -134,8 +133,8 @@ def matmul_row(mesh: Mesh, x2: jax.Array, w_q: jax.Array,
     if residual is not None:
         in_specs.append(P())
         args.append(residual)
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(), check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def mlp(mesh: Mesh, x2: jax.Array, qparams: dict, activation: str,
@@ -179,8 +178,8 @@ def mlp(mesh: Mesh, x2: jax.Array, qparams: dict, activation: str,
     if residual is not None:
         in_specs.append(P())
         args.append(residual)
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(), check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 def grouped_moe(mesh: Mesh, x: jax.Array, qparams: dict, activation: str,
@@ -217,8 +216,8 @@ def grouped_moe(mesh: Mesh, x: jax.Array, qparams: dict, activation: str,
     if expert_counts is not None:
         in_specs.append(espec)
         args.append(expert_counts)
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=espec, check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=espec, check_vma=False)(*args)
 
 
 def decode_attn(mesh: Mesh, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -253,8 +252,8 @@ def decode_attn(mesh: Mesh, q: jax.Array, k: jax.Array, v: jax.Array,
     if k_scale is not None:
         in_specs += [P(None, None, TP_AXIS), P(None, None, TP_AXIS)]
         args += [k_scale, v_scale]
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(None, TP_AXIS), check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(None, TP_AXIS), check_vma=False)(*args)
 
 
 def decode_attn_paged(mesh: Mesh, q: jax.Array, k_pages: jax.Array,
@@ -292,5 +291,5 @@ def decode_attn_paged(mesh: Mesh, q: jax.Array, k_pages: jax.Array,
     if k_scale_pages is not None:
         in_specs += [P(None, None, TP_AXIS), P(None, None, TP_AXIS)]
         args += [k_scale_pages, v_scale_pages]
-    return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=P(None, TP_AXIS), check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=P(None, TP_AXIS), check_vma=False)(*args)

@@ -31,6 +31,7 @@ tests/test_reliability.py).
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -629,7 +630,9 @@ class PagedServingEngine(ServingEngine):
                                   num_blocks=self.num_blocks,
                                   kv_dtype=self.kv_dtype, mesh=self.mesh,
                                   rules=self.rules)
-        return self.paged.cache
+        # the engine owns the pools from here: every step donates them
+        cache, self.paged.cache = self.paged.cache, None
+        return cache
 
     def _tables(self):
         return jnp.asarray(self.paged.tables)
@@ -655,7 +658,9 @@ class PagedServingEngine(ServingEngine):
                 return a
             return jax.tree_util.tree_map_with_path(fix, cache)
 
-        @jax.jit
+        # Every step donates the pool tree (``cache``): XLA updates the
+        # pools in place instead of holding two copies across the step.
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def prefill_chunk(params, cache, tokens, slot, length, offset,
                           tables):
             """Prefill one chunk of one request into slot ``slot``.
@@ -672,8 +677,13 @@ class PagedServingEngine(ServingEngine):
 
             def take(path, a):
                 name = str(path[-1]) if path else ""
-                if per_row(name):
-                    return jax.lax.dynamic_slice_in_dim(a, slot, 1, 1)
+                if not per_row(name):
+                    return a
+                a = jax.lax.dynamic_slice_in_dim(a, slot, 1, 1)
+                if "index" in name:
+                    # the chunk writes from ``offset``: a reused slot
+                    # still holds its previous sequence's write index
+                    a = jnp.full_like(a, offset)
                 return a
 
             sub = jax.tree_util.tree_map_with_path(take, cache)
@@ -693,7 +703,7 @@ class PagedServingEngine(ServingEngine):
             cache = jax.tree_util.tree_map_with_path(put, cache, sub)
             return logits[0, -1], cache
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def decode_all(params, cache, last_tokens, decode_mask, tables):
             """One decode step for every slot in ``decode_mask``.
 
@@ -718,7 +728,7 @@ class PagedServingEngine(ServingEngine):
                     params, {"inputs": last_tokens[:, None]}, cache)
             return logits[:, 0], cache
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def scrub(cache, blocks):
             """Reset freed blocks' positions to the empty sentinel so a
             reallocated block never exposes its previous sequence's

@@ -122,7 +122,9 @@ class PagedKVCache:
     an argument of every jitted step, so the device tree never holds a
     stale copy.  ``cache`` is the device pool tree from
     ``Model.init_paged_cache`` (per-layer pools, int8 + scale
-    side-tensors when ``kv_dtype == "int8"``).
+    side-tensors when ``kv_dtype == "int8"``); the serving engine takes
+    it over (and clears the attribute), because its jitted steps donate
+    the pools.
     """
 
     def __init__(self, model, n_slots: int, max_len: int, block_size: int,

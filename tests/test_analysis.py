@@ -9,13 +9,13 @@ can't fail can't prove anything.
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import jaxpr_tools as jt
 from repro.analysis import manifest, passes
 from repro.kernels import ops
 from repro.kernels.cim_gemm import cim_gemm_int8, quantize_rows_int8
+from repro.launch.mesh import make_mesh
 from repro.quant import QuantPlan, kernel_mode, quantize_moe_experts, \
     quantized_moe_apply
 
@@ -41,7 +41,7 @@ def _decode_jaxpr(m, qparams, kv_len=16):
 
 
 def _model_mesh():
-    return jax.make_mesh((1,), (manifest.TP_AXIS,))
+    return make_mesh((1,), (manifest.TP_AXIS,))
 
 
 # ---------------------------------------------------------------------------
@@ -127,8 +127,8 @@ class TestDtypeFlowMutations:
             def body(a, b):
                 acc = ops.cim_int8_gemm_acc(a, b, interpret=True)
                 return jax.lax.psum(acc, manifest.TP_AXIS)
-            return shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=P(), check_rep=False)(a, b)
+            return jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False)(a, b)
 
         jaxpr = jax.make_jaxpr(sharded)(xq, wq)
         assert passes.dtype_flow_audit(jaxpr) == []
@@ -146,8 +146,8 @@ class TestDtypeFlowMutations:
                 acc = ops.cim_int8_gemm_acc(a, b, interpret=True)
                 return jax.lax.psum(acc.astype(jnp.float32),
                                     manifest.TP_AXIS)
-            return shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=P(), check_rep=False)(a, b)
+            return jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False)(a, b)
 
         out = passes.dtype_flow_audit(jax.make_jaxpr(sharded)(xq, wq))
         assert ("dtype_flow", "int32_escape") in _codes(out), out
@@ -188,8 +188,8 @@ class TestCollectiveMutations:
         mesh = _model_mesh()
         x = jnp.ones((4, 8))
         return jax.make_jaxpr(
-            lambda a: shard_map(body, mesh=mesh, in_specs=(P(),),
-                                out_specs=P(), check_rep=False)(a))(x)
+            lambda a: jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                    out_specs=P(), check_vma=False)(a))(x)
 
     def test_all_gather_flagged(self):
         """An all-gather on the model axis re-opens the data-movement

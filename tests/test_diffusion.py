@@ -422,6 +422,7 @@ class TestBridgeDiT:
 
 _TP_SETUP = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_dit_config
     from repro.models.dit import DiTModel
     from repro.parallel.context import sharding_context
@@ -451,14 +452,14 @@ class TestDiTTensorParallel:
                 ref = jax.jit(lambda p,a,b,c: m.forward(p,a,b,c))(
                     qp, x, t, y)
                 for p in (1, 2, 4):
-                    mesh = jax.make_mesh((p,), ("model",))
+                    mesh = make_mesh((p,), ("model",))
                     f = jax.jit(lambda pp,a,b,c: m.forward(pp,a,b,c))
                     with sharding_context(mesh):
                         got = f(qp, x, t, y)
                     assert (np.asarray(got) == np.asarray(ref)).all(), p
                     print(f"shards{p} OK")
                 # mesh-placed weights (q + scale co-sharded) too
-                mesh = jax.make_mesh((2,), ("model",))
+                mesh = make_mesh((2,), ("model",))
                 qps = m.quantize(params, mesh=mesh)
                 f = jax.jit(lambda pp,a,b,c: m.forward(pp,a,b,c))
                 with sharding_context(mesh):
@@ -478,7 +479,7 @@ class TestDiTTensorParallel:
             with kernel_mode(True):
                 ref = jax.jit(lambda p,a,b,c: m.forward(p,a,b,c))(
                     qp, x, t, y)
-                mesh = jax.make_mesh((2,), ("model",))
+                mesh = make_mesh((2,), ("model",))
                 f = jax.jit(lambda pp,a,b,c: m.forward(pp,a,b,c))
                 with sharding_context(mesh):
                     got = f(qp, x, t, y)

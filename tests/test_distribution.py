@@ -11,15 +11,8 @@ from repro.parallel.sharding import batch_sharding, resolve_spec
 
 from conftest import run_forced_devices_subprocess as _run_subprocess
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)              # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # jax 0.4.x
-
-
-MESH_1POD = _abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestResolveSpec:
@@ -95,8 +88,9 @@ class TestPipelineParallel:
     def test_gpipe_matches_sequential(self):
         out = _run_subprocess("""
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.parallel.pipeline import pipeline_apply
-            mesh = jax.make_mesh((4,), ("pod",))
+            mesh = make_mesh((4,), ("pod",))
             ws = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 16)) * 0.3
             stage_fn = lambda w, x: jnp.tanh(x @ w["w"])
             x = jax.random.normal(jax.random.PRNGKey(1), (8, 16))

@@ -520,6 +520,7 @@ class TestPagedDecodeKernel:
         devices, so it runs in a subprocess like test_tp)."""
         out = _run_subprocess(textwrap.dedent("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.kernels import ops as kops
             from repro.quant import tp as _tp
 
@@ -546,7 +547,7 @@ class TestPagedDecodeKernel:
             ref = np.asarray(kops.decode_attention_paged(
                 q, kp, vp, pp, bt, q_pos))
             for p in (1, 2):
-                mesh = jax.make_mesh((p,), ("model",))
+                mesh = make_mesh((p,), ("model",))
                 out = np.asarray(_tp.decode_attn_paged(
                     mesh, q, kp, vp, pp, bt, q_pos))
                 assert (out == ref).all(), p
@@ -832,6 +833,101 @@ class TestTrafficHarness:
             assert metrics["preemptions"] == 0
             gens.append({r.uid: r.generated for _, r in wl})
         assert gens[0] == gens[1]
+
+
+# ---------------------------------------------------------------------------
+# serving launcher (repro.launch.serve) and its set-up
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def restore_cache_dir():
+    """Put the compilation-cache setting back after a test that turns
+    the cache on."""
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+class TestServeLauncher:
+    ARGS = ["--arch", "deepseek-67b", "--int8", "--requests", "3",
+            "--slots", "2", "--prompt-len", "6", "20", "--max-new", "4",
+            "--prefill-chunk", "8"]
+
+    @pytest.fixture(scope="class")
+    def ds_model(self):
+        return build_model(reduced_config(get_config("deepseek-67b")))
+
+    def test_init_quantized_equals_quantize_of_init(self, ds_model):
+        """Layer-by-layer init + quantize builds exactly the tree that
+        quantizing the whole bf16 init builds."""
+        plan = QuantPlan.full()
+        want = ds_model.quantize(ds_model.init(KEY), plan)
+        got = ds_model.init_quantized(KEY, plan)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            assert (np.asarray(a) == np.asarray(b)).all()
+
+    def test_memory_budget_counts_what_is_built(self, ds_model):
+        from repro.launch import serve
+        args = serve.parse_args(self.ARGS)
+        plan = QuantPlan.full()
+        b = serve.memory_budget(ds_model, plan, args.slots,
+                                serve.max_len_for(args))
+        params = ds_model.init_quantized(KEY, plan)
+        eng = serve.build_engine(args, ds_model, params, plan)
+        nbytes = serve._nbytes
+        layers = [v for k, v in params.items() if k.startswith("group_")]
+        assert b["layers_bytes"] == nbytes(layers)
+        assert b["head_bytes"] + b["layers_bytes"] == nbytes(params)
+        assert b["kv_bytes"] == nbytes(eng.cache)
+        assert b["layers_fp_bytes"] > b["layers_bytes"]
+
+    def test_main_serves_reduced_config_on_cpu(self, ds_model,
+                                               restore_cache_dir):
+        """On the CPU the launcher serves ``reduced_config`` on the
+        paged engine; every greedy request gets its tokens."""
+        from repro.launch import serve
+        res = serve.main(self.ARGS)
+        assert res["tokens_out"] == 3 * 4
+        assert len(res["ttft_s"]) == 3
+        assert res["decode_step_s"] and res["prefill_step_s"]
+
+    def test_paged_steps_donate_the_pools(self, ds_model):
+        """A step consumes the pool tree it was given: the engine holds
+        one copy of the KV pools, not two."""
+        from repro.launch import serve
+        args = serve.parse_args(self.ARGS)
+        plan = QuantPlan.full()
+        eng = serve.build_engine(args, ds_model,
+                                 ds_model.init_quantized(KEY, plan), plan)
+        assert eng.paged.cache is None        # the engine owns the pools
+        pools = [a for g in eng.cache.values() for k, a in g.items()
+                 if "pages" in k]
+        eng.submit(Request(uid=0, prompt=np.ones(5, np.int32),
+                           max_new_tokens=2))
+        eng.run_until_done(max_iters=20)
+        assert len(pools) == 5 and all(a.is_deleted() for a in pools)
+
+
+class TestCompileCache:
+    def test_env_dir_wins_and_nothing_is_set(self, monkeypatch,
+                                             restore_cache_dir):
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_default_is_fixed_repo_dir(self, monkeypatch, restore_cache_dir):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = Path(__file__).resolve().parents[1]
+        want = str(repo / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ from repro import optim
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config, reduced_config
 from repro.data import DataConfig, Pipeline, for_model
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.training import (StragglerPolicy, Trainer, TrainerConfig,
                             simple_train_step)
@@ -83,7 +84,7 @@ class TestCheckpointer:
         """Elastic re-mesh: restore onto explicit (1x1) mesh shardings."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         _, m, params = small_model
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
         ck = Checkpointer(tmp_path, async_writes=False)
         ck.save(5, {"params": params})

@@ -25,6 +25,7 @@ from conftest import run_forced_devices_subprocess as _run_subprocess
 # context is active at trace time.
 _SETUP = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.models.layers import param_values, mlp_init
     from repro.models.attention import attention_init
     from repro.parallel.context import sharding_context
@@ -36,7 +37,7 @@ _SETUP = textwrap.dedent("""
     def check(name, mk_ref, mk_tp, *args):
         ref = jax.jit(mk_ref())(*args)
         for p in (1, 2, 4):
-            mesh = jax.make_mesh((p,), ("model",))
+            mesh = make_mesh((p,), ("model",))
             f = jax.jit(mk_tp())          # fresh jit per mesh: the
             with sharding_context(mesh):  # context is read at trace time
                 out = f(*args)
@@ -160,7 +161,7 @@ class TestTPParity:
             x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, d)) * 0.5
             ref = jax.jit(lambda a: quantized_mlp_apply(
                 qp, a, "geglu", use_kernel=False))(x)
-            mesh = jax.make_mesh((8,), ("model",))
+            mesh = make_mesh((8,), ("model",))
             f = jax.jit(lambda a: quantized_mlp_apply(
                 qp, a, "geglu", use_kernel=False))
             with sharding_context(mesh):
@@ -202,6 +203,7 @@ class TestTPEngine:
         on the model axis."""
         out = _run_subprocess("""
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_config, reduced_config
             from repro.models import build_model
             from repro.quant import QuantPlan
@@ -226,7 +228,7 @@ class TestTPEngine:
                 return [r.generated for r in reqs], eng
 
             base, _ = run(None)
-            mesh = jax.make_mesh((2,), ("model",))
+            mesh = make_mesh((2,), ("model",))
             gens, eng = run(mesh)
             assert gens == base, (gens, base)
             up = eng.params["group_0"]["mlp"]["up"]
@@ -248,6 +250,7 @@ class TestTPEngine:
         out = _run_subprocess("""
             import dataclasses
             import jax, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_config, reduced_config
             from repro.models import build_model
             from repro.quant import QuantPlan
@@ -276,7 +279,7 @@ class TestTPEngine:
             base, eng0 = run(None)
             assert eng0.kv_dtype == "int8"      # plan covers attn_kv
             for p in (2, 4):
-                mesh = jax.make_mesh((p,), ("model",))
+                mesh = make_mesh((p,), ("model",))
                 gens, eng = run(mesh)
                 assert gens == base, (p, gens, base)
                 ck = eng.cache["group_0"]["k"]
