@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracing import span, step_span, trace_gc
 from repro.serving.lifecycle import (EngineStallError, LifecycleMixin,
                                      RequestStatus)
 from .sampler import DEFAULT_SCHEDULE, DiffusionSchedule, sample
@@ -62,7 +63,6 @@ class DiffusionStats:
     denoise_steps: int = 0              # model evaluations (per batch)
     images_out: int = 0
     batch_occupancy: list = field(default_factory=list)
-    wall_s: float = 0.0
     # reliability counters (monotone, mirrors serving.EngineStats)
     submitted: int = 0
     completed: int = 0
@@ -98,9 +98,11 @@ class DiffusionEngine:
         self.queue: deque[ImageRequest] = deque()
         self.stats = DiffusionStats()
         self._samplers: dict = {}
+        self._step_num = 0              # engine.step's step_num
         self.obs = obs
         if obs is not None:
             obs.bind_dit_engine(self)
+        trace_gc()
 
     # ------------------------------------------------------------------
     def _mesh_ctx(self):
@@ -208,52 +210,59 @@ class DiffusionEngine:
 
     def step(self) -> None:
         """Run one batch: pop up to ``batch_size`` queued requests that
-        share the head-of-queue trace key, pad, sample, deliver."""
+        share the head-of-queue trace key, pad, sample, deliver; inside
+        one ``engine.step`` profiler span split by phase
+        (:mod:`repro.obs.tracing`)."""
+        self._step_num += 1
+        with step_span(self._step_num):
+            self._step()
+
+    def _step(self) -> None:
         self._purge_expired(self._clock())
         if not self.queue:
             return
-        head = self.queue[0]
-        key = (head.num_steps, head.cfg_scale, head.method)
-        batch: list[ImageRequest] = []
-        rest: deque[ImageRequest] = deque()
-        while self.queue and len(batch) < self.batch:
-            r = self.queue.popleft()
-            if (r.num_steps, r.cfg_scale, r.method) == key:
-                r.status = RequestStatus.ACTIVE
-                batch.append(r)
-            else:
-                rest.append(r)
-        self.queue = rest + self.queue   # preserve order of the skipped
-
-        t0 = time.perf_counter()
-        pad = self.batch - len(batch)
-        rows = batch + [batch[-1]] * pad          # padded rows discarded
-        noise = jnp.stack([self._noise(r) for r in rows])
-        labels = jnp.asarray([r.label for r in rows], jnp.int32)
-        lat = np.asarray(self._sampler(*key)(self.params, noise, labels))
-        if self.fault_hook is not None:
-            out = self.fault_hook("denoise", lat)
-            if out is not None:
-                lat = np.asarray(out)
-        if self.obs is not None:
-            # CFG stacks conditional + null rows into one 2B batch, so
-            # a guided image costs two model evaluations per step
-            evals = head.num_steps * (2 if head.cfg_scale > 0.0 else 1)
-            self.obs.on_denoise_batch(batch, evals, self._clock())
-        delivered = 0
-        for i, r in enumerate(batch):
-            if self.health_checks and not np.isfinite(lat[i]).all():
-                self._finish(r, RequestStatus.FAILED,
-                             "non-finite latents")
-                continue
-            r.latents = lat[i]
-            self._finish(r, RequestStatus.OK)
-            delivered += 1
-        self.stats.batches += 1
-        self.stats.denoise_steps += head.num_steps
-        self.stats.images_out += delivered
-        self.stats.batch_occupancy.append(len(batch) / self.batch)
-        self.stats.wall_s += time.perf_counter() - t0
+        with span("engine.dit.prepare"):
+            head = self.queue[0]
+            key = (head.num_steps, head.cfg_scale, head.method)
+            batch: list[ImageRequest] = []
+            rest: deque[ImageRequest] = deque()
+            while self.queue and len(batch) < self.batch:
+                r = self.queue.popleft()
+                if (r.num_steps, r.cfg_scale, r.method) == key:
+                    r.status = RequestStatus.ACTIVE
+                    batch.append(r)
+                else:
+                    rest.append(r)
+            self.queue = rest + self.queue   # preserve order of the skipped
+            pad = self.batch - len(batch)
+            rows = batch + [batch[-1]] * pad          # padded rows discarded
+            noise = jnp.stack([self._noise(r) for r in rows])
+            labels = jnp.asarray([r.label for r in rows], jnp.int32)
+        with span("engine.dit.fetch"):
+            lat = np.asarray(self._sampler(*key)(self.params, noise, labels))
+        with span("engine.dit.deliver"):
+            if self.fault_hook is not None:
+                out = self.fault_hook("denoise", lat)
+                if out is not None:
+                    lat = np.asarray(out)
+            if self.obs is not None:
+                # CFG stacks conditional + null rows into one 2B batch, so
+                # a guided image costs two model evaluations per step
+                evals = head.num_steps * (2 if head.cfg_scale > 0.0 else 1)
+                self.obs.on_denoise_batch(batch, evals, self._clock())
+            delivered = 0
+            for i, r in enumerate(batch):
+                if self.health_checks and not np.isfinite(lat[i]).all():
+                    self._finish(r, RequestStatus.FAILED,
+                                 "non-finite latents")
+                    continue
+                r.latents = lat[i]
+                self._finish(r, RequestStatus.OK)
+                delivered += 1
+            self.stats.batches += 1
+            self.stats.denoise_steps += head.num_steps
+            self.stats.images_out += delivered
+            self.stats.batch_occupancy.append(len(batch) / self.batch)
 
     def pending(self) -> int:
         return len(self.queue)

@@ -1,4 +1,5 @@
-"""Per-request tracing: structured event log + request span records.
+"""Per-request tracing: structured event log + request span records,
+and the engines' profiler spans.
 
 Every request served by an instrumented engine leaves two artifacts:
 
@@ -37,12 +38,85 @@ denoise_batch      evals, batch (diffusion engine)
 request_end        status, error, tokens, joules, span close — exactly
                    once per request
 =================  ======================================================
+
+Profiler spans stand apart from both, per engine and not per request:
+named host intervals on the profiler's clock, written with
+``jax.profiler.TraceAnnotation`` so they land in the same trace as the
+device ops and name what the host does while the device waits.  The
+paged and diffusion engines emit them.  They are always on and
+independent of ``obs=``: with no profiler attached each costs about a
+microsecond, and nothing inside a jitted function changes.  Phases nest inside their
+``engine.step``; ``engine.release`` and ``engine.gc`` may nest inside
+any other span.
+
+=========================  ============================================
+span                       what it covers
+=========================  ============================================
+engine.step                one ``step()`` call; ``step_num`` is the
+                           engine's own step count
+engine.admit               paged: deadline expiry and slot admission
+engine.prefill.dispatch    paged, per chunk: padding, block growth,
+                           chunk and table uploads, the jitted call (a
+                           chunk whose growth fails its request stops
+                           before the call)
+engine.prefill.fetch       paged: the final chunk's logits to the host
+engine.prefill.sample      paged: that row's health check and sample
+engine.decode.dispatch     paged: block growth for the decode batch,
+                           mask and table uploads, the jitted call
+engine.decode.fetch        paged: the batch's logits to the host (the
+                           host blocked on the device, then the copy)
+engine.decode.sample       paged: health checks, sampling and per-row
+                           bookkeeping of the batch
+engine.release             paged: a slot's block release and scrub
+                           dispatch
+engine.dit.prepare         diffusion: batch, noise and labels
+engine.dit.fetch           diffusion: sampler dispatch and the latents
+                           to the host
+engine.dit.deliver         diffusion: health checks and delivery
+engine.gc                  one garbage collection (:func:`trace_gc`);
+                           ``generation`` is the collected generation
+=========================  ============================================
 """
 from __future__ import annotations
 
+import gc
 import json
 from dataclasses import dataclass, field
 from typing import Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+
+def span(name: str, **meta) -> TraceAnnotation:
+    """A profiler span named ``name``; ``meta`` rides along as stats."""
+    return TraceAnnotation(name, **meta)
+
+
+def step_span(n: int) -> StepTraceAnnotation:
+    """The ``engine.step`` span of step ``n`` (the profiler's step
+    marker, so trace viewers group the host and device work by step)."""
+    return StepTraceAnnotation("engine.step", step_num=n)
+
+
+_gc_open: list = []
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    # a collection starts and stops on one thread, and collections never
+    # overlap, so one open span at a time
+    if phase == "start":
+        ann = TraceAnnotation("engine.gc", generation=info["generation"])
+        ann.__enter__()
+        _gc_open.append(ann)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def trace_gc() -> None:
+    """Put every garbage collection in an ``engine.gc`` span, once per
+    process however often it is called."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 class EventLog:

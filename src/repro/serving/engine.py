@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracing import span, step_span, trace_gc
+
 from .lifecycle import (EngineStallError, LifecycleMixin,
                         RequestStatus)
 from .paged_cache import PoolExhausted
@@ -63,6 +65,7 @@ class Request(LifecycleMixin):
     status: RequestStatus = RequestStatus.QUEUED
     error: Optional[str] = None
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None      # engine clock; first admission
     first_token_at: Optional[float] = None   # engine clock; TTFT source
     finished_at: Optional[float] = None      # engine clock; span close
 
@@ -415,6 +418,7 @@ class ServingEngine:
                 toks = np.concatenate(
                     [req.prompt,
                      np.full(pad, req.prompt[-1])]).astype(np.int32)
+                req.admitted_at = now
                 if self.obs is not None:
                     self.obs.on_admit(req, slot, now)
                 logits, self.cache = self._prefill_one(
@@ -619,8 +623,10 @@ class PagedServingEngine(ServingEngine):
         self.slot_fill: dict[int, list] = {}
         self._slot_seq = np.zeros(n_slots, np.int64)   # admission order
         self._admit_order = 0
+        self._step_num = 0              # engine.step's step_num
         super().__init__(model, params, n_slots=n_slots, max_len=max_len,
                          prefill_bucket=prefill_bucket, **kw)
+        trace_gc()
 
     # -- cache ---------------------------------------------------------
     def _init_cache(self):
@@ -791,13 +797,14 @@ class PagedServingEngine(ServingEngine):
         return used
 
     def _clear_slot(self, slot: int) -> None:
-        freed = self.paged.release(slot)
-        if freed:
-            pad = np.full(self._scrub_width, self._scrub_pad, np.int32)
-            pad[:len(freed)] = freed
-            self.cache = self._scrub(self.cache, jnp.asarray(pad))
-        self.slot_req[slot] = None
-        self.slot_fill.pop(slot, None)
+        with span("engine.release"):
+            freed = self.paged.release(slot)
+            if freed:
+                pad = np.full(self._scrub_width, self._scrub_pad, np.int32)
+                pad[:len(freed)] = freed
+                self.cache = self._scrub(self.cache, jnp.asarray(pad))
+            self.slot_req[slot] = None
+            self.slot_fill.pop(slot, None)
 
     def _admit(self, now: float) -> None:
         """Assign queued requests to free slots (FIFO, no reordering).
@@ -826,6 +833,8 @@ class PagedServingEngine(ServingEngine):
                     return
                 self.queue.popleft()
                 req.status = RequestStatus.ACTIVE
+                if req.admitted_at is None:    # a resume keeps the first
+                    req.admitted_at = now
                 self.slot_req[slot] = req
                 self.slot_fill[slot] = [toks, 0]
                 self._slot_seq[slot] = self._admit_order
@@ -889,15 +898,23 @@ class PagedServingEngine(ServingEngine):
     # -- the engine loop -----------------------------------------------
     def step(self) -> None:
         """One engine iteration: expire + admit + one prefill chunk per
-        filling slot + one batched decode for every running slot."""
+        filling slot + one batched decode for every running slot, inside
+        one ``engine.step`` profiler span split by phase
+        (:mod:`repro.obs.tracing`)."""
+        self._step_num += 1
+        with step_span(self._step_num):
+            self._step()
+
+    def _step(self) -> None:
         now = self._clock()
-        for slot in self._active():
-            req = self.slot_req[slot]
-            if req.expired(now):
-                self._finish(req, RequestStatus.TIMED_OUT,
-                             "deadline expired mid-decode")
-                self._clear_slot(slot)
-        self._admit(now)
+        with span("engine.admit"):
+            for slot in self._active():
+                req = self.slot_req[slot]
+                if req.expired(now):
+                    self._finish(req, RequestStatus.TIMED_OUT,
+                                 "deadline expired mid-decode")
+                    self._clear_slot(slot)
+            self._admit(now)
 
         # chunked prefill: one chunk per filling slot, interleaved with
         # decode below (a long prompt never stalls running sequences)
@@ -907,16 +924,18 @@ class PagedServingEngine(ServingEngine):
                 continue
             req = self.slot_req[slot]
             toks, off = self.slot_fill[slot]
-            chunk = toks[off:off + C]
-            valid = len(chunk)
-            if valid < C:                        # pad by repeating
-                chunk = np.concatenate(
-                    [chunk, np.full(C - valid, chunk[-1])]).astype(np.int32)
-            if not self._ensure(slot, off + valid):
-                continue
-            logits, self.cache = self._prefill_chunk_fn(
-                self.params, self.cache, jnp.asarray(chunk), slot,
-                valid, off, self._tables())
+            with span("engine.prefill.dispatch"):
+                chunk = toks[off:off + C]
+                valid = len(chunk)
+                if valid < C:                    # pad by repeating
+                    chunk = np.concatenate(
+                        [chunk, np.full(C - valid, chunk[-1])]
+                    ).astype(np.int32)
+                if not self._ensure(slot, off + valid):
+                    continue
+                logits, self.cache = self._prefill_chunk_fn(
+                    self.params, self.cache, jnp.asarray(chunk), slot,
+                    valid, off, self._tables())
             self.stats.prefill_chunks += 1
             if self.obs is not None:
                 # the dispatch computes C padded query positions at
@@ -931,64 +950,73 @@ class PagedServingEngine(ServingEngine):
             self.stats.prefills += 1
             if self.obs is not None:
                 self.obs.on_prefill_done(req, now)
-            logits = self._apply_fault_hook("prefill", np.asarray(logits))
-            if self.health_checks and not np.isfinite(logits).all():
-                self.stats.prefill_failures += 1
-                self._finish(req, RequestStatus.FAILED,
-                             "non-finite prefill logits")
-                self._clear_slot(slot)
-                continue
-            tok = self._sample(req, logits, len(req.generated))
-            req.generated.append(tok)
-            if self.obs is not None:
-                self.obs.on_token(req, tok, now)
-            if req.first_token_at is None:
-                req.first_token_at = self._clock()
-                if self.obs is not None:
-                    self.obs.on_first_token(req, req.first_token_at)
-            del self.slot_fill[slot]
-            self.slot_pos[slot] = len(toks)
-            self.slot_last[slot] = tok
-            self._maybe_finish(slot, req, tok)
-
-        # batched decode over every slot that is past prefill
-        ok = []
-        for slot in self._active():
-            if slot in self.slot_fill or self.slot_req[slot] is None:
-                continue
-            if self._ensure(slot, int(self.slot_pos[slot]) + 1):
-                ok.append(slot)
-        ok = [s for s in ok if self.slot_req[s] is not None
-              and s not in self.slot_fill]       # drop preempted victims
-        if ok:
-            self.stats.batch_occupancy.append(len(ok) / self.n_slots)
-            mask = np.zeros(self.n_slots, bool)
-            mask[ok] = True
-            logits, self.cache = self._decode_masked(
-                self.params, self.cache, jnp.asarray(self.slot_last),
-                jnp.asarray(mask), self._tables())
-            logits = self._apply_fault_hook("decode", np.asarray(logits))
-            self.stats.decode_steps += 1
-            if self.obs is not None:
-                self.obs.on_decode_rows(
-                    [(self.slot_req[s], int(self.slot_pos[s]) + 1)
-                     for s in ok], now)
-            for slot in ok:
-                req = self.slot_req[slot]
-                if self.health_checks \
-                        and not np.isfinite(logits[slot]).all():
+            with span("engine.prefill.fetch"):
+                logits = np.asarray(logits)
+            with span("engine.prefill.sample"):
+                logits = self._apply_fault_hook("prefill", logits)
+                if self.health_checks and not np.isfinite(logits).all():
+                    self.stats.prefill_failures += 1
                     self._finish(req, RequestStatus.FAILED,
-                                 "non-finite logits")
+                                 "non-finite prefill logits")
                     self._clear_slot(slot)
                     continue
-                tok = self._sample(req, logits[slot], len(req.generated))
+                tok = self._sample(req, logits, len(req.generated))
                 req.generated.append(tok)
-                self.stats.tokens_out += 1
                 if self.obs is not None:
                     self.obs.on_token(req, tok, now)
+                if req.first_token_at is None:
+                    req.first_token_at = self._clock()
+                    if self.obs is not None:
+                        self.obs.on_first_token(req, req.first_token_at)
+                del self.slot_fill[slot]
+                self.slot_pos[slot] = len(toks)
                 self.slot_last[slot] = tok
-                self.slot_pos[slot] += 1
                 self._maybe_finish(slot, req, tok)
+
+        # batched decode over every slot that is past prefill
+        with span("engine.decode.dispatch"):
+            ok = []
+            for slot in self._active():
+                if slot in self.slot_fill or self.slot_req[slot] is None:
+                    continue
+                if self._ensure(slot, int(self.slot_pos[slot]) + 1):
+                    ok.append(slot)
+            ok = [s for s in ok if self.slot_req[s] is not None
+                  and s not in self.slot_fill]   # drop preempted victims
+            if ok:
+                self.stats.batch_occupancy.append(len(ok) / self.n_slots)
+                mask = np.zeros(self.n_slots, bool)
+                mask[ok] = True
+                logits, self.cache = self._decode_masked(
+                    self.params, self.cache, jnp.asarray(self.slot_last),
+                    jnp.asarray(mask), self._tables())
+        if ok:
+            with span("engine.decode.fetch"):
+                logits = np.asarray(logits)
+            self.stats.decode_steps += 1
+            with span("engine.decode.sample"):
+                logits = self._apply_fault_hook("decode", logits)
+                if self.obs is not None:
+                    self.obs.on_decode_rows(
+                        [(self.slot_req[s], int(self.slot_pos[s]) + 1)
+                         for s in ok], now)
+                for slot in ok:
+                    req = self.slot_req[slot]
+                    if self.health_checks \
+                            and not np.isfinite(logits[slot]).all():
+                        self._finish(req, RequestStatus.FAILED,
+                                     "non-finite logits")
+                        self._clear_slot(slot)
+                        continue
+                    tok = self._sample(req, logits[slot],
+                                       len(req.generated))
+                    req.generated.append(tok)
+                    self.stats.tokens_out += 1
+                    if self.obs is not None:
+                        self.obs.on_token(req, tok, now)
+                    self.slot_last[slot] = tok
+                    self.slot_pos[slot] += 1
+                    self._maybe_finish(slot, req, tok)
         self.stats.cache_utilization.append(self.paged.utilization())
         if self.obs is not None:
             self.obs.on_kv_state(

@@ -243,6 +243,16 @@ class TestTracing:
         with pytest.raises(RuntimeError, match="already closed"):
             t.close("failed", "again", 6.0)
 
+    def test_trace_gc_installs_one_callback(self):
+        import gc
+
+        from repro.obs import tracing
+        tracing.trace_gc()
+        tracing.trace_gc()
+        assert gc.callbacks.count(tracing._gc_span) == 1
+        gc.collect()                 # no profiler attached: near-free
+        assert tracing._gc_open == []
+
     def test_trace_latency_properties(self):
         t = RequestTrace(uid=0, submitted_at=2.0)
         assert t.queue_wait is None and t.ttft is None and t.itl is None
@@ -445,6 +455,37 @@ class TestEngineObservability:
         uid = pre[0]["uid"]
         admits = obs.events.select("admit", uid=uid)
         assert any(e["resumed"] for e in admits)
+
+    def test_admitted_at_is_the_first_admission(self, small_model):
+        """``Request.admitted_at`` is the engine clock at the first
+        admission, before the first token, and a resume after preemption
+        leaves it where it was."""
+        cfg, m, params = small_model
+        obs = Observability()
+        eng, reqs = self._serve(m, params, cfg, obs, n=6, seed=1,
+                                num_blocks=9, n_slots=4, out=6,
+                                max_prompt=20)
+        assert eng.stats.preemptions >= 1
+        for r in reqs:
+            admits = obs.events.select("admit", uid=r.uid)
+            assert r.admitted_at == admits[0]["ts"] \
+                == obs.traces[r.uid].admitted_at
+            assert r.submitted_at <= r.admitted_at <= r.first_token_at
+        resumed = {e["uid"] for e in obs.events.select("admit")
+                   if e["resumed"]}
+        assert resumed
+        for r in reqs:
+            if r.uid in resumed:
+                admits = obs.events.select("admit", uid=r.uid)
+                assert r.admitted_at == admits[0]["ts"] < admits[-1]["ts"]
+        # the ring engine admits and prefills at once
+        tick = [0]
+        ring = ServingEngine(m, params, n_slots=1, max_len=32,
+                             prefill_bucket=4, clock=lambda: float(tick[0]))
+        a, b = _requests(cfg, 2, out=3)
+        with kernel_mode(False):
+            _drive(ring, [a, b], tick)
+        assert a.admitted_at == 0.0 < b.admitted_at <= b.first_token_at
 
     def test_pool_exhaustion_fails_and_counts(self, small_model):
         cfg, m, params = small_model
