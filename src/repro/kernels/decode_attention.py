@@ -387,15 +387,18 @@ def decode_attention_splitkv(q: jax.Array, k: jax.Array, v: jax.Array,
 # Paged (block-table) flash-decode: same online-softmax walk, but each KV
 # block is fetched through a scalar-prefetched per-sequence block table
 # instead of a contiguous slice — the kernel side of the paged KV cache
-# (serving/paged_cache.py).  Pools are sequence-free: [NB, bs, KH, D].
+# (serving/paged_cache.py).  Pools are sequence-free and stacked over the
+# layers of a scan group: [L, NB, bs, KH, D], read at a prefetched layer
+# index, so the layer scan never slices a one-layer pool out of the stack.
 # ---------------------------------------------------------------------------
-def _decode_paged_kernel(qpos_ref, skip_ref, bt_ref, *refs, scale: float,
-                         window, n_kv_steps: int, quantized: bool):
-    """The block table is consumed by the index maps only (it routes the
-    DMA); the kernel body is exactly the ring kernel's — that shared body
-    plus a shared skip mask is what makes paged == ring bit-identical on
-    equivalent layouts."""
-    del bt_ref
+def _decode_paged_kernel(qpos_ref, skip_ref, bt_ref, layer_ref, *refs,
+                         scale: float, window, n_kv_steps: int,
+                         quantized: bool):
+    """The block table and layer index are consumed by the index maps
+    only (they route the DMA); the kernel body is exactly the ring
+    kernel's — that shared body plus a shared skip mask is what makes
+    paged == ring bit-identical on equivalent layouts."""
+    del bt_ref, layer_ref
     _decode_kernel(qpos_ref, skip_ref, *refs, scale=scale, window=window,
                    n_kv_steps=n_kv_steps, quantized=quantized)
 
@@ -404,45 +407,60 @@ def _decode_paged_kernel(qpos_ref, skip_ref, bt_ref, *refs, scale: float,
 def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, pos_pages: jax.Array,
                            block_tables: jax.Array, q_pos: jax.Array,
+                           layer: jax.Array,
                            k_scale_pages: jax.Array | None = None,
                            v_scale_pages: jax.Array | None = None,
                            window=None, interpret: bool = False) -> jax.Array:
-    """q: [B, KH, G, D]; k/v pools: [NB, bs, KH, D]; pos_pages: [NB, bs];
-    block_tables: [B, nb] int32 (physical block per logical block; 0 is
-    the reserved null block, kept all-empty so unallocated table entries
-    self-mask); q_pos: [B].
+    """q: [B, KH, G, D]; k/v pools: [L, NB, bs, KH, D]; pos_pages: [L,
+    NB, bs]; block_tables: [B, nb] int32 (physical block per logical
+    block; 0 is the reserved null block, kept all-empty so unallocated
+    table entries self-mask); q_pos: [B]; layer: int32 scalar, the pool
+    layer to attend over.
 
-    ``k_scale_pages``/``v_scale_pages`` [NB, bs, KH] f32 turn on the
+    ``k_scale_pages``/``v_scale_pages`` [L, NB, bs, KH] f32 turn on the
     int8-KV path (pools must then be int8).  Grid (B, nb): block ki of
-    row b streams pool block ``block_tables[b, ki]`` (all KV heads) via
-    the scalar-prefetched table, runs the ring kernel's online-softmax
-    step, and the skip list (computed from the gathered per-block
-    positions) elides fully-masked blocks exactly as on the ring path.
+    row b streams pool block ``[layer, block_tables[b, ki]]`` (all KV
+    heads) via the scalar-prefetched table and layer index (the layer
+    dim is squeezed out of every pool block), runs the ring kernel's
+    online-softmax step, and the skip list (computed from the gathered
+    per-block positions) elides fully-masked blocks exactly as on the
+    ring path.
     """
     B, KH, G, D = q.shape
-    NB, bs = pos_pages.shape
+    NB, bs = pos_pages.shape[1:]
     nb = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
     quantized = k_scale_pages is not None
     bt = block_tables.astype(jnp.int32)
-    skip = _keep_blocks(pos_pages[bt], q_pos, window)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def im_q(b, ki, qp, sk, bt):
+    # K/V stream from the stacked pools at the layer index.  The position
+    # and scale pools end in small dims ([bs] and [bs, KH]) that XLA
+    # stores in a compact transposed layout and Mosaic reads only in the
+    # padded row-major one, so every call relays them out: taking their
+    # layer first keeps that to one layer's bytes, not the stack's.
+    def one_layer(a):
+        return jax.lax.dynamic_index_in_dim(a, layer[0], 0, keepdims=False)
+
+    pos_layer = one_layer(pos_pages)
+    skip = _keep_blocks(pos_layer[bt], q_pos, window)
+
+    def im_q(b, ki, qp, sk, bt, ly):
         return (b, 0, 0, 0)
 
-    def im_kv(b, ki, qp, sk, bt):
-        return (bt[b, ki], 0, 0, 0)
+    def im_kv(b, ki, qp, sk, bt, ly):
+        return (ly[0], bt[b, ki], 0, 0, 0)
 
-    def im_pos(b, ki, qp, sk, bt):
+    def im_pos(b, ki, qp, sk, bt, ly):
         return (bt[b, ki], 0, 0)
 
-    def im_scale(b, ki, qp, sk, bt):
+    def im_scale(b, ki, qp, sk, bt, ly):
         return (bt[b, ki], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, KH, G, D), im_q),
-        pl.BlockSpec((1, bs, KH, D), im_kv),
-        pl.BlockSpec((1, bs, KH, D), im_kv),
+        pl.BlockSpec((pl.Squeezed(), 1, bs, KH, D), im_kv),
+        pl.BlockSpec((pl.Squeezed(), 1, bs, KH, D), im_kv),
         pl.BlockSpec((1, 1, bs), im_pos),
     ]
     if quantized:
@@ -450,18 +468,19 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
                      pl.BlockSpec((1, bs, KH), im_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KH, G, D), im_q),
         scratch_shapes=_state_scratch(KH, G, D),
     )
-    operands = (q, k_pages, v_pages, pos_pages.reshape(NB, 1, bs)) \
-        + ((k_scale_pages, v_scale_pages) if quantized else ())
+    operands = (q, k_pages, v_pages, pos_layer.reshape(NB, 1, bs)) \
+        + ((one_layer(k_scale_pages), one_layer(v_scale_pages))
+           if quantized else ())
     return pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale, window=window,
                           n_kv_steps=nb, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), skip, bt, *operands)
+    )(q_pos.astype(jnp.int32), skip, bt, layer, *operands)

@@ -430,15 +430,20 @@ def decode_attention(q, k, v, pos, q_pos, k_scale=None, v_scale=None,
 
 
 def decode_attention_paged(q, k_pages, v_pages, pos_pages, block_tables,
-                           q_pos, k_scale_pages=None, v_scale_pages=None,
-                           window=None, interpret: bool | None = None):
-    """Flash-decode over a paged (block-table) KV cache.
+                           q_pos, layer, k_scale_pages=None,
+                           v_scale_pages=None, window=None,
+                           interpret: bool | None = None):
+    """Flash-decode over one layer of a paged (block-table) KV cache.
 
-    Pools [NB, bs, KH, D] hold fixed-size KV blocks shared by all
-    sequences; ``block_tables`` [B, nb] int32 maps each row's logical
-    blocks to physical pool blocks (0 = the reserved all-empty null
-    block).  ``k_scale_pages``/``v_scale_pages`` [NB, bs, KH] f32 turn
-    on the int8-KV path (in-kernel dequant).  Bit-identical to
+    Pools [L, NB, bs, KH, D] hold the fixed-size KV blocks of every layer
+    of a scan group, shared by all sequences; ``layer`` (int32 scalar)
+    picks the layer the kernel reads, as a scalar-prefetch operand, so
+    the layer scan hands over the stacked pools it carries and no
+    one-layer pool is ever sliced out (``Model._stack``).
+    ``block_tables`` [B, nb] int32 maps each row's logical blocks to
+    physical pool blocks (0 = the reserved all-empty null block).
+    ``k_scale_pages``/``v_scale_pages`` [L, NB, bs, KH] f32 turn on the
+    int8-KV path (in-kernel dequant).  Bit-identical to
     :func:`decode_attention` at ``block_k == bs`` on equivalent layouts
     (same online-softmax body, same skip mask — pinned in
     tests/test_serving.py).
@@ -448,7 +453,7 @@ def decode_attention_paged(q, k_pages, v_pages, pos_pages, block_tables,
         k_pages = k_pages.astype(q.dtype)
         v_pages = v_pages.astype(q.dtype)
     return _da.decode_attention_paged(
-        q, k_pages, v_pages, pos_pages, block_tables, q_pos,
+        q, k_pages, v_pages, pos_pages, block_tables, q_pos, layer,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
         window=window, interpret=interpret)
 

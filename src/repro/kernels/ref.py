@@ -159,23 +159,24 @@ def decode_attention_ref(q, k, v, pos, q_pos, window=None,
 
 
 def decode_attention_paged_ref(q, k_pages, v_pages, pos_pages, block_tables,
-                               q_pos, window=None, k_scale_pages=None,
+                               q_pos, layer, window=None, k_scale_pages=None,
                                v_scale_pages=None):
-    """Oracle for the paged (block-table) flash-decode kernel: gather the
-    pools into the linear [B, nb*bs, KH, D] layout and run the dense
-    decode reference.  q [B,KH,G,D]; pools [NB,bs,KH,D]; pos_pages
-    [NB,bs]; block_tables [B,nb] int32 (0 = reserved null block, all
-    empty-sentinel, so unallocated entries self-mask)."""
+    """Oracle for the paged (block-table) flash-decode kernel: gather
+    layer ``layer`` of the stacked pools into the linear [B, nb*bs, KH,
+    D] layout and run the dense decode reference.  q [B,KH,G,D]; pools
+    [L,NB,bs,KH,D]; pos_pages [L,NB,bs]; block_tables [B,nb] int32 (0 =
+    reserved null block, all empty-sentinel, so unallocated entries
+    self-mask); layer an int32 scalar."""
     B, nb = block_tables.shape
-    bs = pos_pages.shape[1]
+    bs = pos_pages.shape[2]
     bt = block_tables.astype(jnp.int32)
-    k = k_pages[bt].reshape(B, nb * bs, *k_pages.shape[2:])
-    v = v_pages[bt].reshape(B, nb * bs, *v_pages.shape[2:])
-    pos = pos_pages[bt].reshape(B, nb * bs)
+    k = k_pages[layer, bt].reshape(B, nb * bs, *k_pages.shape[3:])
+    v = v_pages[layer, bt].reshape(B, nb * bs, *v_pages.shape[3:])
+    pos = pos_pages[layer, bt].reshape(B, nb * bs)
     ks = vs = None
     if k_scale_pages is not None:
-        ks = k_scale_pages[bt].reshape(B, nb * bs, -1)
-        vs = v_scale_pages[bt].reshape(B, nb * bs, -1)
+        ks = k_scale_pages[layer, bt].reshape(B, nb * bs, -1)
+        vs = v_scale_pages[layer, bt].reshape(B, nb * bs, -1)
     return decode_attention_ref(q, k, v, pos, q_pos, window=window,
                                 k_scale=ks, v_scale=vs)
 

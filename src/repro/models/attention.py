@@ -315,23 +315,26 @@ def _ring_update(buf: jax.Array, new: jax.Array, idx: jax.Array,
 # Paged (block-table) cache update
 # ---------------------------------------------------------------------------
 def _paged_update(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
-                  idx: jax.Array, valid_len: Optional[jax.Array] = None
-                  ) -> jax.Array:
+                  idx: jax.Array, layer: jax.Array,
+                  valid_len: Optional[jax.Array] = None) -> jax.Array:
     """Write ``new`` (S entries starting at logical position ``idx[b]``
-    per batch row) into a shared block pool through per-row block tables.
+    per batch row) into layer ``layer`` of a stacked block pool through
+    per-row block tables.
 
-    pool [NB, bs, ...]; new [B, S, ...]; block_tables [B, nb] int32;
-    idx [B].  Position p lands in pool block ``block_tables[b, p//bs]``
-    at offset ``p % bs``.  Invalid writes — pad entries beyond
-    ``valid_len``, positions past the table (sentinel-index rows), or
-    entries whose logical block is unallocated (table entry 0, the
-    reserved null block) — are routed out of range and dropped, so the
-    null block stays pristine and rows never write through a stale or
-    foreign table entry.  Blocks are sequence-exclusive, so valid writes
-    never collide across rows.
+    pool [L, NB, bs, ...]; new [B, S, ...]; block_tables [B, nb] int32;
+    idx [B]; layer an int32 scalar.  Position p lands in pool block
+    ``[layer, block_tables[b, p//bs]]`` at offset ``p % bs``: one scatter
+    into the stacked pool, which XLA applies in place when the pool is
+    a donated buffer or a loop carry.  Invalid writes — pad entries
+    beyond ``valid_len``, positions past the table (sentinel-index
+    rows), or entries whose logical block is unallocated (table entry 0,
+    the reserved null block) — are routed out of range and dropped, so
+    the null block stays pristine and rows never write through a stale
+    or foreign table entry.  Blocks are sequence-exclusive, so valid
+    writes never collide across rows.
     """
-    NB, bs = pool.shape[0], pool.shape[1]
-    B, S = new.shape[0], new.shape[1]
+    NB, bs = pool.shape[1], pool.shape[2]
+    S = new.shape[1]
     nb = block_tables.shape[1]
     p = idx[:, None].astype(jnp.int32) + jnp.arange(S, dtype=jnp.int32)[None]
     logical = p // bs
@@ -343,17 +346,20 @@ def _paged_update(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
     if valid_len is not None:
         invalid |= jnp.arange(S, dtype=jnp.int32)[None] >= valid_len[:, None]
     phys = jnp.where(invalid, NB, phys)       # out of range -> dropped
-    return pool.at[phys, offs].set(new.astype(pool.dtype), mode="drop")
+    return pool.at[layer, phys, offs].set(new.astype(pool.dtype),
+                                          mode="drop")
 
 
-def _gather_paged(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather a row-linear [B, nb*bs, ...] view of a block pool (the
+def _gather_paged(pool: jax.Array, block_tables: jax.Array,
+                  layer: jax.Array) -> jax.Array:
+    """Gather a row-linear [B, nb*bs, ...] view of layer ``layer`` of a
+    stacked block pool [L, NB, bs, ...] straight from the stack (the
     multi-token/chunked-prefill oracle path; unallocated table entries
     read the all-empty null block and self-mask)."""
     B, nb = block_tables.shape
-    bs = pool.shape[1]
-    g = pool[block_tables.astype(jnp.int32)]
-    return g.reshape(B, nb * bs, *pool.shape[2:])
+    bs = pool.shape[2]
+    g = pool[layer, block_tables.astype(jnp.int32)]
+    return g.reshape(B, nb * bs, *pool.shape[3:])
 
 
 # ---------------------------------------------------------------------------
@@ -408,36 +414,37 @@ def _decode_attention_cached(q, ck, cv, cpos, q_pos, k_scale, v_scale,
     return out4.reshape(B, 1, H, D).astype(q.dtype)
 
 
-def _decode_attention_paged_cached(q, ck, cv, cpos, bt, q_pos, k_scale,
-                                   v_scale, window):
-    """One-token decode over the paged (block-table) cache: same kernel/
-    oracle/TP dispatch as :func:`_decode_attention_cached`, with the KV
-    pools streamed through the scalar-prefetched block table.
+def _decode_attention_paged_cached(q, ck, cv, cpos, bt, q_pos, layer,
+                                   k_scale, v_scale, window):
+    """One-token decode over layer ``layer`` of the paged (block-table)
+    cache: same kernel/oracle/TP dispatch as
+    :func:`_decode_attention_cached`, with the stacked KV pools streamed
+    through the scalar-prefetched block table and layer index.
 
-    q [B, 1, H, D]; pools [NB, bs, KH, D] (int8 with [NB, bs, KH] scales
-    on the quantized path); bt [B, nb]; returns [B, 1, H, D].
+    q [B, 1, H, D]; pools [L, NB, bs, KH, D] (int8 with [L, NB, bs, KH]
+    scales on the quantized path); bt [B, nb]; returns [B, 1, H, D].
     """
     from repro.kernels import ops as kops
     from repro.kernels.ref import decode_attention_paged_ref
     from repro.quant import tp as _tp
 
     B, _, H, D = q.shape
-    KH = ck.shape[2]
+    KH = ck.shape[3]
     q4 = q[:, 0].reshape(B, KH, H // KH, D)
     use_kernel = _resolve_use_kernel(None)
     mesh = _tp_mesh_for(KH)
     if mesh is not None:
         out4 = _tp.decode_attn_paged(mesh, q4, ck, cv, cpos, bt, q_pos,
-                                     k_scale, v_scale, window=window,
+                                     layer, k_scale, v_scale, window=window,
                                      use_kernel=use_kernel)
     elif use_kernel:
         out4 = kops.decode_attention_paged(q4, ck, cv, cpos, bt, q_pos,
-                                           k_scale_pages=k_scale,
+                                           layer, k_scale_pages=k_scale,
                                            v_scale_pages=v_scale,
                                            window=window)
     else:
         out4 = decode_attention_paged_ref(q4, ck, cv, cpos, bt, q_pos,
-                                          window=window,
+                                          layer, window=window,
                                           k_scale_pages=k_scale,
                                           v_scale_pages=v_scale)
     return out4.reshape(B, 1, H, D).astype(q.dtype)
@@ -445,24 +452,38 @@ def _decode_attention_paged_cached(q, ck, cv, cpos, bt, q_pos, k_scale,
 
 def _paged_cache_apply(cache, k, v, positions, q, mask_kind, window,
                        prefix_len):
-    """Cache write + attend for a paged (block-table) cache dict."""
+    """Cache write + attend for one layer of a paged (block-table) cache.
+
+    ``cache`` holds the scan group's stacked block pools (``*_pages``,
+    [L, NB, bs, ...]), which ride ``Model._stack``'s layer-scan carry,
+    this layer's index ``layer``, and this layer's per-row
+    ``block_tables``/``index``.  The new K/V, scales and positions
+    scatter into the stacked pools at ``[layer, block, offset]``, and
+    attention reads them at ``layer`` (the decode kernel through its
+    scalar-prefetched layer index, chunked prefill through one gather),
+    so no one-layer pool is sliced out or written back and XLA updates
+    the donated pools in place.  Returns (out, new cache without
+    ``layer``).
+    """
     idx = cache["index"]
     bt = cache["block_tables"]
+    layer = cache["layer"]
     S = positions.shape[1]
     valid_len = jnp.sum(positions < 2 ** 29, axis=1).astype(jnp.int32)
     quantized = cache["k_pages"].dtype == jnp.int8
+
+    def write(name, new):
+        return _paged_update(cache[name], new, bt, idx, layer, valid_len)
+
     cks = cvs = None
     if quantized:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        ck = _paged_update(cache["k_pages"], kq, bt, idx, valid_len)
-        cv = _paged_update(cache["v_pages"], vq, bt, idx, valid_len)
-        cks = _paged_update(cache["k_scale_pages"], ks, bt, idx, valid_len)
-        cvs = _paged_update(cache["v_scale_pages"], vs, bt, idx, valid_len)
+        ck, cv = write("k_pages", kq), write("v_pages", vq)
+        cks, cvs = write("k_scale_pages", ks), write("v_scale_pages", vs)
     else:
-        ck = _paged_update(cache["k_pages"], k, bt, idx, valid_len)
-        cv = _paged_update(cache["v_pages"], v, bt, idx, valid_len)
-    cpos = _paged_update(cache["pos_pages"], positions, bt, idx, valid_len)
+        ck, cv = write("k_pages", k), write("v_pages", v)
+    cpos = write("pos_pages", positions)
     new_cache = {"k_pages": ck, "v_pages": cv, "pos_pages": cpos,
                  "block_tables": bt, "index": idx + S}
     if quantized:
@@ -470,19 +491,27 @@ def _paged_cache_apply(cache, k, v, positions, q, mask_kind, window,
         new_cache["v_scale_pages"] = cvs
     if S == 1 and mask_kind in ("causal", "sliding", "prefix"):
         out = _decode_attention_paged_cached(
-            q, ck, cv, cpos, bt, positions[:, 0], cks, cvs,
+            q, ck, cv, cpos, bt, positions[:, 0], layer, cks, cvs,
             window if mask_kind == "sliding" else None)
     else:
-        # chunked-prefill / multi-token oracle path: gather the pools
-        # into the row-linear layout (XLA dequant on the int8 path)
-        k_lin = _gather_paged(ck, bt)
-        v_lin = _gather_paged(cv, bt)
-        pos_lin = _gather_paged(cpos, bt)
+        # chunked-prefill / multi-token oracle path: gather this layer
+        # of the pools into the row-linear layout (XLA dequant on the
+        # int8 path).  K/V are gathered straight from the stacked pools,
+        # so no one-layer K/V pool is materialised.  The position and
+        # scale pools end in dims narrower than a lane, which the TPU
+        # stores transposed, and a gather relays out its whole operand:
+        # they are gathered from their one layer, a relayout of one
+        # layer's bytes instead of the stack's.
+        def gather_small(pool):
+            one = jax.lax.dynamic_slice_in_dim(pool, layer, 1, 0)
+            return _gather_paged(one, bt, 0)
+
+        k_lin, v_lin = _gather_paged(ck, bt, layer), _gather_paged(cv, bt,
+                                                                   layer)
+        pos_lin = gather_small(cpos)
         if quantized:
-            k_lin = _dequantize_kv(k_lin, _gather_paged(cks, bt)).astype(
-                q.dtype)
-            v_lin = _dequantize_kv(v_lin, _gather_paged(cvs, bt)).astype(
-                q.dtype)
+            k_lin = _dequantize_kv(k_lin, gather_small(cks)).astype(q.dtype)
+            v_lin = _dequantize_kv(v_lin, gather_small(cvs)).astype(q.dtype)
         out = dense_attention(q, k_lin, v_lin, positions, pos_lin, mask_kind,
                               window, prefix_len)
     return out, new_cache

@@ -319,7 +319,20 @@ class Model:
 
     def _stack(self, params, x, positions, caches, prefix_len,
                decode: bool = False):
-        """Run all layer groups. caches: None or dict group_i -> stacked."""
+        """Run all layer groups. caches: None or dict group_i -> stacked.
+
+        Each group is one ``jax.lax.scan`` over its stacked layers, with
+        the layer params and the layer caches as ``xs`` and the new
+        caches as ``ys``.  A paged cache group (it holds
+        ``block_tables``) is split: its block pools (``*_pages``, [L, NB,
+        ...]) ride the scan's carry with the layer index, and each layer
+        scatters into and reads the stacked pools at that index
+        (``attention._paged_cache_apply``), so XLA updates the donated
+        pools in place instead of slicing each layer's pool out and
+        writing it back whole.  Its per-row block tables and write index
+        stay in ``xs``/``ys``.  Ring caches, recurrent state and the
+        cache-less forward carry no pools.
+        """
         cfg = self.cfg
         total_aux = jnp.zeros((), jnp.float32)
         new_caches = {} if caches is not None else None
@@ -327,23 +340,35 @@ class Model:
         for gi, (spec, count) in enumerate(self.groups):
             gparams = params[f"group_{gi}"]
             gcache = caches[f"group_{gi}"] if caches is not None else None
+            pools = {}
+            if gcache is not None and "block_tables" in gcache:
+                pools = {k: a for k, a in gcache.items()
+                         if k.endswith("_pages")}
+                gcache = {k: a for k, a in gcache.items() if k not in pools}
 
             def body(carry, layer_in, spec=spec):
-                x, aux = carry
+                x, aux, pools, layer = carry
                 x = shard(x, ("batch", "act_seq", None))
                 lparams, lcache = layer_in
+                if pools:
+                    lcache = {**lcache, **pools, "layer": layer}
                 x, ncache, a = block_apply(lparams, spec, cfg, x, positions,
                                            lcache, prefix_len)
                 x = shard(x, ("batch", "act_seq", None))
-                return (x, aux + a), ncache
+                if pools:
+                    pools = {k: ncache[k] for k in pools}
+                    ncache = {k: a for k, a in ncache.items()
+                              if k not in pools}
+                return (x, aux + a, pools, layer + 1), ncache
 
             if cfg.remat and not decode:
                 body = jax.checkpoint(body)
 
-            (x, total_aux), ncache = jax.lax.scan(
-                body, (x, total_aux), (gparams, gcache))
+            (x, total_aux, pools, _), ncache = jax.lax.scan(
+                body, (x, total_aux, pools, jnp.zeros((), jnp.int32)),
+                (gparams, gcache))
             if new_caches is not None:
-                new_caches[f"group_{gi}"] = ncache
+                new_caches[f"group_{gi}"] = {**ncache, **pools}
         return x, new_caches, total_aux
 
     def _head(self, params, x):

@@ -259,6 +259,7 @@ def decode_attn(mesh: Mesh, q: jax.Array, k: jax.Array, v: jax.Array,
 def decode_attn_paged(mesh: Mesh, q: jax.Array, k_pages: jax.Array,
                       v_pages: jax.Array, pos_pages: jax.Array,
                       block_tables: jax.Array, q_pos: jax.Array,
+                      layer: jax.Array,
                       k_scale_pages: jax.Array | None = None,
                       v_scale_pages: jax.Array | None = None, *,
                       window: int | None = None,
@@ -266,30 +267,32 @@ def decode_attn_paged(mesh: Mesh, q: jax.Array, k_pages: jax.Array,
     """Head-parallel paged flash-decode: the block-table analogue of
     :func:`decode_attn`.
 
-    q [B, KH, G, D] and the KV block pools [NB, bs, KH, D] (+[NB, bs,
-    KH] scales on the int8 path) shard on their KV-head axis; the block
-    tables and position pages replicate (they are head-agnostic host
-    metadata).  Each shard streams its KH/p heads through the same
-    scalar-prefetched block-table kernel with no collective — the paged
-    pool, like the ring cache, holds 1/p of the KV bytes per device.
+    q [B, KH, G, D] and the stacked KV block pools [L, NB, bs, KH, D]
+    (+[L, NB, bs, KH] scales on the int8 path) shard on their KV-head
+    axis; the block tables, position pages and the layer index replicate
+    (they are head-agnostic metadata).  Each shard streams its KH/p heads
+    of pool layer ``layer`` through the same scalar-prefetched
+    block-table kernel with no collective — the paged pool, like the
+    ring cache, holds 1/p of the KV bytes per device.
     """
-    def body(ql, kl, vl, posl, btl, qpl, *sc):
+    def body(ql, kl, vl, posl, btl, qpl, ly, *sc):
         ks, vs = sc if sc else (None, None)
         if use_kernel:
             return kops.decode_attention_paged(ql, kl, vl, posl, btl, qpl,
-                                               k_scale_pages=ks,
+                                               ly, k_scale_pages=ks,
                                                v_scale_pages=vs,
                                                window=window)
         return kref.decode_attention_paged_ref(ql, kl, vl, posl, btl, qpl,
-                                               window=window,
+                                               ly, window=window,
                                                k_scale_pages=ks,
                                                v_scale_pages=vs)
 
-    in_specs = [P(None, TP_AXIS), P(None, None, TP_AXIS),
-                P(None, None, TP_AXIS), P(), P(), P()]
-    args = [q, k_pages, v_pages, pos_pages, block_tables, q_pos]
+    pool = P(None, None, None, TP_AXIS)
+    in_specs = [P(None, TP_AXIS), pool, pool, P(), P(), P(), P()]
+    args = [q, k_pages, v_pages, pos_pages, block_tables, q_pos,
+            jnp.asarray(layer, jnp.int32)]
     if k_scale_pages is not None:
-        in_specs += [P(None, None, TP_AXIS), P(None, None, TP_AXIS)]
+        in_specs += [pool, pool]
         args += [k_scale_pages, v_scale_pages]
     return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=P(None, TP_AXIS), check_vma=False)(*args)

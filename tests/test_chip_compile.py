@@ -9,7 +9,10 @@ nothing here executes.  The topology is described inside a fixture (one
 process at a time may load the TPU library), and every compile runs in
 the test's own process.
 """
+import dataclasses
 import os
+import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -85,13 +88,14 @@ def test_swiglu_mlp(compile_v5e, M):
 @pytest.mark.parametrize("kv", ["int8", "bf16"])
 def test_paged_decode_attention(compile_v5e, kv):
     from repro.kernels import ops
-    B, G, bs, nb, NB = 8, HEADS // KV_HEADS, 16, 36, 1 + 8 * 36
+    B, G, bs, nb, NB, L = 8, HEADS // KV_HEADS, 16, 36, 1 + 8 * 36, 4
     dt = jnp.int8 if kv == "int8" else jnp.bfloat16
-    pool = ((NB, bs, KV_HEADS, HEAD_DIM), dt)
+    pool = ((L, NB, bs, KV_HEADS, HEAD_DIM), dt)
     shapes = [((B, KV_HEADS, G, HEAD_DIM), jnp.bfloat16), pool, pool,
-              ((NB, bs), jnp.int32), ((B, nb), jnp.int32), ((B,), jnp.int32)]
+              ((L, NB, bs), jnp.int32), ((B, nb), jnp.int32),
+              ((B,), jnp.int32), ((), jnp.int32)]
     if kv == "int8":
-        shapes += [((NB, bs, KV_HEADS), jnp.float32)] * 2
+        shapes += [((L, NB, bs, KV_HEADS), jnp.float32)] * 2
     compile_v5e(lambda *a: ops.decode_attention_paged(*a), *shapes)
 
 
@@ -140,3 +144,83 @@ def test_dit_adaln_gemm(compile_v5e):
                                                                   bias=b),
                 ((4, DIT_D), jnp.float32), ((DIT_D, n), jnp.int8),
                 ((n,), jnp.float32), ((n,), jnp.float32))
+
+
+# deepseek-67b-4L as the benchmark serves it: 32 slots, 4096-token block
+# tables of 16-token blocks (8,193 with the null block), int8 KV and
+# 256-token prefill chunks
+LM_LAYERS, SLOTS, BLOCK, MAX_BLOCKS, CHUNK = 4, 32, 16, 256, 256
+NUM_BLOCKS = 1 + SLOTS * MAX_BLOCKS
+POOL_SHAPES = tuple(f"s8[{dims}]" for dims in (
+    f"{LM_LAYERS},{NUM_BLOCKS},{BLOCK},{KV_HEADS},{HEAD_DIM}",
+    f"1,{NUM_BLOCKS},{BLOCK},{KV_HEADS},{HEAD_DIM}",
+    f"{NUM_BLOCKS},{BLOCK},{KV_HEADS},{HEAD_DIM}"))
+MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _paged_step_hlo(one_chip, step: str) -> str:
+    """The paged engine's decode step or one prefill chunk, compiled for
+    one v5e with every Pallas kernel, as optimised HLO text."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.quant import QuantPlan, kernel_mode
+    from repro.serving import PagedServingEngine
+
+    cfg = dataclasses.replace(get_config("deepseek-67b"),
+                              n_layers=LM_LAYERS)
+    model = build_model(cfg)
+    params = jax.eval_shape(
+        lambda k: model.init_quantized(k, QuantPlan.full()),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        SLOTS, NUM_BLOCKS, BLOCK, MAX_BLOCKS, kv_dtype="int8"))
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, cache = jax.tree.map(lambda a: on_chip(a.shape, a.dtype),
+                                 (params, cache))
+    eng = object.__new__(PagedServingEngine)
+    eng.model, eng.mesh, eng.rules, eng.degraded = model, None, None, False
+    eng.paged = SimpleNamespace(
+        allocator=SimpleNamespace(num_blocks=NUM_BLOCKS),
+        max_blocks=MAX_BLOCKS)
+    eng._build_steps()
+    tables = on_chip((SLOTS, MAX_BLOCKS), jnp.int32)
+    if step == "decode":
+        fn = eng._decode_masked
+        args = (on_chip((SLOTS,), jnp.int32), on_chip((SLOTS,), jnp.bool_),
+                tables)
+    else:
+        fn = eng._prefill_chunk_fn
+        scalar = on_chip((), jnp.int32)
+        args = (on_chip((CHUNK,), jnp.int32), scalar, scalar, scalar, tables)
+    with kernel_mode(True):
+        hlo = fn.lower(params, cache, *args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    return hlo
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_paged_step_updates_kv_pools_in_place(one_chip, monkeypatch, step):
+    """The layer scan carries the stacked KV pools: the compiled step
+    neither slices a layer's pool out, writes one back, nor copies the
+    stack, and every pool leaf is donated (aliased to an output)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    hlo = _paged_step_hlo(one_chip, step)
+    moved = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?(\S+?)\{\S* ([\w-]+)\(",
+                     line)
+        if m is None or m[2] not in POOL_SHAPES:
+            continue
+        name, op = m[1], re.sub(r"-(start|done)$", "", m[3])
+        if op in MOVES or (op == "fusion" and any(k in name for k in MOVES)):
+            moved.append(name)
+    assert moved == []
+
+    pools = re.findall(r"%cache\S*_pages\S* = \S+ parameter\((\d+)\)", hlo)
+    aliased = re.findall(r"\((\d+), \{[^}]*\}, (?:may|must)-alias\)", hlo)
+    assert len(pools) == 5                 # K, V, their scales, positions
+    assert set(pools) <= set(aliased)

@@ -23,6 +23,7 @@ from repro.configs import get_config, reduced_config
 from repro.kernels import ops as kops
 from repro.kernels.ref import decode_attention_paged_ref, decode_attention_ref
 from repro.models import build_model
+from repro.models.model import Model, block_apply
 from repro.quant import QuantPlan, kernel_mode
 from repro.serving import (BlockAllocator, PagedKVCache, PagedServingEngine,
                            PoolExhausted, Request, RequestStatus,
@@ -403,6 +404,9 @@ def _ring_and_pages(B, S, KH, G, D, bs, seed, int8=False, n_empty=0,
         paged.update(k_pages=jnp.asarray(kqp), v_pages=jnp.asarray(vqp),
                      k_scale_pages=jnp.asarray(ksp),
                      v_scale_pages=jnp.asarray(vsp))
+    # pools as the paged kernel reads them: a one-layer stack [1, NB, ...]
+    paged = {k: a if k == "block_tables" else a[None]
+             for k, a in paged.items()}
     return q, q_pos, ring, paged
 
 
@@ -418,7 +422,7 @@ class TestPagedDecodeKernel:
             window=window, block_k=bs, n_splits=1)
         paged_out = kops.decode_attention_paged(
             q, paged["k_pages"], paged["v_pages"], paged["pos_pages"],
-            paged["block_tables"], q_pos,
+            paged["block_tables"], q_pos, 0,
             k_scale_pages=paged.get("k_scale_pages"),
             v_scale_pages=paged.get("v_scale_pages"), window=window)
         return np.asarray(ring_out), np.asarray(paged_out)
@@ -435,7 +439,7 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
         paged_oracle = np.asarray(decode_attention_paged_ref(
             q, paged["k_pages"], paged["v_pages"], paged["pos_pages"],
-            paged["block_tables"], q_pos))
+            paged["block_tables"], q_pos, 0))
         np.testing.assert_allclose(p, paged_oracle, rtol=2e-5, atol=2e-5)
 
     def test_sliding_window_paged_equals_ring(self):
@@ -514,6 +518,53 @@ class TestPagedDecodeKernel:
             q, ring["k"], ring["v"], ring["pos"], q_pos))
         np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("kv,window", [("bf16", None), ("bf16", 7),
+                                           ("int8", None), ("int8", 7)])
+    def test_stacked_pools_at_layer_equal_one_layer(self, kv, window):
+        """The kernel and its oracle read layer ``l`` of a 3-layer pool
+        stack exactly as they read that layer's pools alone, and the
+        kernel equals the ring kernel on the equivalent layout, bit for
+        bit.  The other layers hold other sequences' pools under other
+        tables, so a read of the wrong layer shows."""
+        int8 = kv == "int8"
+        layers = [_ring_and_pages(B=3, S=32, KH=2, G=2, D=8, bs=8, seed=20 + l,
+                                  int8=int8, lengths=[32, 19, 9])
+                  for l in range(3)]
+        names = ["k_pages", "v_pages", "pos_pages"] + (
+            ["k_scale_pages", "v_scale_pages"] if int8 else [])
+
+        def cast(a):
+            return a if int8 or a.dtype != jnp.float32 \
+                else a.astype(jnp.bfloat16)
+
+        stack = {n: jnp.concatenate([cast(pg[n]) for _, _, _, pg in layers])
+                 for n in names}
+        for l, (q, q_pos, ring, paged) in enumerate(layers):
+            q = cast(q)
+            one = {n: cast(paged[n]) for n in names}
+            bt = paged["block_tables"]
+
+            def call(fn, pools, layer):
+                return np.asarray(fn(
+                    q, pools["k_pages"], pools["v_pages"],
+                    pools["pos_pages"], bt, q_pos, layer, window=window,
+                    k_scale_pages=pools.get("k_scale_pages"),
+                    v_scale_pages=pools.get("v_scale_pages")))
+
+            out = call(kops.decode_attention_paged, stack, l)
+            assert (out == call(kops.decode_attention_paged, one, 0)).all()
+            ring_out = kops.decode_attention(
+                q, cast(ring["k"]), cast(ring["v"]), ring["pos"], q_pos,
+                k_scale=ring.get("k_scale"), v_scale=ring.get("v_scale"),
+                window=window, block_k=8, n_splits=1)
+            assert (out == np.asarray(ring_out)).all()
+            oracle = call(decode_attention_paged_ref, stack, l)
+            assert (oracle == call(decode_attention_paged_ref, one, 0)).all()
+            np.testing.assert_allclose(
+                out.astype(np.float32), oracle.astype(np.float32),
+                rtol=2e-2 if kv == "bf16" else 2e-5,
+                atol=2e-2 if kv == "bf16" else 2e-5)
+
     def test_tp_paged_decode_parity(self):
         """Head-parallel paged flash-decode (quant/tp.py) == unsharded
         kernel bit-for-bit at 1/2-way model meshes (forced host
@@ -544,12 +595,13 @@ class TestPagedDecodeKernel:
             q_pos = jnp.asarray([L - 1 for L in lengths], jnp.int32)
             kp, vp = jnp.asarray(kp), jnp.asarray(vp)
             pp, bt = jnp.asarray(pp), jnp.asarray(bt)
+            kp, vp, pp = kp[None], vp[None], pp[None]   # one-layer stack
             ref = np.asarray(kops.decode_attention_paged(
-                q, kp, vp, pp, bt, q_pos))
+                q, kp, vp, pp, bt, q_pos, 0))
             for p in (1, 2):
                 mesh = make_mesh((p,), ("model",))
                 out = np.asarray(_tp.decode_attn_paged(
-                    mesh, q, kp, vp, pp, bt, q_pos))
+                    mesh, q, kp, vp, pp, bt, q_pos, 0))
                 assert (out == ref).all(), p
             print("tp_paged OK")
         """), devices=2)
@@ -773,6 +825,70 @@ class TestPagedServingEngine:
         eng.shutdown(drain=False)
         assert eng.paged.allocator.n_used == 0
         eng.paged.allocator.check()
+
+
+class _SlicedPoolsModel(Model):
+    """The layer scan before the pools rode its carry: every cache leaf,
+    pools included, is scanned as ``xs``/``ys``, so each layer's pools
+    are sliced out of the stack and written back whole (as one-layer
+    stacks read at layer 0)."""
+
+    def _stack(self, params, x, positions, caches, prefix_len,
+               decode=False):
+        new_caches = {}
+        for gi, (spec, _) in enumerate(self.groups):
+            def body(x, layer_in, spec=spec):
+                lparams, lcache = layer_in
+                pools = [k for k in lcache if k.endswith("_pages")]
+                lcache = {**lcache, **{k: lcache[k][None] for k in pools},
+                          "layer": jnp.zeros((), jnp.int32)}
+                x, ncache, _ = block_apply(lparams, spec, self.cfg, x,
+                                           positions, lcache, prefix_len)
+                return x, {k: a[0] if k in pools else a
+                           for k, a in ncache.items()}
+
+            x, new_caches[f"group_{gi}"] = jax.lax.scan(
+                body, x, (params[f"group_{gi}"], caches[f"group_{gi}"]))
+        return x, new_caches, jnp.zeros((), jnp.float32)
+
+
+class TestCarriedPools:
+    @pytest.mark.parametrize("kv,kernels", [("bf16", False),
+                                            ("int8", False),
+                                            ("int8", True)])
+    def test_engine_bitwise_equals_sliced_pools_scan(self, small_model, kv,
+                                                     kernels):
+        """A tight-pool engine run (chunked prefill, decode, a preemption
+        and its resume) hands out the same greedy tokens and fetches
+        the same logits, bit for bit, whether the layer scan carries
+        the stacked pools or slices each layer's pools as ``xs``/``ys``;
+        on the jnp oracles and on the Pallas kernels (interpreted)."""
+        cfg, m, params = small_model
+        runs = []
+        for model in (m, _SlicedPoolsModel(cfg)):
+            fetched = []
+
+            def record(phase, logits):
+                fetched.append((phase, np.array(logits)))
+
+            eng = PagedServingEngine(
+                model, params, n_slots=4, max_len=64, prefill_bucket=16,
+                block_size=8, num_blocks=9, prefill_chunk=8,
+                quant_plan=QuantPlan.full() if kv == "int8" else None,
+                fault_hook=record)
+            reqs = _requests(cfg, 6, seed=1, out=6)
+            for r in reqs:
+                eng.submit(r)
+            with kernel_mode(kernels):
+                eng.run_until_done(max_iters=2000)
+            assert all(r.status is RequestStatus.OK for r in reqs)
+            assert eng.stats.preemptions >= 1
+            runs.append(([r.generated for r in reqs], fetched))
+        (carried, c_logits), (sliced, s_logits) = runs
+        assert carried == sliced
+        assert [p for p, _ in c_logits] == [p for p, _ in s_logits]
+        for (_, a), (_, b) in zip(c_logits, s_logits):
+            assert a.tobytes() == b.tobytes()
 
 
 # ---------------------------------------------------------------------------
