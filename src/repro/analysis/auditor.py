@@ -24,7 +24,7 @@ from . import manifest, passes
 from .passes import Violation
 
 _KEY = jax.random.PRNGKey(0)
-_KV_LEAF_NAMES = ("k", "v", "k_pages", "v_pages")
+_KV_LEAF_NAMES = ("k", "v", "k_pages", "v_pages", "latent_pages")
 
 
 @dataclasses.dataclass
@@ -202,7 +202,7 @@ def audit_lm(arch: str, phase: str = "decode", paged: bool = False,
                                     kv_len=kv_len, batch=batch)
     expected = manifest.model_sites(model, phase, sharded=tp > 1,
                                     kv_len=kv_len if phase == "decode"
-                                    else 0)
+                                    else 0, paged=paged)
     sites = jt.pallas_sites(jaxpr)
     violations = []
     violations += passes.dispatch_audit(sites, expected)

@@ -21,7 +21,7 @@ acc_gemm       ``_cim_gemm_kernel`` — int32-accumulator partial GEMM;
                cross-shard ``psum``
 grouped_moe    ``_cim_grouped_gemm_kernel`` / ``_cim_grouped_gated_kernel``
 decode_attn    ``_decode_kernel`` / ``_decode_paged_kernel`` /
-               ``_decode_splitkv_kernel``
+               ``_decode_splitkv_kernel`` / ``_mla_decode_kernel``
 attn_combine   ``_combine_kernel`` (split-KV log-sum-exp merge)
 =============  =====================================================
 
@@ -58,6 +58,7 @@ KERNEL_SITES = {
     "_decode_kernel": "decode_attn",
     "_decode_paged_kernel": "decode_attn",
     "_decode_splitkv_kernel": "decode_attn",
+    "_mla_decode_kernel": "decode_attn",
     "_combine_kernel": "attn_combine",
 }
 
@@ -149,31 +150,42 @@ def _moe_dims(cfg):
 
 
 def block_sites(cfg, spec, phase: str, sharded: bool = False,
-                kv_len: int = 0) -> Counter:
+                kv_len: int = 0, paged: bool = False) -> Counter:
     """Expected site-class dispatch counts for ONE transformer block.
 
     ``spec`` is the ``(mixer, ffn)`` pair from ``Model.groups``;
     ``phase`` is ``"prefill"`` / ``"decode"`` / ``"step"`` (DiT).
     ``sharded`` states the step is traced under a model-axis mesh
     (per-shard counts); ``kv_len`` is the attended cache length (decides
-    split-KV).
+    split-KV); ``paged`` that decode runs over the paged cache (MLA's
+    latent kernel runs there only; its ring decode is jnp).
     """
     mixer, ffn = spec
-    if mixer not in ("attn", "attn_local"):
+    if mixer not in ("attn", "attn_local", "mla"):
         raise ValueError(f"no full-plan contract for mixer {mixer!r}")
-    q_dim = cfg.n_heads * cfg.head_dim
     sites: Counter = Counter()
-    # attention: QKV projection + decode kernel + out projection
-    if sharded:
-        sites += gemm_in_sites(cfg.d_model)          # column-parallel QKV
-        sites["acc_gemm"] += 1                       # row-parallel out
-    else:
+    if mixer == "mla":
+        if sharded:
+            raise ValueError("MLA is data-parallel: no TP contract")
+        m = cfg.mla
+        # q_a | kv_a as one wide GEMM, q_b, out-projection
         sites += gemm_in_sites(cfg.d_model)
-        sites += gemm_in_sites(q_dim)
-    if phase == "decode":
-        sites["decode_attn"] += 1
-        if kv_len > SPLITKV_THRESHOLD:
-            sites["attn_combine"] += 1
+        sites += gemm_in_sites(m.q_lora_rank)
+        sites += gemm_in_sites(cfg.n_heads * m.v_head_dim)
+        if phase == "decode" and paged:
+            sites["decode_attn"] += 1
+    else:
+        # attention: QKV projection + decode kernel + out projection
+        if sharded:
+            sites += gemm_in_sites(cfg.d_model)      # column-parallel QKV
+            sites["acc_gemm"] += 1                   # row-parallel out
+        else:
+            sites += gemm_in_sites(cfg.d_model)
+            sites += gemm_in_sites(cfg.n_heads * cfg.head_dim)
+        if phase == "decode":
+            sites["decode_attn"] += 1
+            if kv_len > SPLITKV_THRESHOLD:
+                sites["attn_combine"] += 1
     # feed-forward
     if ffn == "dense":
         if sharded:
@@ -202,7 +214,7 @@ def block_sites(cfg, spec, phase: str, sharded: bool = False,
 
 
 def model_sites(model, phase: str, sharded: bool = False,
-                kv_len: int = 0) -> Counter:
+                kv_len: int = 0, paged: bool = False) -> Counter:
     """Expected dispatch counts for one whole-model step.  Stacked layer
     groups scan over a single traced block body, so each group
     contributes its per-block profile exactly once regardless of
@@ -211,7 +223,7 @@ def model_sites(model, phase: str, sharded: bool = False,
     total: Counter = Counter()
     for spec, _count in model.groups:
         total += block_sites(model.cfg, spec, phase, sharded=sharded,
-                             kv_len=kv_len)
+                             kv_len=kv_len, paged=paged)
     return total
 
 
@@ -232,11 +244,11 @@ def dit_sites(cfg, sharded: bool = False) -> Counter:
 
 def supports_full_plan(model) -> bool:
     """True when every layer group of the model has a contract entry
-    (attention mixer + dense/moe/none ffn) — the archs `make audit`
-    must cover.  MLA / SSM / xLSTM mixers are ROADMAP item 3."""
+    (attention or MLA mixer + dense/moe/none ffn) — the archs `make
+    audit` must cover.  SSM / xLSTM mixers are ROADMAP item R2."""
     for spec, _count in model.groups:
         mixer, ffn = spec
-        if mixer not in ("attn", "attn_local"):
+        if mixer not in ("attn", "attn_local", "mla"):
             return False
         if ffn not in ("dense", "moe", "none"):
             return False

@@ -14,6 +14,7 @@ directory, so an unregistered config module fails the pre-push gate.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 
 from .base import ModelConfig
@@ -55,10 +56,33 @@ def _load(table: dict, arch: str, what: str):
 
 
 def get_config(arch: str) -> ModelConfig:
+    """``arch`` is a registered id, or ``<id>:ep<n>.<i>``: the model
+    whose MoE layers hold shard ``i`` of ``n`` of their routed experts,
+    one chip's share under n-way expert parallelism (the router keeps
+    every expert)."""
     if arch in _DIT_MODULES:
         raise KeyError(f"{arch!r} is a diffusion config; use "
                        f"get_dit_config({arch!r})")
-    return _load(_MODULES, arch, "arch")
+    name, _, share = arch.partition(":ep")
+    cfg = _load(_MODULES, name, "arch")
+    if share:
+        n, _, i = share.partition(".")
+        cfg = expert_share(cfg, int(n), int(i))
+    return cfg
+
+
+def expert_share(cfg: ModelConfig, n_shards: int, shard: int) -> ModelConfig:
+    """``cfg`` with its MoE layers holding shard ``shard`` of
+    ``n_shards`` equal shares of the routed experts."""
+    if cfg.moe is None:
+        raise ValueError(f"{cfg.name} has no experts to share")
+    if cfg.moe.n_routed_experts % n_shards or not 0 <= shard < n_shards:
+        raise ValueError(f"no expert shard {shard} of {n_shards} for "
+                         f"{cfg.moe.n_routed_experts} experts")
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}:ep{n_shards}.{shard}",
+        moe=dataclasses.replace(cfg.moe, n_expert_shards=n_shards,
+                                expert_shard=shard))
 
 
 def get_dit_config(arch: str):

@@ -18,7 +18,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.models.mla import MLAConfig
 from repro.models.ssm import SSMConfig
 from repro.models.xlstm import XLSTMConfig
 
@@ -122,14 +121,16 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         kw["attn_every"] = 2
         kw["n_layers"] = 4
     if cfg.mla is not None:
-        kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
-                              qk_nope_head_dim=16, qk_rope_head_dim=8,
-                              v_head_dim=16)
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16)
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, n_routed_experts=8, top_k=2, d_expert=32,
             shared_d_ff=32 if cfg.moe.n_shared_experts else 0,
-            first_k_dense=min(cfg.moe.first_k_dense, 1))
+            first_k_dense=min(cfg.moe.first_k_dense, 1),
+            n_group=min(cfg.moe.n_group, 4),
+            topk_group=min(cfg.moe.topk_group, 2))
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(state_dim=8, head_dim=16, expand=2,
                               conv_kernel=4, chunk=8)

@@ -279,16 +279,21 @@ def _xlstm_ops(cfg: ModelConfig, batch: int, q_len: int, bits: int,
 
 
 def _plan_layer_coverage(mixer: str, ffn: str) -> dict:
-    """OpKind -> plan layer-kind map for ONE layer, derived from
-    ``repro.quant.plan.covered_kinds`` (the single source of truth) so
-    the simulator costs exactly what apply_plan quantizes: only
-    attn/attn_local mixers get quantized projections (MLA stays bf16),
-    and a MoE layer's shared expert (OpKind.FFN) follows
-    ``moe_experts`` with the routed experts.  Attention QK/SV (the
-    KV-cache GEMVs) follow ``attn_kv``: with the int8 KV cache the
-    flash-decode kernel streams int8 K/V and dequantizes in-kernel, so
-    those GEMVs run at the 8-bit operand width too.  Softmax, the
-    router, and the LM head are not plan-covered — they stay bf16."""
+    """OpKind (or, for MLA, op-name suffix) -> plan layer-kind map for
+    ONE layer, derived from ``repro.quant.plan.covered_kinds`` (the
+    single source of truth) so the simulator costs exactly what
+    apply_plan quantizes: attn/attn_local projections follow
+    ``attn_qkv``/``attn_out``; MLA's q/kv down-projections and q
+    up-projection follow ``mla_proj`` and its out-projection
+    ``mla_out``, while its W_UK/W_UV products (``q_absorb``, ``v_up``,
+    ``kv_up``) stay bf16, and so do its latent score/value products (the
+    decode kernel feeds the MXU bf16 from the int8 latent); a MoE
+    layer's shared expert (OpKind.FFN) follows ``moe_experts`` with the
+    routed experts.  Attention QK/SV (the KV-cache GEMVs) follow
+    ``attn_kv``: with the int8 KV cache the flash-decode kernel streams
+    int8 K/V and dequantizes in-kernel, so those GEMVs run at the 8-bit
+    operand width too.  Softmax, the router, and the LM head are not
+    plan-covered — they stay bf16."""
     # local import: quant pulls the Pallas kernel modules, which the
     # simulator core otherwise never needs (callers passing a QuantPlan
     # have already imported repro.quant anyway)
@@ -300,9 +305,14 @@ def _plan_layer_coverage(mixer: str, ffn: str) -> dict:
         cov[OpKind.QKV] = "attn_qkv"
     if "attn_out" in kinds:
         cov[OpKind.PROJ] = "attn_out"
-    if "attn_kv" in kinds:
+    if "attn_kv" in kinds and mixer != "mla":
         cov[OpKind.ATTN_QK] = "attn_kv"
         cov[OpKind.ATTN_SV] = "attn_kv"
+    if "mla_proj" in kinds:
+        cov.update({"q_down": "mla_proj", "q_up": "mla_proj",
+                    "kv_down": "mla_proj"})
+    if "mla_out" in kinds:
+        cov["o"] = "mla_out"
     if "mlp" in kinds:
         cov[OpKind.FFN] = "mlp"
     if "moe_experts" in kinds:
@@ -316,7 +326,7 @@ def _plan_op_bits(op, plan, coverage: dict):
     the paper's INT8 energy point); everything else stays bf16."""
     if not isinstance(op, MatMulOp):
         return op
-    kind = coverage.get(op.kind)
+    kind = coverage.get(op.name.rsplit(".", 1)[-1], coverage.get(op.kind))
     bits = 8 if (kind is not None and plan.covers(kind)) else 16
     return op.scaled(act_bits=bits, weight_bits=bits)
 

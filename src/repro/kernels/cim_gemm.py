@@ -612,49 +612,74 @@ def cim_gated_gemm_int8(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 # ---------------------------------------------------------------------------
 def _scalar_im(scalar: bool):
     """Index-map adapter for scalar-prefetch grids: with ``scalar`` the
-    grouped kernels' index maps receive the trailing skip-list ref,
-    which plain (e, m, n, k) maps must ignore."""
+    grouped kernels' index maps receive the trailing prefetch refs (the
+    skip list, and the tile groups), which plain (e, m, n, k) maps must
+    ignore."""
     def im(f):
-        return (lambda e, m, n, k, c: f(e, m, n, k)) if scalar else f
+        return (lambda e, m, n, k, *refs: f(e, m, n, k)) if scalar else f
+    return im
+
+
+def _weight_im(scalar: bool, ragged: bool, n_n: int, n_k: int, f):
+    """Index map of a per-expert operand block ``f(e, k, n)``: expert
+    ``e``'s, or with ``ragged`` the expert ``groups[e]`` whose rows tile
+    ``e`` holds.  An empty ragged tile keeps the last (k, n) block, so
+    after the tile before it the pipeline issues no weight DMA for it."""
+    if not ragged:
+        return _scalar_im(scalar)(lambda e, m, n, k: f(e, k, n))
+
+    def im(e, m, n, k, c, g):
+        live = c[e] > 0
+        return f(g[e], jnp.where(live, k, n_k - 1),
+                 jnp.where(live, n, n_n - 1))
     return im
 
 
 def _grouped_specs(block_m: int, block_n: int, block_k: int,
-                   scalar: bool = False):
+                   scalar: bool = False, ragged: bool = False,
+                   n_n: int = 1, n_k: int = 1):
     """BlockSpecs for (x [E,M,K], w [E,K,N], x_scale [E,M,1],
-    w_scale [E,1,N]) with the expert index as the leading grid dim.
-    ``scalar``: index maps take the trailing scalar-prefetch ref
-    (the per-expert skip list)."""
+    w_scale [E,1,N]) with the expert (or ragged row tile) index as the
+    leading grid dim.  ``scalar``: index maps take the trailing
+    scalar-prefetch refs (the skip list; with ``ragged`` also the tile
+    groups)."""
     im = _scalar_im(scalar)
     return [
         pl.BlockSpec((1, block_m, block_k), im(lambda e, m, n, k: (e, m, k))),
-        pl.BlockSpec((1, block_k, block_n), im(lambda e, m, n, k: (e, k, n))),
+        pl.BlockSpec((1, block_k, block_n),
+                     _weight_im(scalar, ragged, n_n, n_k,
+                                lambda e, k, n: (e, k, n))),
         pl.BlockSpec((1, block_m, 1), im(lambda e, m, n, k: (e, m, 0))),
-        pl.BlockSpec((1, 1, block_n), im(lambda e, m, n, k: (e, 0, n))),
+        pl.BlockSpec((1, 1, block_n),
+                     _weight_im(scalar, ragged, n_n, n_k,
+                                lambda e, k, n: (e, 0, n))),
     ]
 
 
 def _grouped_call(kernel, grid, in_specs, out_specs, out_shape,
-                  scratch_shapes, operands, counts, interpret):
+                  scratch_shapes, operands, counts, interpret, groups=None):
     """Dispatch a grouped kernel, with the per-expert ``counts`` skip
-    list as a scalar-prefetch operand when given (empty experts skip
-    all MXU work in their grid cells)."""
+    list (and, ragged, the tile ``groups``) as scalar-prefetch operands
+    when given (empty experts or tiles skip all MXU work in their grid
+    cells)."""
     if counts is None:
         return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                               out_specs=out_specs, out_shape=out_shape,
                               scratch_shapes=scratch_shapes,
                               interpret=interpret)(*operands)
+    prefetch = [counts.astype(jnp.int32)]
+    if groups is not None:
+        prefetch.append(groups.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
         out_specs=out_specs, scratch_shapes=scratch_shapes)
     return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(counts.astype(jnp.int32),
-                                               *operands)
+                          interpret=interpret)(*prefetch, *operands)
 
 
 def _cim_grouped_gemm_kernel(*refs, n_k_steps: int, activation: str | None,
                              has_bias: bool, quantize_out: bool,
-                             has_counts: bool):
+                             has_counts: bool, ragged: bool = False):
     """One (expert, block_m x block_n) output tile; K swept innermost.
 
     With ``has_counts`` the leading ref is the scalar-prefetch skip
@@ -662,10 +687,11 @@ def _cim_grouped_gemm_kernel(*refs, n_k_steps: int, activation: str | None,
     int8 dot products entirely (no MXU work).  The shared epilogue then
     runs on the zero accumulator — exactly what the full pipeline
     produces for all-zero rows (zero-row activations quantize to q=0),
-    so skipping is bit-identical, just cheaper.
+    so skipping is bit-identical, just cheaper.  ``ragged``: a second
+    prefetch ref (the tile groups) follows, read only by index maps.
     """
     if has_counts:
-        c_ref, refs = refs[0], refs[1:]
+        c_ref, refs = refs[0], refs[1 + ragged:]
     x_ref, w_ref, xs_ref, ws_ref = refs[:4]
     i = 4
     b_ref = None
@@ -708,6 +734,7 @@ def _cim_grouped_gemm_kernel(*refs, n_k_steps: int, activation: str | None,
 def cim_grouped_gemm_int8(x: jax.Array, w: jax.Array, x_scale: jax.Array,
                           w_scale: jax.Array, bias: jax.Array | None = None,
                           counts: jax.Array | None = None,
+                          groups: jax.Array | None = None,
                           activation: str | None = None,
                           out_dtype=jnp.float32, quantize_out: bool = False,
                           block_m: int = 256, block_n: int = 2 * CORE_N,
@@ -731,12 +758,19 @@ def cim_grouped_gemm_int8(x: jax.Array, w: jax.Array, x_scale: jax.Array,
     list: grid cells of experts with ``counts[e] == 0`` run no MXU dot
     products (their all-zero capacity rows previously streamed through
     the MXU anyway); outputs stay bit-identical.
+
+    Ragged form: with ``groups`` (int32 [T], needs ``counts``), x is [T,
+    M, K] row tiles and tile t multiplies expert ``groups[t]``'s weights
+    (w [E, K, N] for any E); ``counts[t] == 0`` marks an empty tile,
+    which also fetches no weights (:func:`_weight_im`).
     """
     E, M, K = x.shape
     E2, K2, N = w.shape
-    assert E == E2 and K == K2, (x.shape, w.shape)
+    ragged = groups is not None
+    assert K == K2 and (ragged or E == E2), (x.shape, w.shape)
+    assert not ragged or (counts is not None and groups.shape == (E,))
     assert x_scale.shape == (E, M, 1), x_scale.shape
-    assert w_scale.shape == (E, 1, N), w_scale.shape
+    assert w_scale.shape == (E2, 1, N), w_scale.shape
 
     if quantize_out:
         block_n = N
@@ -752,13 +786,15 @@ def cim_grouped_gemm_int8(x: jax.Array, w: jax.Array, x_scale: jax.Array,
     grid = (E, M // block_m, N // block_n, n_k_steps)
 
     scalar = counts is not None
-    in_specs = _grouped_specs(block_m, block_n, block_k, scalar=scalar)
+    in_specs = _grouped_specs(block_m, block_n, block_k, scalar=scalar,
+                              ragged=ragged, n_n=grid[2], n_k=n_k_steps)
     im = _scalar_im(scalar)
     operands = [x, w, x_scale, w_scale]
     if bias is not None:
-        assert bias.shape == (E, 1, N), bias.shape
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_n), im(lambda e, m, n, k: (e, 0, n))))
+        assert bias.shape == (E2, 1, N), bias.shape
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_n), _weight_im(scalar, ragged, grid[2], n_k_steps,
+                                        lambda e, k, n: (e, 0, n))))
         operands.append(bias)
 
     if quantize_out:
@@ -779,16 +815,18 @@ def cim_grouped_gemm_int8(x: jax.Array, w: jax.Array, x_scale: jax.Array,
     return _grouped_call(
         functools.partial(_cim_grouped_gemm_kernel, n_k_steps=n_k_steps,
                           activation=activation, has_bias=bias is not None,
-                          quantize_out=quantize_out, has_counts=scalar),
+                          quantize_out=quantize_out, has_counts=scalar,
+                          ragged=ragged),
         grid, in_specs, out_specs, out_shape,
         [pltpu.VMEM((block_m, block_n), jnp.int32)],
-        operands, counts, interpret)
+        operands, counts, interpret, groups)
 
 
 def _cim_grouped_gated_kernel(*refs, n_k_steps: int, activation: str,
-                              quantize_out: bool, has_counts: bool):
+                              quantize_out: bool, has_counts: bool,
+                              ragged: bool = False):
     if has_counts:
-        c_ref, refs = refs[0], refs[1:]
+        c_ref, refs = refs[0], refs[1 + ragged:]
     x_ref, wg_ref, wu_ref, xs_ref, gs_ref, us_ref = refs[:6]
     refs = refs[6:]
     out_refs = refs[:-2]
@@ -837,6 +875,7 @@ def cim_grouped_gated_gemm_int8(x: jax.Array, w_gate: jax.Array,
                                 w_up: jax.Array, x_scale: jax.Array,
                                 gate_scale: jax.Array, up_scale: jax.Array,
                                 counts: jax.Array | None = None,
+                                groups: jax.Array | None = None,
                                 activation: str = "gelu",
                                 out_dtype=jnp.float32,
                                 quantize_out: bool = False,
@@ -854,14 +893,17 @@ def cim_grouped_gated_gemm_int8(x: jax.Array, w_gate: jax.Array,
     consumes int8 directly — a full MoE expert layer is then exactly
     three dispatches (quantize + this + grouped down) independent of E.
     ``counts`` (int32 [E], scalar-prefetched) skips both dot products
-    for zero-capacity experts; outputs stay bit-identical.
+    for zero-capacity experts; outputs stay bit-identical.  ``groups``:
+    the ragged form of :func:`cim_grouped_gemm_int8`.
     """
     E, M, K = x.shape
     E2, K2, N = w_gate.shape
-    assert E == E2 and K == K2 and w_up.shape == (E, K, N), \
+    ragged = groups is not None
+    assert K == K2 and w_up.shape == (E2, K, N) and (ragged or E == E2), \
         (x.shape, w_gate.shape, w_up.shape)
+    assert not ragged or (counts is not None and groups.shape == (E,))
     assert x_scale.shape == (E, M, 1), x_scale.shape
-    assert gate_scale.shape == (E, 1, N) and up_scale.shape == (E, 1, N)
+    assert gate_scale.shape == (E2, 1, N) and up_scale.shape == (E2, 1, N)
 
     if quantize_out:
         block_n = N
@@ -877,13 +919,17 @@ def cim_grouped_gated_gemm_int8(x: jax.Array, w_gate: jax.Array,
 
     scalar = counts is not None
     im = _scalar_im(scalar)
+
+    def wim(f):
+        return _weight_im(scalar, ragged, grid[2], n_k_steps, f)
+
     in_specs = [
         pl.BlockSpec((1, block_m, block_k), im(lambda e, m, n, k: (e, m, k))),
-        pl.BlockSpec((1, block_k, block_n), im(lambda e, m, n, k: (e, k, n))),
-        pl.BlockSpec((1, block_k, block_n), im(lambda e, m, n, k: (e, k, n))),
+        pl.BlockSpec((1, block_k, block_n), wim(lambda e, k, n: (e, k, n))),
+        pl.BlockSpec((1, block_k, block_n), wim(lambda e, k, n: (e, k, n))),
         pl.BlockSpec((1, block_m, 1), im(lambda e, m, n, k: (e, m, 0))),
-        pl.BlockSpec((1, 1, block_n), im(lambda e, m, n, k: (e, 0, n))),
-        pl.BlockSpec((1, 1, block_n), im(lambda e, m, n, k: (e, 0, n))),
+        pl.BlockSpec((1, 1, block_n), wim(lambda e, k, n: (e, 0, n))),
+        pl.BlockSpec((1, 1, block_n), wim(lambda e, k, n: (e, 0, n))),
     ]
     if quantize_out:
         out_specs = [
@@ -903,8 +949,9 @@ def cim_grouped_gated_gemm_int8(x: jax.Array, w_gate: jax.Array,
     return _grouped_call(
         functools.partial(_cim_grouped_gated_kernel, n_k_steps=n_k_steps,
                           activation=activation, quantize_out=quantize_out,
-                          has_counts=scalar),
+                          has_counts=scalar, ragged=ragged),
         grid, in_specs, out_specs, out_shape,
         [pltpu.VMEM((block_m, block_n), jnp.int32),
          pltpu.VMEM((block_m, block_n), jnp.int32)],
-        [x, w_gate, w_up, x_scale, gate_scale, up_scale], counts, interpret)
+        [x, w_gate, w_up, x_scale, gate_scale, up_scale], counts, interpret,
+        groups)

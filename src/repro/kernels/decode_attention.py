@@ -484,3 +484,287 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
     )(q_pos.astype(jnp.int32), skip, bt, layer, *operands)
+
+
+# ---------------------------------------------------------------------------
+# Paged latent (MLA) decode: the absorbed form over int8 latent pools.
+# Same block-table / layer-index grid as ``decode_attention_paged``, one
+# grid step per (row, logical block); every head of the row is one MXU
+# operand, so the score and output products are [H, r] x [r, bs] and
+# [H, bs] x [bs, r] matmuls -- bound by compute at 128 heads.
+# ---------------------------------------------------------------------------
+def _mla_last_block(qpos, bs: int, nb: int):
+    """Last logical block a row attends (0 for a row that attends
+    nothing, whose grid steps then re-read one block and skip)."""
+    return jnp.minimum(jnp.maximum(qpos, 0) // bs, nb - 1)
+
+
+def _latent_step(ql, qr, lat, sc, sr, qpos, t0, scale, m_ref, l_ref,
+                 acc_ref):
+    """One online-softmax step of absorbed attention over a block of
+    latents: ql [Q, r] / qr [Q, rope] queries, lat [bs, W] the block's
+    latents and rope keys side by side (int8 or bf16), sc / sr [1, bs]
+    their per-token scales, qpos the queries' positions (a scalar or [Q,
+    1]), t0 the block's first position.  The MXU operands are bf16 (int8
+    values convert exactly), accumulation f32; the scales factor out of
+    both dots.  A query that sees no position of the block takes p = 0."""
+    R, Dr = ql.shape[-1], qr.shape[-1]
+    lat = lat.astype(jnp.bfloat16)
+    c, kr = lat[:, :R], lat[:, R:R + Dr]
+    dims = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(ql.astype(jnp.bfloat16), c, dims,
+                             preferred_element_type=jnp.float32) * sc
+         + jax.lax.dot_general(qr.astype(jnp.bfloat16), kr, dims,
+                               preferred_element_type=jnp.float32) * sr)
+    s = s * scale                                         # [Q, bs]
+    t = t0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    seen = t <= qpos
+    s = jnp.where(seen, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
+    pv = jax.lax.dot_general((p * sc).astype(jnp.bfloat16), c,
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = m_new
+
+
+def _latent_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _mla_decode_kernel(qpos_ref, bt_ref, layer_ref, ql_ref, qr_ref,
+                       lat_ref, s_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                       scale: float, bs: int, n_kv_steps: int):
+    """One (row, block) step.  Blocks: q_lat [1, H, r] and q_rope [1, H,
+    rope]; the block's latents and rope keys side by side [bs, W >= r +
+    rope] (int8 or bf16); their scales [1, 1, 2, bs].  Scratch m/l [H, 1],
+    acc [H, r]."""
+    del bt_ref, layer_ref
+    b, ki = pl.program_id(0), pl.program_id(1)
+    qpos = qpos_ref[b]
+
+    @pl.when(ki == 0)
+    def _init():
+        _latent_init(m_ref, l_ref, acc_ref)
+
+    @pl.when(ki * bs <= qpos)
+    def _step():
+        _latent_step(ql_ref[0], qr_ref[0], lat_ref[...],
+                     s_ref[0, 0, 0:1, :], s_ref[0, 0, 1:2, :], qpos,
+                     ki * bs, scale, m_ref, l_ref, acc_ref)
+
+    @pl.when(ki == n_kv_steps - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def mla_decode_paged(q_lat: jax.Array, q_rope: jax.Array,
+                     latent_pages: jax.Array, c_scale_pages: jax.Array,
+                     r_scale_pages: jax.Array, block_tables: jax.Array,
+                     q_pos: jax.Array, layer: jax.Array, scale: float,
+                     interpret: bool = False) -> jax.Array:
+    """q_lat: [B, H, r] (queries folded through W_UK); q_rope: [B, H,
+    rope]; latent_pages: [L, NB, bs, W], each token's latent and rope key
+    side by side in its first r + rope entries (int8 with per-token
+    scales c_scale_pages /
+    r_scale_pages [L, NB, bs], or bf16 with unit scales); block_tables
+    [B, nb]; q_pos [B] (the row attends positions 0..q_pos; a row at or
+    past the empty sentinel 2**29 attends nothing and returns zeros);
+    layer: int32 scalar.  Returns [B, H, r] in q_lat's dtype:
+    ``softmax(scale * (q_lat.c + q_rope.k_rope)) . c`` per head.
+
+    Grid (B, nb): block ki of row b streams pool block ``[layer,
+    block_tables[b, ki]]`` through the scalar-prefetched table and layer
+    index.  Blocks past the row's last one map to that last block, so
+    the pipeline issues no DMA for them, and their step is skipped.  The
+    row's scales are gathered from the layer's scale pools before the
+    call into [B, nb, 2, bs], so each step reads one lane-dense [2, bs]
+    tile.
+    """
+    B, H, R = q_lat.shape
+    Dr = q_rope.shape[-1]
+    bs, W = latent_pages.shape[2:]
+    nb = block_tables.shape[1]
+    bt = block_tables.astype(jnp.int32)
+    qpos = jnp.where(q_pos < 2 ** 29, q_pos, -1).astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def row_scales(pool):
+        one = jax.lax.dynamic_index_in_dim(pool, layer[0], 0, keepdims=False)
+        return one[bt]                                    # [B, nb, bs]
+
+    scales = jnp.stack([row_scales(c_scale_pages), row_scales(r_scale_pages)],
+                       axis=2)                            # [B, nb, 2, bs]
+
+    def im_q(b, ki, qp, bt_, ly):
+        return (b, 0, 0)
+
+    def im_pool(b, ki, qp, bt_, ly):
+        return (ly[0], bt_[b, jnp.minimum(ki, _mla_last_block(qp[b], bs, nb))],
+                0, 0)
+
+    def im_scale(b, ki, qp, bt_, ly):
+        return (b, jnp.minimum(ki, _mla_last_block(qp[b], bs, nb)), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nb),
+        in_specs=[
+            pl.BlockSpec((1, H, R), im_q),
+            pl.BlockSpec((1, H, Dr), im_q),
+            pl.BlockSpec((pl.Squeezed(), pl.Squeezed(), bs, W), im_pool),
+            pl.BlockSpec((1, 1, 2, bs), im_scale),
+        ],
+        out_specs=pl.BlockSpec((1, H, R), im_q),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, R), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, scale=scale, bs=bs,
+                          n_kv_steps=nb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, R), q_lat.dtype),
+        interpret=interpret,
+    )(qpos, bt, layer, q_lat, q_rope.astype(q_lat.dtype), latent_pages,
+      scales)
+
+
+# ---------------------------------------------------------------------------
+# Paged latent (MLA) chunked prefill: the same absorbed step for every
+# query of a chunk.  A grid step takes a tile of query rows (a few
+# positions x every head) and walks the row's blocks in an in-kernel
+# loop that stops at the tile's last position, DMAing each block from
+# the pool (double-buffered), so a chunk costs its context and not the
+# table's width, and no up-projected K/V or score matrix reaches HBM.
+# ---------------------------------------------------------------------------
+MLA_PREFILL_TILE_ROWS = 512      # query rows (positions x heads) a step
+
+
+def _mla_prefill_kernel(last_ref, bt_ref, layer_ref, ql_ref, qr_ref,
+                        qpos_ref, s_ref, lat_hbm, o_ref, buf, sem, m_ref,
+                        l_ref, acc_ref, *, scale: float, bs: int):
+    """One (row, query tile) step.  Blocks: q_lat [1, Q, r], q_rope [1, Q,
+    rope], the rows' positions [1, Q, 1] (-1: a pad), the row's scales
+    [1, 2, nb, bs]; ``lat_hbm`` the whole pool [L, NB, bs, W] in HBM.
+    Scratch: two block buffers and their DMA semaphores, m/l [Q, 1], acc
+    [Q, r]."""
+    b, t = pl.program_id(0), pl.program_id(1)
+    last = last_ref[b, t]                 # the tile's last position, or -1
+    n = jnp.where(last >= 0, last // bs + 1, 0)
+    layer = layer_ref[0]
+
+    def fetch(i, slot):
+        return pltpu.make_async_copy(lat_hbm.at[layer, bt_ref[b, i]],
+                                     buf.at[slot], sem.at[slot])
+
+    _latent_init(m_ref, l_ref, acc_ref)
+
+    @pl.when(n > 0)
+    def _first():
+        fetch(0, 0).start()
+
+    qpos = qpos_ref[0]
+
+    def body(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n)
+        def _next():
+            fetch(i + 1, 1 - slot).start()
+
+        fetch(i, slot).wait()
+        _latent_step(ql_ref[0], qr_ref[0], buf[slot],
+                     s_ref[0, 0, pl.ds(i, 1), :], s_ref[0, 1, pl.ds(i, 1), :],
+                     qpos, i * bs, scale, m_ref, l_ref, acc_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def mla_prefill_paged(q_lat: jax.Array, q_rope: jax.Array,
+                      latent_pages: jax.Array, c_scale_pages: jax.Array,
+                      r_scale_pages: jax.Array, block_tables: jax.Array,
+                      positions: jax.Array, layer: jax.Array, scale: float,
+                      interpret: bool = False) -> jax.Array:
+    """Causal absorbed attention of a prefill chunk over the paged latent
+    pool, the chunk's own latents already written.
+
+    q_lat [B, S, H, r] (queries folded through W_UK); q_rope [B, S, H,
+    rope]; the pools as for :func:`mla_decode_paged`; block_tables [B,
+    nb]; positions [B, S] (query s attends positions 0..positions[b, s];
+    a position at or past the empty sentinel 2**29 attends nothing and
+    returns zeros); layer: int32 scalar.  Returns [B, S, H, r] in q_lat's
+    dtype, the same per-query math as :func:`mla_decode_paged`.
+
+    Grid (B, query tiles): each tile holds ``MLA_PREFILL_TILE_ROWS // H``
+    positions x all H heads as one MXU operand and loops over blocks
+    0..(its last position) // bs, so blocks past the chunk cost neither a
+    DMA nor a grid step.
+    """
+    B, S, H, R = q_lat.shape
+    Dr = q_rope.shape[-1]
+    bs = latent_pages.shape[2]
+    nb = block_tables.shape[1]
+    ts = max(1, MLA_PREFILL_TILE_ROWS // H)   # positions per tile
+    pad = -S % ts
+    qpos = jnp.where(positions < 2 ** 29, positions, -1).astype(jnp.int32)
+    if pad:
+        q_lat = jnp.pad(q_lat, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q_rope = jnp.pad(q_rope, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        qpos = jnp.pad(qpos, ((0, 0), (0, pad)), constant_values=-1)
+    Sp = S + pad
+    nt, Q = Sp // ts, ts * H
+    last = jnp.max(qpos.reshape(B, nt, ts), axis=-1)       # [B, nt]
+    rows = jnp.repeat(qpos, H, axis=1)[..., None]          # [B, Sp*H, 1]
+    bt = block_tables.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def row_scales(pool):
+        one = jax.lax.dynamic_index_in_dim(pool, layer[0], 0, keepdims=False)
+        return one[bt]                                     # [B, nb, bs]
+
+    scales = jnp.stack([row_scales(c_scale_pages), row_scales(r_scale_pages)],
+                       axis=1)                             # [B, 2, nb, bs]
+
+    def im_rows(b, t, *_):
+        return (b, t, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nt),
+        in_specs=[
+            pl.BlockSpec((1, Q, R), im_rows),
+            pl.BlockSpec((1, Q, Dr), im_rows),
+            pl.BlockSpec((1, Q, 1), im_rows),
+            pl.BlockSpec((1, 2, nb, bs), lambda b, t, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, Q, R), im_rows),
+        scratch_shapes=[pltpu.VMEM((2,) + latent_pages.shape[2:],
+                                   latent_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((Q, 1), jnp.float32),
+                        pltpu.VMEM((Q, 1), jnp.float32),
+                        pltpu.VMEM((Q, R), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, scale=scale, bs=bs),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sp * H, R), q_lat.dtype),
+        interpret=interpret,
+    )(last, bt, layer, q_lat.reshape(B, Sp * H, R),
+      q_rope.astype(q_lat.dtype).reshape(B, Sp * H, Dr), rows, scales,
+      latent_pages)
+    return out.reshape(B, Sp, H, R)[:, :S]

@@ -306,6 +306,7 @@ def cim_quantized_grouped_mlp(x: jax.Array, up_q: jax.Array,
                               gate_q: jax.Array | None = None,
                               gate_scale: jax.Array | None = None,
                               expert_counts: jax.Array | None = None,
+                              groups: jax.Array | None = None,
                               activation: str = "gelu",
                               out_dtype=jnp.float32,
                               interpret: bool | None = None) -> jax.Array:
@@ -327,6 +328,11 @@ def cim_quantized_grouped_mlp(x: jax.Array, up_q: jax.Array,
     scalar-prefetched into both grouped kernels: experts that received
     no tokens skip their MXU dot products instead of streaming all-zero
     capacity rows through the grid — same dispatch count, same bits.
+
+    Ragged form: with ``groups`` (int32 [n_tiles]) x is [n_tiles, tm, d]
+    row tiles, tile t runs expert ``groups[t]``'s weights and
+    ``expert_counts`` is per tile (0: an empty tile, which also fetches
+    no weights).
     """
     interpret = _on_cpu() if interpret is None else interpret
     E, T, d = x.shape
@@ -348,13 +354,13 @@ def cim_quantized_grouped_mlp(x: jax.Array, up_q: jax.Array,
     if gate_q is not None:
         g_p, gs_p, _ = _pad_grouped_weight(gate_q, gate_scale)
         h = cim_grouped_gated_gemm_int8(x_q, g_p, up_p, x_s, gs_p, us_p,
-                                        counts=expert_counts,
+                                        counts=expert_counts, groups=groups,
                                         activation=activation,
                                         quantize_out=fuse_requant,
                                         interpret=interpret)
     else:
         h = cim_grouped_gemm_int8(x_q, up_p, x_s, us_p,
-                                  counts=expert_counts,
+                                  counts=expert_counts, groups=groups,
                                   activation=activation,
                                   quantize_out=fuse_requant,
                                   interpret=interpret)
@@ -372,8 +378,8 @@ def cim_quantized_grouped_mlp(x: jax.Array, up_q: jax.Array,
     down_p, ds_p, _ = _pad_grouped_weight(
         jnp.pad(down_q, ((0, 0), (0, ff_p - d_ff), (0, 0))), down_scale)
     out = cim_grouped_gemm_int8(h_q, down_p, h_s, ds_p,
-                                counts=expert_counts, out_dtype=out_dtype,
-                                interpret=interpret)
+                                counts=expert_counts, groups=groups,
+                                out_dtype=out_dtype, interpret=interpret)
     return out[:, :T, :N]
 
 
@@ -456,6 +462,34 @@ def decode_attention_paged(q, k_pages, v_pages, pos_pages, block_tables,
         q, k_pages, v_pages, pos_pages, block_tables, q_pos, layer,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
         window=window, interpret=interpret)
+
+
+def mla_decode_paged(q_lat, q_rope, latent_pages, c_scale_pages,
+                     r_scale_pages, block_tables, q_pos, layer, scale,
+                     interpret: bool | None = None):
+    """Absorbed MLA decode over one layer of the paged latent pool
+    (``kernels.decode_attention.mla_decode_paged``): q_lat [B, H, r],
+    q_rope [B, H, rope]; pool [L, NB, bs, W >= r + rope] with per-token
+    scales [L, NB, bs] for the latent and the rope key; returns [B, H,
+    r]."""
+    interpret = _on_cpu() if interpret is None else interpret
+    return _da.mla_decode_paged(q_lat, q_rope, latent_pages, c_scale_pages,
+                                r_scale_pages, block_tables, q_pos, layer,
+                                scale=float(scale), interpret=interpret)
+
+
+def mla_prefill_paged(q_lat, q_rope, latent_pages, c_scale_pages,
+                      r_scale_pages, block_tables, positions, layer, scale,
+                      interpret: bool | None = None):
+    """Causal absorbed attention of a prefill chunk over one layer of the
+    paged latent pool (``kernels.decode_attention.mla_prefill_paged``):
+    q_lat [B, S, H, r], q_rope [B, S, H, rope], positions [B, S]; returns
+    [B, S, H, r]."""
+    interpret = _on_cpu() if interpret is None else interpret
+    return _da.mla_prefill_paged(q_lat, q_rope, latent_pages, c_scale_pages,
+                                 r_scale_pages, block_tables, positions,
+                                 layer, scale=float(scale),
+                                 interpret=interpret)
 
 
 def decode_attention_splitkv(q, k, v, pos, q_pos, k_scale=None, v_scale=None,

@@ -1,6 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose references)."""
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -101,7 +102,8 @@ def quantized_mlp_ref(x: jax.Array, qtree: dict, activation: str,
 
 
 def grouped_quantized_mlp_ref(x: jax.Array, qtree: dict, activation: str,
-                              out_dtype=jnp.float32) -> jax.Array:
+                              out_dtype=jnp.float32,
+                              groups: jax.Array | None = None) -> jax.Array:
     """Oracle for the grouped-expert fused int8 MLP pipeline.
 
     x [E, T, d]; ``qtree`` holds stacked per-expert leaves:
@@ -110,7 +112,11 @@ def grouped_quantized_mlp_ref(x: jax.Array, qtree: dict, activation: str,
     :func:`quantized_mlp_ref` vmapped over the expert axis — the grouped
     Pallas kernel must match this (and hence the per-expert loop)
     bit-for-bit, since every step is elementwise or exact int32 math.
+    With ``groups`` (int32 [T]) x is [T, M, d] row tiles and tile t
+    takes expert ``groups[t]``'s leaves (the ragged form).
     """
+    if groups is not None:
+        qtree = jax.tree.map(lambda a: a[groups], qtree)
     return jax.vmap(
         lambda xe, qt: quantized_mlp_ref(xe, qt, activation,
                                          out_dtype=out_dtype))(x, qtree)
@@ -203,3 +209,56 @@ def ssd_scan_ref(x, log_a, b, c):
 
 def online_softmax_ref(x):
     return jax.nn.softmax(x.astype(jnp.float32), axis=-1).astype(x.dtype)
+
+
+def mla_decode_paged_ref(q_lat, q_rope, latent_pages, c_scale_pages,
+                         r_scale_pages, block_tables, q_pos, layer,
+                         scale: float):
+    """Oracle for ``mla_decode_paged``: the same bf16 MXU operands (the
+    dequant scales applied to the f32 products) over the row's gathered
+    latents at layer ``layer``; rows at or past the empty sentinel
+    (2**29) attend nothing and return zeros."""
+    B, nb = block_tables.shape
+    bs = latent_pages.shape[2]
+    R, Dr = q_lat.shape[-1], q_rope.shape[-1]
+    bt = block_tables.astype(jnp.int32)
+
+    def lin(pool):
+        g = pool[layer, bt]                       # [B, nb, bs, ...]
+        return g.reshape(B, nb * bs, *pool.shape[3:])
+
+    lat = lin(latent_pages).astype(jnp.bfloat16)
+    c, kr = lat[..., :R], lat[..., R:R + Dr]
+    sc, sr = lin(c_scale_pages), lin(r_scale_pages)       # [B, T]
+    ql = q_lat.astype(jnp.bfloat16)
+    qr = q_rope.astype(jnp.bfloat16)
+    s = (jnp.einsum("bhr,btr->bht", ql, c,
+                    preferred_element_type=jnp.float32) * sc[:, None]
+         + jnp.einsum("bhk,btk->bht", qr, kr,
+                      preferred_element_type=jnp.float32) * sr[:, None])
+    s = s * scale
+    qpos = jnp.where(q_pos < 2 ** 29, q_pos, -1)
+    t = jnp.arange(nb * bs)
+    ok = t[None, None, :] <= qpos[:, None, None]
+    s = jnp.where(ok, s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bht,btr->bhr", (p * sc[:, None]).astype(jnp.bfloat16), c,
+                   preferred_element_type=jnp.float32)
+    return (o / jnp.maximum(l, 1e-30)).astype(q_lat.dtype)
+
+
+def mla_prefill_paged_ref(q_lat, q_rope, latent_pages, c_scale_pages,
+                          r_scale_pages, block_tables, positions, layer,
+                          scale: float):
+    """Oracle for ``mla_prefill_paged``: :func:`mla_decode_paged_ref` for
+    each query position of the chunk (q_lat [B, S, H, r], positions [B,
+    S]); returns [B, S, H, r]."""
+    one = functools.partial(mla_decode_paged_ref, latent_pages=latent_pages,
+                            c_scale_pages=c_scale_pages,
+                            r_scale_pages=r_scale_pages,
+                            block_tables=block_tables, layer=layer,
+                            scale=scale)
+    return jax.vmap(lambda ql, qr, qp: one(ql, qr, q_pos=qp),
+                    in_axes=(1, 1, 1), out_axes=1)(q_lat, q_rope, positions)

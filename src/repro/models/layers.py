@@ -154,14 +154,49 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** exponent)
 
 
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (arXiv:2309.00071 §3.4, as
+    DeepSeek-V3 publishes it): ``0.1 * mscale * ln(scale) + 1``."""
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_position: int, beta_fast: float,
+                     beta_slow: float) -> jax.Array:
+    """YaRN rope frequencies [head_dim/2]: the rotary dims that turn more
+    than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are
+    divided by ``factor``, and a linear ramp blends the dims between
+    (DeepSeek-V3's ``yarn_find_correction_range`` and
+    ``yarn_linear_ramp_mask``)."""
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                    # 1: original frequency
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+               theta: float = 10000.0, freqs: Optional[jax.Array] = None,
+               scale: float = 1.0) -> jax.Array:
+    """x: [..., seq, heads, head_dim]; positions: [..., seq].  ``freqs``
+    (default: plain rope at ``theta``) and ``scale`` (multiplies sin and
+    cos: YaRN's mscale ratio) override the rotation."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                  # [hd/2]
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)              # [hd/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, hd/2]
-    sin = jnp.sin(angles)[..., :, None, :]
-    cos = jnp.cos(angles)[..., :, None, :]
+    sin = scale * jnp.sin(angles)[..., :, None, :]
+    cos = scale * jnp.cos(angles)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

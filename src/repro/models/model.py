@@ -83,10 +83,14 @@ def block_init(key, spec: tuple[str, str], cfg: ModelConfig) -> dict:
 
 def block_apply(params: dict, spec: tuple[str, str], cfg: ModelConfig,
                 x: jax.Array, positions: jax.Array,
-                cache: Optional[dict], prefix_len) -> tuple:
-    """Returns (x, new_cache, aux_loss)."""
+                cache: Optional[dict], prefix_len,
+                train: bool = False) -> tuple:
+    """Returns (x, new_cache, aux_loss, load): ``load`` is the MoE
+    layer's held-expert load (:func:`moe.held_load` over the rows with a
+    real position: int32 [3]), zeros for other layers."""
     mixer, ffn = spec
     aux = jnp.zeros((), jnp.float32)
+    load = jnp.zeros((3,), jnp.int32)
 
     h = norm_apply(cfg.norm, params["mixer_norm"], x)
     new_cache = None
@@ -106,9 +110,9 @@ def block_apply(params: dict, spec: tuple[str, str], cfg: ModelConfig,
             prefix_len=prefix_len, rope_theta=cfg.rope_theta, cache=cache,
             residual=x)
     elif mixer == "mla":
-        out, new_cache = mla_mod.mla_apply(
+        x, new_cache = mla_mod.mla_apply(
             params["mla"], h, positions, cfg.mla, rope_theta=cfg.rope_theta,
-            cache=cache)
+            cache=cache, residual=x)
     elif mixer == "mamba2":
         out, new_cache = ssm_mod.mamba2_apply(params["mamba"], h, cfg.ssm,
                                               cache=cache)
@@ -118,7 +122,7 @@ def block_apply(params: dict, spec: tuple[str, str], cfg: ModelConfig,
     elif mixer == "slstm":
         out, new_cache = xlstm_mod.slstm_block_apply(params["slstm"], h,
                                                      cfg.xlstm, cache=cache)
-    if mixer not in ("attn", "attn_local"):
+    if mixer not in ("attn", "attn_local", "mla"):
         x = x + out
 
     if ffn == "dense":
@@ -126,9 +130,11 @@ def block_apply(params: dict, spec: tuple[str, str], cfg: ModelConfig,
         x = mlp_apply(params["mlp"], h, cfg.activation, residual=x)
     elif ffn == "moe":
         h = norm_apply(cfg.norm, params["ffn_norm"], x)
-        out, aux = moe_mod.moe_apply(params["moe"], h, cfg.moe, cfg.activation)
+        out, aux, load = moe_mod.moe_layer(
+            params["moe"], h, cfg.moe, cfg.activation, train=train,
+            valid=positions < 2 ** 29)
         x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, load
 
 
 def block_cache_init(spec: tuple[str, str], cfg: ModelConfig, batch: int,
@@ -318,7 +324,7 @@ class Model:
         return shard(x, ("batch", "act_seq", None)), prefix_len
 
     def _stack(self, params, x, positions, caches, prefix_len,
-               decode: bool = False):
+               decode: bool = False, train: bool = False):
         """Run all layer groups. caches: None or dict group_i -> stacked.
 
         Each group is one ``jax.lax.scan`` over its stacked layers, with
@@ -332,10 +338,14 @@ class Model:
         writing it back whole.  Its per-row block tables and write index
         stay in ``xs``/``ys``.  Ring caches, recurrent state and the
         cache-less forward carry no pools.
+
+        Returns (x, new caches, summed aux loss, held-expert loads: int32
+        [n_moe_layers, 3], one row per MoE layer in depth order).
         """
         cfg = self.cfg
         total_aux = jnp.zeros((), jnp.float32)
         new_caches = {} if caches is not None else None
+        loads = []
 
         for gi, (spec, count) in enumerate(self.groups):
             gparams = params[f"group_{gi}"]
@@ -352,24 +362,29 @@ class Model:
                 lparams, lcache = layer_in
                 if pools:
                     lcache = {**lcache, **pools, "layer": layer}
-                x, ncache, a = block_apply(lparams, spec, cfg, x, positions,
-                                           lcache, prefix_len)
+                x, ncache, a, load = block_apply(lparams, spec, cfg, x,
+                                                 positions, lcache,
+                                                 prefix_len, train)
                 x = shard(x, ("batch", "act_seq", None))
                 if pools:
                     pools = {k: ncache[k] for k in pools}
                     ncache = {k: a for k, a in ncache.items()
                               if k not in pools}
-                return (x, aux + a, pools, layer + 1), ncache
+                return (x, aux + a, pools, layer + 1), (ncache, load)
 
             if cfg.remat and not decode:
                 body = jax.checkpoint(body)
 
-            (x, total_aux, pools, _), ncache = jax.lax.scan(
+            (x, total_aux, pools, _), (ncache, load) = jax.lax.scan(
                 body, (x, total_aux, pools, jnp.zeros((), jnp.int32)),
                 (gparams, gcache))
             if new_caches is not None:
                 new_caches[f"group_{gi}"] = {**ncache, **pools}
-        return x, new_caches, total_aux
+            if spec[1] == "moe":
+                loads.append(load)
+        loads = (jnp.concatenate(loads) if loads
+                 else jnp.zeros((0, 3), jnp.int32))
+        return x, new_caches, total_aux, loads
 
     def _head(self, params, x):
         if self.cfg.tie_embeddings:
@@ -378,14 +393,23 @@ class Model:
 
     def forward(self, params, batch, caches=None, positions=None,
                 decode: bool = False, head: bool = True,
-                last_only: bool = False, last_index=None):
+                last_only: bool = False, last_index=None,
+                train: bool = False):
+        """(logits or features, new caches, aux loss)."""
+        return self._forward(params, batch, caches, positions, decode, head,
+                             last_only, last_index, train)[:3]
+
+    def _forward(self, params, batch, caches=None, positions=None,
+                 decode: bool = False, head: bool = True,
+                 last_only: bool = False, last_index=None,
+                 train: bool = False):
         cfg = self.cfg
         x, prefix_len = self._embed_inputs(params, batch)
         B, S = x.shape[:2]
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        x, new_caches, aux = self._stack(params, x, positions, caches,
-                                         prefix_len, decode)
+        x, new_caches, aux, loads = self._stack(params, x, positions, caches,
+                                                prefix_len, decode, train)
         x = norm_apply(cfg.norm, params["final_norm"], x)
         if last_only:
             x = x[:, -1:]
@@ -396,9 +420,9 @@ class Model:
                 lambda xi, i: jax.lax.dynamic_slice_in_dim(xi, i, 1, 0)
             )(x, last_index.astype(jnp.int32))
         if not head:
-            return x, new_caches, aux
+            return x, new_caches, aux, loads
         logits = shard(self._head(params, x), ("batch", "act_seq", "vocab"))
-        return logits, new_caches, aux
+        return logits, new_caches, aux, loads
 
     # -- training ----------------------------------------------------------
     LOSS_CHUNK_BUDGET = 2 ** 26   # logits elements per chunk (global)
@@ -418,7 +442,8 @@ class Model:
         command-r 256k vocab x 1M tokens); each chunk is rematerialized in
         the backward pass (jax.checkpoint)."""
         cfg = self.cfg
-        feats, _, aux = self.forward(params, batch, head=False)
+        # training keeps the MoE capacity path (models/moe.py)
+        feats, _, aux = self.forward(params, batch, head=False, train=True)
         targets = batch["targets"]
         if cfg.frontend == "vision":
             feats = feats[:, -targets.shape[1]:]
@@ -516,12 +541,19 @@ class Model:
     def decode_step(self, params, batch, caches):
         """One (or a few, for speculative verify) new tokens per sequence
         against existing caches."""
+        return self.decode_step_with_load(params, batch, caches)[:2]
+
+    def decode_step_with_load(self, params, batch, caches):
+        """:meth:`decode_step` plus the step's held-expert loads: int32
+        [n_moe_layers, 3] (pairs routed to held experts, most rows on
+        one held expert, held experts with any row) over the rows that decode (a position below
+        the empty sentinel)."""
         idx = self._cache_index(caches)          # [B] per-slot positions
         S = self._step_len(batch)
         positions = (idx[:, None] + jnp.arange(S)[None, :]).astype(jnp.int32)
-        logits, caches, _ = self.forward(params, batch, caches=caches,
-                                         positions=positions, decode=True)
-        return logits, caches
+        logits, caches, _, loads = self._forward(
+            params, batch, caches=caches, positions=positions, decode=True)
+        return logits, caches, loads
 
     def _step_len(self, batch) -> int:
         for k in ("inputs", "frame_embeddings"):
@@ -606,24 +638,32 @@ class Model:
 
     def init_paged_cache(self, batch: int, num_blocks: int, block_size: int,
                          max_blocks: int, kv_dtype=None):
-        """Paged (block-table) KV caches for the continuously-batched
+        """Paged (block-table) caches for the continuously-batched
         serving engine: every attention layer gets its own pool of
         ``num_blocks`` fixed-size blocks (block 0 reserved as the
         all-empty null block) plus per-row block tables of width
-        ``max_blocks``.  Only attention mixers page; recurrent mixers
-        have no position-keyed cache to page."""
+        ``max_blocks``; an MLA layer's pools hold its latent and rope
+        key per token (``mla.init_paged_latent_cache``) under the same
+        tables.  Recurrent mixers have no position-keyed cache to
+        page."""
         kv = kv_dtype or self.cfg.kv_cache_dtype
         dt = jnp.int8 if kv == "int8" else jnp.bfloat16
         caches = {}
         for gi, (spec, count) in enumerate(self.groups):
             mixer = spec[0]
-            if mixer not in ("attn", "attn_local"):
+            if mixer == "mla":
+                one = mla_mod.init_paged_latent_cache(
+                    batch, num_blocks, block_size, max_blocks, self.cfg.mla,
+                    dtype=dt)
+            elif mixer in ("attn", "attn_local"):
+                one = attn_mod.init_paged_kv_cache(
+                    batch, num_blocks, block_size, max_blocks,
+                    self.cfg.n_kv_heads, self.cfg.head_dim, dtype=dt)
+            else:
                 raise NotImplementedError(
                     f"paged KV cache: unsupported mixer {mixer!r} (only "
-                    f"attention layers hold a position-keyed cache)")
-            one = attn_mod.init_paged_kv_cache(
-                batch, num_blocks, block_size, max_blocks,
-                self.cfg.n_kv_heads, self.cfg.head_dim, dtype=dt)
+                    f"attention and MLA layers hold a position-keyed "
+                    f"cache)")
             caches[f"group_{gi}"] = jax.tree.map(
                 lambda a: jnp.broadcast_to(a[None], (count, *a.shape)).copy()
                 if hasattr(a, "shape") else a, one)
@@ -633,8 +673,10 @@ class Model:
         kv = kv_dtype or self.cfg.kv_cache_dtype
         axes = {}
         for gi, (spec, _) in enumerate(self.groups):
-            one = attn_mod.paged_kv_cache_logical_axes(
-                quantized=kv == "int8")
+            one = (mla_mod.paged_latent_cache_logical_axes()
+                   if spec[0] == "mla" else
+                   attn_mod.paged_kv_cache_logical_axes(
+                       quantized=kv == "int8"))
             axes[f"group_{gi}"] = jax.tree.map(
                 lambda a: ("layers", *a) if isinstance(a, tuple) else a, one,
                 is_leaf=lambda a: isinstance(a, tuple))

@@ -55,6 +55,18 @@ class Observability:
             "pool_exhaustions_total", "KV pool allocation failures")
         self.chaos_total = r.counter(
             "chaos_injections_total", "chaos faults injected, by kind")
+        self.moe_rows_held_total = r.counter(
+            "moe_rows_held_total",
+            "decode (row, expert) pairs routed to this chip's experts, "
+            "by MoE layer")
+        self.moe_rows_max_held_total = r.counter(
+            "moe_rows_max_held_total",
+            "most decode rows on one held expert per step, summed, by "
+            "MoE layer")
+        self.moe_experts_touched_total = r.counter(
+            "moe_experts_touched_total",
+            "held experts with any decode row per step, summed, by MoE "
+            "layer")
         self.dispatches_total = r.counter(
             "dispatches_total",
             "modeled Pallas dispatches by manifest site class")
@@ -197,6 +209,13 @@ class Observability:
             self._book_price(t, price(kv_len))
             emit("decode", now, uid=req.uid, kv_len=kv_len)
         self._flush_energy()
+
+    def on_moe_load(self, load) -> None:
+        """One decode step's held-expert load, [n_moe_layers, 3]."""
+        for i, (rows, top, touched) in enumerate(load):
+            self.moe_rows_held_total.inc(float(rows), layer=str(i))
+            self.moe_rows_max_held_total.inc(float(top), layer=str(i))
+            self.moe_experts_touched_total.inc(float(touched), layer=str(i))
 
     def on_token(self, req, token: int, now: float) -> None:
         t = self._trace(req)

@@ -277,6 +277,32 @@ def quantize_attention(attn_params: dict, qkv: bool = True,
     return p
 
 
+def quantize_mla(mla_params: dict, proj: bool = True,
+                 out: bool = True) -> dict:
+    """Quantize one MLA layer's projections.
+
+    ``q_down [d, q_lora]`` and ``kv_down [d, kv_lora + rope]`` take the
+    same input, so they fuse into one ``"down"`` :class:`QuantizedLinear`
+    ([d, q_lora + kv_lora + rope] int8, one wide GEMM, split after);
+    ``q_up [q_lora, H, nope + rope]`` is stored flat ([q_lora, H * (nope
+    + rope)], the layout its GEMM reads, so no layer relays it out);
+    ``o [H, v, d]`` as the attention out-projection.
+    ``kv_up`` (W_UK / W_UV), the norms and anything already quantized
+    pass through.
+    """
+    p = dict(mla_params)
+    if proj and "q_down" in p:
+        wide = jnp.concatenate([p.pop("q_down"), p.pop("kv_down")], axis=-1)
+        p["down"] = quantize_linear(wide)
+        wq = p["q_up"]
+        p["q_up"] = quantize_linear(wq.reshape(wq.shape[0], -1))
+    if out and "o" in p and not isinstance(p.get("o"), QuantizedLinear):
+        wo = p["o"]
+        flat = quantize_linear(wo.reshape(-1, wo.shape[-1]))
+        p["o"] = QuantizedLinear(flat.q.reshape(wo.shape), flat.scale)
+    return p
+
+
 def quantized_qkv_proj(qkv: QuantizedLinear, x: jax.Array,
                        use_kernel: bool | None = None) -> jax.Array:
     """One wide fused GEMM for all of q/k/v: x [..., d] -> [..., HK, Dh].
@@ -363,7 +389,8 @@ def quantize_moe_experts(moe_params: dict) -> dict:
 
 def quantized_moe_apply(qparams: dict, x: jax.Array, activation: str,
                         use_kernel: bool | None = False,
-                        expert_counts: jax.Array | None = None) -> jax.Array:
+                        expert_counts: jax.Array | None = None,
+                        groups: jax.Array | None = None) -> jax.Array:
     """Grouped-expert fused INT8 MLPs: x [E, T, d] -> [E, T, d].
 
     ALL experts' capacity buffers run the fused pipeline in a **constant
@@ -385,13 +412,17 @@ def quantized_moe_apply(qparams: dict, x: jax.Array, activation: str,
     bits.  Under a model-axis sharding context the pipeline runs
     expert-parallel: every device serves its E/p experts' stacks.
 
+    Ragged form (``groups``, int32 [n_tiles]): x is [n_tiles, tm, d] row
+    tiles, tile t runs expert ``groups[t]`` and ``expert_counts`` is per
+    tile (0: empty); it runs unsharded.
+
     use_kernel=False runs the bit-identical grouped jnp oracle; None
     auto-selects by backend (or per :func:`kernel_mode`).
     """
     use_kernel = _resolve_use_kernel(use_kernel)
     act = _canon_activation(activation)
     gate = qparams.get("gate")
-    mesh = _tp_mesh_for(x.shape[0])
+    mesh = None if groups is not None else _tp_mesh_for(x.shape[0])
     if mesh is not None:
         out = _tp.grouped_moe(mesh, x, qparams, act, use_kernel,
                               expert_counts=expert_counts)
@@ -401,14 +432,14 @@ def quantized_moe_apply(qparams: dict, x: jax.Array, activation: str,
             qparams["down"].q, qparams["down"].scale,
             gate_q=None if gate is None else gate.q,
             gate_scale=None if gate is None else gate.scale,
-            expert_counts=expert_counts, activation=act)
+            expert_counts=expert_counts, groups=groups, activation=act)
     else:
         qtree = {k: (v.q, v.scale) for k, v in qparams.items()
                  if k in ("up", "gate", "down")}
-        out = kref.grouped_quantized_mlp_ref(x, qtree, act)
+        out = kref.grouped_quantized_mlp_ref(x, qtree, act, groups=groups)
     out = _screen(out, lambda: kref.grouped_quantized_mlp_ref(
         _san(x), {k: (v.q, _san(v.scale)) for k, v in qparams.items()
-                  if k in ("up", "gate", "down")}, act))
+                  if k in ("up", "gate", "down")}, act, groups=groups))
     return out.astype(x.dtype)
 
 

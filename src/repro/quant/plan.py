@@ -27,6 +27,15 @@ Pallas pipeline:
                                                  epilogue — diffusion
                                                  blocks only, see
                                                  models/dit.py)
+    ``mla_proj``     MLA q_a/kv_a + q_b         (q_a and kv_a as ONE wide
+                                                 fused GEMM on the block
+                                                 input, q_b a second;
+                                                 kv_b = W_UK/W_UV stays
+                                                 bf16, folded around the
+                                                 latent decode kernel)
+    ``mla_out``      MLA out-projection         (one fused GEMM with the
+                                                 block residual added in
+                                                 its epilogue)
     ``attn_kv``      decode KV cache + GEMVs    (KV stored int8 at the
                                                  cache-update site, the
                                                  flash-decode kernel
@@ -35,7 +44,9 @@ Pallas pipeline:
                                                  this kind covers the
                                                  cache dtype and the
                                                  QK/SV attention GEMVs'
-                                                 simulator costing)
+                                                 simulator costing; on an
+                                                 MLA layer, the int8
+                                                 latent pools)
 
 :func:`apply_plan` rewrites covered weights into
 :class:`~repro.quant.linear.QuantizedLinear` leaves; the model layers
@@ -60,11 +71,11 @@ from dataclasses import dataclass
 
 import jax
 
-from .linear import (QuantizedLinear, quantize_attention, quantize_mlp,
-                     quantize_moe_experts)
+from .linear import (QuantizedLinear, quantize_attention, quantize_mla,
+                     quantize_mlp, quantize_moe_experts)
 
 LAYER_KINDS = ("mlp", "attn_qkv", "attn_out", "attn_kv", "moe_experts",
-               "adaln")
+               "adaln", "mla_proj", "mla_out")
 
 # The layer kinds a DiT (diffusion-transformer) block draws on: the adaLN
 # modulation GEMM plus the same attention/MLP projections as a dense LLM
@@ -79,12 +90,14 @@ def covered_kinds(mixer: str, ffn: str) -> tuple[str, ...]:
     The single source of truth for plan coverage: ``apply_plan`` (what
     gets quantized), ``QuantPlan.layer_table`` (reporting), and the
     simulator bridge (what gets costed at INT8) all derive from it.
-    MLA/SSM/xLSTM mixers are not covered — their projections stay bf16
-    until the kernels learn them (ROADMAP follow-up).
+    SSM/xLSTM mixers are not covered — their projections stay bf16
+    until the kernels learn them (ROADMAP R2).
     """
     kinds: list[str] = []
     if mixer in ("attn", "attn_local"):
         kinds += ["attn_qkv", "attn_out", "attn_kv"]
+    elif mixer == "mla":
+        kinds += ["mla_proj", "mla_out", "attn_kv"]
     if ffn == "dense":
         kinds += ["mlp"]
     elif ffn == "moe":
@@ -107,6 +120,8 @@ class QuantPlan:
     attn_kv: bool = True
     moe_experts: bool = True
     adaln: bool = True
+    mla_proj: bool = True
+    mla_out: bool = True
 
     # -- constructors ----------------------------------------------------
     @classmethod
@@ -124,7 +139,8 @@ class QuantPlan:
         """PR 1 behaviour: only dense-FFN MLPs quantized (the
         ``quantize_mlp=True`` deprecation shim maps here)."""
         return cls(mlp=True, attn_qkv=False, attn_out=False,
-                   attn_kv=False, moe_experts=False, adaln=False)
+                   attn_kv=False, moe_experts=False, adaln=False,
+                   mla_proj=False, mla_out=False)
 
     # -- queries ---------------------------------------------------------
     def covers(self, kind: str) -> bool:
@@ -189,6 +205,11 @@ def apply_plan(groups, params, plan: QuantPlan):
                 lambda p: quantize_attention(p, qkv="attn_qkv" in kinds,
                                              out="attn_out" in kinds)
             )(group["attn"])
+        if ({"mla_proj", "mla_out"} & set(kinds)) and "mla" in group:
+            group["mla"] = jax.vmap(
+                lambda p: quantize_mla(p, proj="mla_proj" in kinds,
+                                       out="mla_out" in kinds)
+            )(group["mla"])
         if "mlp" in kinds and "mlp" in group:
             group["mlp"] = jax.vmap(quantize_mlp)(group["mlp"])
         if "moe_experts" in kinds and "moe" in group:
@@ -232,6 +253,22 @@ def attn_plan_axes(attn: dict, qkv: bool = True, out: bool = True) -> dict:
     return attn
 
 
+def mla_plan_axes(mla: dict, proj: bool = True, out: bool = True) -> dict:
+    """Logical-axes rewrite for one MLA layer (the axes mirror of
+    :func:`~repro.quant.linear.quantize_mla`)."""
+    mla = dict(mla)
+    if proj and "q_down" in mla:
+        qa = mla.pop("q_down")              # [*, d, q_lora]
+        mla.pop("kv_down")
+        mla["down"] = q_scale_axes(qa)
+        ua = mla["q_up"]                    # [*, q_lora, H, qk] -> flat
+        mla["q_up"] = q_scale_axes(ua[:-1])
+    if out and "o" in mla:
+        oa = mla["o"]
+        mla["o"] = QuantizedLinear(q=oa, scale=oa[:-3] + oa[-1:])
+    return mla
+
+
 def mlp_plan_axes(mlp: dict) -> dict:
     """Logical-axes rewrite for one (dense or DiT) MLP's weight leaves."""
     return {k: q_scale_axes(a) if k in ("up", "down", "gate") else a
@@ -263,6 +300,10 @@ def plan_axes(groups, axes, plan: QuantPlan):
             group["attn"] = attn_plan_axes(group["attn"],
                                            qkv="attn_qkv" in kinds,
                                            out="attn_out" in kinds)
+        if ({"mla_proj", "mla_out"} & set(kinds)) and "mla" in group:
+            group["mla"] = mla_plan_axes(group["mla"],
+                                         proj="mla_proj" in kinds,
+                                         out="mla_out" in kinds)
         if "mlp" in kinds and "mlp" in group:
             group["mlp"] = mlp_plan_axes(group["mlp"])
         if "moe_experts" in kinds and "moe" in group:
@@ -290,6 +331,14 @@ def plan_is_applied(groups, params, plan: QuantPlan) -> bool:
                 return False
             if plan.attn_out and not isinstance(attn.get("o"),
                                                 QuantizedLinear):
+                return False
+        if mixer == "mla" and "mla" in group:
+            mla = group["mla"]
+            if plan.mla_proj and not isinstance(mla.get("down"),
+                                                QuantizedLinear):
+                return False
+            if plan.mla_out and not isinstance(mla.get("o"),
+                                               QuantizedLinear):
                 return False
         if ffn == "dense" and plan.mlp and "mlp" in group:
             if not isinstance(group["mlp"].get("up"), QuantizedLinear):

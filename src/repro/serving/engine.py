@@ -89,6 +89,13 @@ class EngineStats:
     pool_exhaustions: int = 0   # KV pool allocation failures (grow/admit)
     evicted_blocks: int = 0     # blocks freed by preemption evictions
     cache_utilization: list = field(default_factory=list)
+    # held-expert load per MoE layer (depth order), summed over decode
+    # steps: (row, expert) pairs routed to this chip's experts, the
+    # most rows on one of them, and how many of them got any row (paged
+    # engine; None without MoE layers)
+    moe_rows_held: Optional[np.ndarray] = None
+    moe_rows_max_held: Optional[np.ndarray] = None
+    moe_experts_touched: Optional[np.ndarray] = None
 
 
 class ServingEngine:
@@ -730,9 +737,11 @@ class PagedServingEngine(ServingEngine):
 
             cache = jax.tree_util.tree_map_with_path(mask_idx, cache)
             with step_ctx():
-                logits, cache = model.decode_step(
+                logits, cache, load = model.decode_step_with_load(
                     params, {"inputs": last_tokens[:, None]}, cache)
-            return logits[:, 0], cache
+            # what the host fetches (logits and the MoE layers' held-
+            # expert load, one transfer), then the donated pools
+            return (logits[:, 0], load), cache
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def scrub(cache, blocks):
@@ -888,6 +897,19 @@ class PagedServingEngine(ServingEngine):
                     return False
                 self._preempt(victim)
 
+    def _count_moe_load(self, load: np.ndarray) -> None:
+        """Sum one decode step's held-expert load ([n_moe_layers, 3])."""
+        st = self.stats
+        if st.moe_rows_held is None:
+            st.moe_rows_held = np.zeros(len(load), np.int64)
+            st.moe_rows_max_held = np.zeros(len(load), np.int64)
+            st.moe_experts_touched = np.zeros(len(load), np.int64)
+        st.moe_rows_held += load[:, 0]
+        st.moe_rows_max_held += load[:, 1]
+        st.moe_experts_touched += load[:, 2]
+        if self.obs is not None:
+            self.obs.on_moe_load(load)
+
     def _maybe_finish(self, slot: int, req: Request, tok: int) -> None:
         if ((req.eos_id is not None and tok == req.eos_id)
                 or len(req.generated) >= req.max_new_tokens
@@ -987,13 +1009,15 @@ class PagedServingEngine(ServingEngine):
                 self.stats.batch_occupancy.append(len(ok) / self.n_slots)
                 mask = np.zeros(self.n_slots, bool)
                 mask[ok] = True
-                logits, self.cache = self._decode_masked(
+                fetched, self.cache = self._decode_masked(
                     self.params, self.cache, jnp.asarray(self.slot_last),
                     jnp.asarray(mask), self._tables())
         if ok:
             with span("engine.decode.fetch"):
-                logits = np.asarray(logits)
+                logits, load = jax.device_get(fetched)
             self.stats.decode_steps += 1
+            if len(load):
+                self._count_moe_load(load)
             with span("engine.decode.sample"):
                 logits = self._apply_fault_hook("decode", logits)
                 if self.obs is not None:

@@ -28,8 +28,9 @@ class TestBridge:
         """graph_from_config(quant_plan=...) must cost exactly what
         apply_plan quantizes: attn/attn_local projections INT8, the
         KV-cache GEMVs INT8 when ``attn_kv`` covers them (int8 KV
-        streamed through the flash-decode kernel), MLA bf16 (not
-        covered by the kernels), MoE shared experts follow
+        streamed through the flash-decode kernel), MLA projections INT8
+        under ``mla_proj``/``mla_out`` with W_UK/W_UV and the latent
+        products bf16, MoE shared experts follow
         ``moe_experts``, router/head bf16."""
         from repro.quant import QuantPlan
         full = QuantPlan.full()
@@ -58,12 +59,18 @@ class TestBridge:
         assert by_kind[OpKind.ATTN_QK] == {16}
         assert by_kind[OpKind.QKV] == {8}
 
-        # MLA (deepseek) emits QKV/PROJ kinds but the kernels keep MLA
-        # in bf16 — the simulator must agree.
+        # MLA (deepseek): mla_proj / mla_out put q_down, q_up, kv_down
+        # and o on the int8 pipeline; W_UK/W_UV (q_absorb, v_up) and the
+        # latent score/value products stay bf16 — the simulator agrees.
         g = graph_from_config(get_config("deepseek-v3-671b"), 4, 1, 512,
                               quant_plan=full)
-        assert {o.act_bits for o in g.matmuls
-                if o.kind in (OpKind.QKV, OpKind.PROJ)} == {16}
+        mla_bits = {o.name.rsplit(".", 1)[-1]: o.act_bits
+                    for o in g.matmuls if ".mla." in o.name}
+        assert {k: mla_bits[k] for k in ("q_down", "q_up", "kv_down",
+                                         "o")} == dict.fromkeys(
+            ("q_down", "q_up", "kv_down", "o"), 8)
+        assert {mla_bits[k] for k in ("q_absorb", "v_up", "qk",
+                                      "sv")} == {16}
         assert {o.act_bits for o in g.matmuls
                 if o.kind == OpKind.MOE_FFN} == {8}
         assert {o.act_bits for o in g.matmuls if o.kind == OpKind.FFN

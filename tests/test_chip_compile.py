@@ -113,6 +113,38 @@ def test_ring_decode_attention(compile_v5e, n_splits):
         ((B, S), jnp.int32), ((B,), jnp.int32), scale, scale)
 
 
+def test_mla_decode_paged(compile_v5e):
+    """The latent decode kernel at DeepSeek-V3's widths as the benchmark
+    serves it: 32 rows, 128 heads, latent 512 + rope 64 in 640-wide pool
+    rows, 256-token blocks over 16,384 positions, a 4-layer stack."""
+    from repro.kernels import ops
+    B, H, R, Dr, W, L, bs, nb = 32, 128, 512, 64, 640, 4, 256, 64
+    NB = 1 + B * nb
+    compile_v5e(
+        lambda ql, qr, lat, sc, sr, bt, qp, ly: ops.mla_decode_paged(
+            ql, qr, lat, sc, sr, bt, qp, ly, scale=0.1),
+        ((B, H, R), jnp.bfloat16), ((B, H, Dr), jnp.bfloat16),
+        ((L, NB, bs, W), jnp.int8), ((L, NB, bs), jnp.float32),
+        ((L, NB, bs), jnp.float32), ((B, nb), jnp.int32),
+        ((B,), jnp.int32), ((), jnp.int32))
+
+
+def test_mla_prefill_paged(compile_v5e):
+    """The latent chunked-prefill kernel at DeepSeek-V3's widths as the
+    benchmark serves it: one row's 256-token chunk, 128 heads, the same
+    pool and tables as the decode kernel."""
+    from repro.kernels import ops
+    S, H, R, Dr, W, L, bs, nb = 256, 128, 512, 64, 640, 4, 256, 64
+    NB = 1 + 32 * nb
+    compile_v5e(
+        lambda ql, qr, lat, sc, sr, bt, pos, ly: ops.mla_prefill_paged(
+            ql, qr, lat, sc, sr, bt, pos, ly, scale=0.1),
+        ((1, S, H, R), jnp.bfloat16), ((1, S, H, Dr), jnp.bfloat16),
+        ((L, NB, bs, W), jnp.int8), ((L, NB, bs), jnp.float32),
+        ((L, NB, bs), jnp.float32), ((1, nb), jnp.int32),
+        ((1, S), jnp.int32), ((), jnp.int32))
+
+
 def test_grouped_moe_mlp(compile_v5e):
     """The grouped SwiGLU expert MLP over every expert's capacity rows."""
     from repro.kernels import ops
@@ -125,6 +157,23 @@ def test_grouped_moe_mlp(compile_v5e):
         ((E, T, MOE_D), jnp.bfloat16), w_in, s_in,
         ((E, MOE_FF, MOE_D), jnp.int8), ((E, MOE_D), jnp.float32),
         w_in, s_in)
+
+
+def test_ragged_moe_mlp(compile_v5e):
+    """The ragged form at DeepSeek-V3's expert widths as a 16k-token
+    prefill would run it on one chip's 8 experts: 520 row tiles of 256
+    rows, each against its tile's expert, with the skip list."""
+    from repro.kernels import ops
+    E, n_tiles, tm, d, F = 8, 520, 256, 7168, 2048
+    w_in = ((E, d, F), jnp.int8)
+    s_in = ((E, F), jnp.float32)
+    compile_v5e(
+        lambda x, u, us, dn, ds, g, gs, c, gr: ops.cim_quantized_grouped_mlp(
+            x, u, us, dn, ds, gate_q=g, gate_scale=gs, expert_counts=c,
+            groups=gr, activation="silu"),
+        ((n_tiles, tm, d), jnp.bfloat16), w_in, s_in,
+        ((E, F, d), jnp.int8), ((E, d), jnp.float32), w_in, s_in,
+        ((n_tiles,), jnp.int32), ((n_tiles,), jnp.int32))
 
 
 def test_dit_mlp(compile_v5e):

@@ -348,6 +348,33 @@ class TestQuantizedMoE:
         assert (np.asarray(skipped)[1] == 0).all()
         assert (np.asarray(skipped)[3] == 0).all()
 
+    @pytest.mark.parametrize("gated", [True, False])
+    def test_ragged_tiles_match_their_experts_bitwise(self, gated):
+        """Ragged form: row tile t runs expert ``groups[t]``'s weights,
+        bit-for-bit the dense pipeline on that expert; an empty tile
+        (skip list 0) reads zeros, and the jnp oracle agrees."""
+        from repro.quant import QuantizedLinear, quantized_mlp_apply
+        E, d, F, tm = 3, 36, 24, 32
+        qparams = self._moe_weights(E, d, F, key=13, gated=gated)
+        groups = jnp.array([0, 0, 2, 1, 1], jnp.int32)
+        live = jnp.array([1, 1, 1, 1, 0], jnp.int32)
+        x = jax.random.normal(jax.random.PRNGKey(14), (5, tm, d)) * 0.5
+        x = x.at[4].set(0.0)
+        act = "swiglu" if gated else "gelu"
+        got = quantized_moe_apply(qparams, x, act, use_kernel=True,
+                                  expert_counts=live, groups=groups)
+        oracle = quantized_moe_apply(qparams, x, act, use_kernel=False,
+                                     expert_counts=live, groups=groups)
+        for t in range(4):
+            one = {k: QuantizedLinear(v.q[int(groups[t])],
+                                      v.scale[int(groups[t])])
+                   for k, v in qparams.items()}
+            want = quantized_mlp_apply(one, x[t], act, use_kernel=True)
+            assert (np.asarray(got[t]) == np.asarray(want)).all()
+        assert (np.asarray(got[4]) == 0).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(oracle),
+                                   rtol=1e-4, atol=1e-5)
+
     def test_skip_list_keeps_dispatch_count(self):
         """The skip list rides the existing grouped dispatches as a
         scalar-prefetch operand — no extra Pallas kernels, and the

@@ -834,7 +834,7 @@ class _SlicedPoolsModel(Model):
     stacks read at layer 0)."""
 
     def _stack(self, params, x, positions, caches, prefix_len,
-               decode=False):
+               decode=False, train=False):
         new_caches = {}
         for gi, (spec, _) in enumerate(self.groups):
             def body(x, layer_in, spec=spec):
@@ -842,14 +842,15 @@ class _SlicedPoolsModel(Model):
                 pools = [k for k in lcache if k.endswith("_pages")]
                 lcache = {**lcache, **{k: lcache[k][None] for k in pools},
                           "layer": jnp.zeros((), jnp.int32)}
-                x, ncache, _ = block_apply(lparams, spec, self.cfg, x,
-                                           positions, lcache, prefix_len)
+                x, ncache, _, _ = block_apply(lparams, spec, self.cfg, x,
+                                              positions, lcache, prefix_len)
                 return x, {k: a[0] if k in pools else a
                            for k, a in ncache.items()}
 
             x, new_caches[f"group_{gi}"] = jax.lax.scan(
                 body, x, (params[f"group_{gi}"], caches[f"group_{gi}"]))
-        return x, new_caches, jnp.zeros((), jnp.float32)
+        return (x, new_caches, jnp.zeros((), jnp.float32),
+                jnp.zeros((0, 2), jnp.int32))
 
 
 class TestCarriedPools:
