@@ -79,9 +79,12 @@ class BlockInfo:
     block_shape: tuple      # mapped/squeezed dims normalized to 1
     array_shape: tuple
     dtype: Any
+    in_vmem: bool = True    # False: left in HBM for the kernel's own DMAs
 
     @property
     def nbytes(self) -> int:
+        if not self.in_vmem:
+            return 0
         return math.prod(self.block_shape) * jnp.dtype(self.dtype).itemsize
 
 
@@ -121,14 +124,18 @@ def _block_infos(eqn) -> tuple:
         out.append(BlockInfo(
             block_shape=tuple(_block_dim(d) for d in bm.block_shape),
             array_shape=tuple(aval.shape),
-            dtype=aval.dtype))
+            dtype=aval.dtype,
+            in_vmem=str(getattr(bm.block_aval, "memory_space", None)
+                        or "vmem") == "vmem"))
     return tuple(out)
 
 
 def _scratch_bytes(eqn) -> int:
+    """VMEM scratch bytes; DMA semaphores and SMEM scalars take none."""
     gm = eqn.params.get("grid_mapping")
     return sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize
-               for a in getattr(gm, "scratch_avals", ()) or ())
+               for a in getattr(gm, "scratch_avals", ()) or ()
+               if str(getattr(a, "memory_space", None) or "vmem") == "vmem")
 
 
 def pallas_sites(jx) -> list:

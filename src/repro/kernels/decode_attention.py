@@ -30,7 +30,13 @@ Three additions over the plain streaming kernel:
   renormalization terms are exact identities (``exp(0) == 1``), so it
   matches the single-dispatch kernel bit-for-bit.
 
-Grid: (B, kv_blocks) — kv innermost (splitkv: (B, NS, blocks)).  One
+The paged kernel (``decode_attention_paged``) walks a block-table cache
+instead: one grid step per row, an in-kernel loop over the row's live
+compute blocks of several pool pages each (``paged_block_pages``), every
+page DMA'd from the pool in HBM into a double-buffered VMEM block, then
+the same online-softmax step over the whole block.
+
+Ring grid: (B, kv_blocks) — kv innermost (splitkv: (B, NS, blocks)).  One
 grid step takes every KV head of a KV block: Mosaic tiles the last two
 dims of a block, so a block may not take one head of the KV-head axis
 (second-to-last in [.., KH, D]).  A static loop over the heads runs the
@@ -49,6 +55,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -384,23 +391,96 @@ def decode_attention_splitkv(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Paged (block-table) flash-decode: same online-softmax walk, but each KV
-# block is fetched through a scalar-prefetched per-sequence block table
-# instead of a contiguous slice — the kernel side of the paged KV cache
-# (serving/paged_cache.py).  Pools are sequence-free and stacked over the
-# layers of a scan group: [L, NB, bs, KH, D], read at a prefetched layer
-# index, so the layer scan never slices a one-layer pool out of the stack.
+# Paged (block-table) flash-decode: the same online-softmax step over a
+# paged KV cache (serving/paged_cache.py).  Pools are sequence-free and
+# stacked over the layers of a scan group: [L, NB, bs, KH, D], read at a
+# prefetched layer index, so the layer scan never slices a one-layer pool
+# out of the stack.  A grid step takes one row and walks only its live
+# compute blocks of several pool pages each, DMAing every page from the
+# pool in HBM into a double-buffered VMEM block.
 # ---------------------------------------------------------------------------
-def _decode_paged_kernel(qpos_ref, skip_ref, bt_ref, layer_ref, *refs,
-                         scale: float, window, n_kv_steps: int,
-                         quantized: bool):
-    """The block table and layer index are consumed by the index maps
-    only (they route the DMA); the kernel body is exactly the ring
-    kernel's — that shared body plus a shared skip mask is what makes
-    paged == ring bit-identical on equivalent layouts."""
-    del bt_ref, layer_ref
-    _decode_kernel(qpos_ref, skip_ref, *refs, scale=scale, window=window,
-                   n_kv_steps=n_kv_steps, quantized=quantized)
+PAGED_BLOCK_TOKENS = 512         # K/V tokens a compute block aims to hold
+
+
+def paged_block_pages(bs: int, nb: int) -> int:
+    """Pool pages per compute block of :func:`decode_attention_paged`:
+    about ``PAGED_BLOCK_TOKENS`` tokens of ``bs``-token pages, and no more
+    pages than the table's ``nb``."""
+    return max(1, min(nb, PAGED_BLOCK_TOKENS // bs))
+
+
+def paged_walk_pages(n_tokens, bs: int, nb: int):
+    """Table entries the causal walk of :func:`decode_attention_paged`
+    covers for a row attending positions ``0..n_tokens - 1`` (int or
+    array): the pages of its compute blocks up to the last live one."""
+    pages = paged_block_pages(bs, nb)
+    return np.minimum(nb, -(-n_tokens // (pages * bs)) * pages)
+
+
+def _decode_paged_kernel(qpos_ref, first_ref, last_ref, bt_ref, layer_ref,
+                         q_ref, k_hbm, v_hbm, pos_ref, *refs, scale: float,
+                         window, pages: int, quantized: bool):
+    """One row.  Blocks: q [1, KH, G, D]; the row's positions [1, nc, T]
+    and, int8, its scales [1, KH, nc, T]; ``k_hbm``/``v_hbm`` the whole
+    pools [L, NB, bs, KH, D] in HBM.  Scratch: two [T, KH, D] buffers each
+    for K and V (T = pages * bs), their DMA semaphores, m/l [KH, G, 1],
+    acc [KH, G, D].
+
+    Compute blocks ``first..last`` of the row are walked in order; the
+    next block's page copies start before the current block is computed.
+    The step per KV head is the ring kernel's, so at ``block_k == T`` on
+    an equivalent layout the two agree bit for bit.  A block inside the
+    range that the keep mask drops is computed all the same, which is
+    exact: every probability of a fully masked block underflows to 0.0
+    once a kept block has set the running max.
+    """
+    if quantized:
+        ks_ref, vs_ref, *refs = refs
+    o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = refs
+    b = pl.program_id(0)
+    first, last, layer = first_ref[b], last_ref[b], layer_ref[0]
+    bs = k_hbm.shape[2]
+
+    def copies(c, slot):
+        out = []
+        for p in range(pages):
+            page, rows = bt_ref[b, c * pages + p], pl.ds(p * bs, bs)
+            out += [pltpu.make_async_copy(pool.at[layer, page],
+                                          buf.at[slot, rows], sem.at[slot])
+                    for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf))]
+        return out
+
+    _init_state(m_ref, l_ref, acc_ref)
+    for cp in copies(first, 0):
+        cp.start()
+    qpos = qpos_ref[b]
+
+    def body(c, carry):
+        slot = (c - first) % 2
+
+        @pl.when(c < last)
+        def _next():
+            for cp in copies(c + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(c, slot):
+            cp.wait()
+        kpos = pos_ref[0, c]
+        for h in range(q_ref.shape[1]):
+            m, l, acc = _attend_block(
+                q_ref[0, h], kbuf[slot, :, h, :], vbuf[slot, :, h, :], kpos,
+                qpos, m_ref[h], l_ref[h], acc_ref[h], scale=scale,
+                window=window,
+                k_scale=ks_ref[0, h, c] if quantized else None,
+                v_scale=vs_ref[0, h, c] if quantized else None)
+            m_ref[h] = m
+            l_ref[h] = l
+            acc_ref[h] = acc
+        return carry
+
+    jax.lax.fori_loop(first, last + 1, body, 0)
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -418,72 +498,76 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     layer to attend over.
 
     ``k_scale_pages``/``v_scale_pages`` [L, NB, bs, KH] f32 turn on the
-    int8-KV path (pools must then be int8).  Grid (B, nb): block ki of
-    row b streams pool block ``[layer, block_tables[b, ki]]`` (all KV
-    heads) via the scalar-prefetched table and layer index (the layer
-    dim is squeezed out of every pool block), runs the ring kernel's
-    online-softmax step, and the skip list (computed from the gathered
-    per-block positions) elides fully-masked blocks exactly as on the
-    ring path.
+    int8-KV path (pools must then be int8).
+
+    Grid (B,).  The table is cut into compute blocks of ``P =``
+    :func:`paged_block_pages` pages (T = P * bs tokens; entries past
+    ``nb`` read the null block).  Each row's first and last kept compute
+    block come from the ring kernel's keep mask over the gathered
+    positions; row b walks only that range, copying the P pages of each
+    block from pool layer ``layer`` into VMEM (double-buffered), and runs
+    the ring kernel's online-softmax step over all T tokens for every KV
+    head.  A row that sees nothing keeps every block, as on the ring
+    path.  The row's positions and scales are gathered from the
+    layer's pools before the call into lane-dense [B, nc, T] and [B, KH,
+    nc, T] rows.
     """
     B, KH, G, D = q.shape
-    NB, bs = pos_pages.shape[1:]
+    bs = pos_pages.shape[2]
     nb = block_tables.shape[1]
+    pages = paged_block_pages(bs, nb)
+    nc = -(-nb // pages)
+    T = pages * bs
     scale = 1.0 / math.sqrt(D)
     quantized = k_scale_pages is not None
-    bt = block_tables.astype(jnp.int32)
+    bt = jnp.pad(block_tables.astype(jnp.int32),
+                 ((0, 0), (0, nc * pages - nb)))
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    # K/V stream from the stacked pools at the layer index.  The position
-    # and scale pools end in small dims ([bs] and [bs, KH]) that XLA
-    # stores in a compact transposed layout and Mosaic reads only in the
-    # padded row-major one, so every call relays them out: taking their
-    # layer first keeps that to one layer's bytes, not the stack's.
-    def one_layer(a):
-        return jax.lax.dynamic_index_in_dim(a, layer[0], 0, keepdims=False)
+    # The position and scale pools end in small dims ([bs] and [bs, KH])
+    # that XLA stores in a compact transposed layout, so a gather from
+    # them relays out its operand: taking the layer first keeps that to
+    # one layer's bytes, not the stack's.
+    def rows(pool):
+        one = jax.lax.dynamic_index_in_dim(pool, layer[0], 0, keepdims=False)
+        return one[bt].reshape(B, nc, T, *pool.shape[3:])
 
-    pos_layer = one_layer(pos_pages)
-    skip = _keep_blocks(pos_layer[bt], q_pos, window)
+    pos = rows(pos_pages)
+    keep = _keep_blocks(pos, q_pos, window) > 0
+    blk = jnp.arange(nc, dtype=jnp.int32)
+    first = jnp.min(jnp.where(keep, blk, nc), axis=1)
+    last = jnp.max(jnp.where(keep, blk, -1), axis=1)
 
-    def im_q(b, ki, qp, sk, bt, ly):
+    def row_scales(pool):
+        return rows(pool).transpose(0, 3, 1, 2)             # [B, KH, nc, T]
+
+    def im_row(b, *_):
         return (b, 0, 0, 0)
 
-    def im_kv(b, ki, qp, sk, bt, ly):
-        return (ly[0], bt[b, ki], 0, 0, 0)
-
-    def im_pos(b, ki, qp, sk, bt, ly):
-        return (bt[b, ki], 0, 0)
-
-    def im_scale(b, ki, qp, sk, bt, ly):
-        return (bt[b, ki], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, KH, G, D), im_q),
-        pl.BlockSpec((pl.Squeezed(), 1, bs, KH, D), im_kv),
-        pl.BlockSpec((pl.Squeezed(), 1, bs, KH, D), im_kv),
-        pl.BlockSpec((1, 1, bs), im_pos),
-    ]
+    in_specs = [pl.BlockSpec((1, KH, G, D), im_row),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, nc, T), lambda b, *_: (b, 0, 0))]
+    operands = [q, k_pages, v_pages, pos]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, KH), im_scale),
-                     pl.BlockSpec((1, bs, KH), im_scale)]
-
+        in_specs += [pl.BlockSpec((1, KH, nc, T), im_row)] * 2
+        operands += [row_scales(k_scale_pages), row_scales(v_scale_pages)]
+    buf = pltpu.VMEM((2, T, KH, D), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, nb),
+        num_scalar_prefetch=5,
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KH, G, D), im_q),
-        scratch_shapes=_state_scratch(KH, G, D),
+        out_specs=pl.BlockSpec((1, KH, G, D), im_row),
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))]
+        + _state_scratch(KH, G, D),
     )
-    operands = (q, k_pages, v_pages, pos_layer.reshape(NB, 1, bs)) \
-        + ((one_layer(k_scale_pages), one_layer(v_scale_pages))
-           if quantized else ())
     return pl.pallas_call(
         functools.partial(_decode_paged_kernel, scale=scale, window=window,
-                          n_kv_steps=nb, quantized=quantized),
+                          pages=pages, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), skip, bt, layer, *operands)
+    )(q_pos.astype(jnp.int32), first, last, bt, layer, *operands)
 
 
 # ---------------------------------------------------------------------------

@@ -449,10 +449,11 @@ def decode_attention_paged(q, k_pages, v_pages, pos_pages, block_tables,
     ``block_tables`` [B, nb] int32 maps each row's logical blocks to
     physical pool blocks (0 = the reserved all-empty null block).
     ``k_scale_pages``/``v_scale_pages`` [L, NB, bs, KH] f32 turn on the
-    int8-KV path (in-kernel dequant).  Bit-identical to
-    :func:`decode_attention` at ``block_k == bs`` on equivalent layouts
-    (same online-softmax body, same skip mask — pinned in
-    tests/test_serving.py).
+    int8-KV path (in-kernel dequant).  Each row walks only its live
+    compute blocks of ``paged_block_pages(bs, nb)`` pages.  Bit-identical
+    to :func:`decode_attention` at ``block_k`` = that block in tokens, on
+    equivalent layouts (same online-softmax step, same keep mask —
+    pinned in tests/test_serving.py).
     """
     interpret = _on_cpu() if interpret is None else interpret
     if k_scale_pages is None and k_pages.dtype != q.dtype:

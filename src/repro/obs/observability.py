@@ -67,6 +67,13 @@ class Observability:
             "moe_experts_touched_total",
             "held experts with any decode row per step, summed, by MoE "
             "layer")
+        self.kv_pages_walked_total = r.counter(
+            "kv_pages_walked_total",
+            "block-table entries the paged decode kernel walks for the "
+            "decoding rows, per full-attention layer")
+        self.kv_pages_table_total = r.counter(
+            "kv_pages_table_total",
+            "block-table entries of the decoding rows, per layer")
         self.dispatches_total = r.counter(
             "dispatches_total",
             "modeled Pallas dispatches by manifest site class")
@@ -216,6 +223,12 @@ class Observability:
             self.moe_rows_held_total.inc(float(rows), layer=str(i))
             self.moe_rows_max_held_total.inc(float(top), layer=str(i))
             self.moe_experts_touched_total.inc(float(touched), layer=str(i))
+
+    def on_kv_walk(self, walked: int, table: int) -> None:
+        """One decode step's paged-kernel walk: table entries walked and
+        table entries of the decoding rows."""
+        self.kv_pages_walked_total.inc(float(walked))
+        self.kv_pages_table_total.inc(float(table))
 
     def on_token(self, req, token: int, now: float) -> None:
         t = self._trace(req)

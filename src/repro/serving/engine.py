@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_attention import paged_walk_pages
 from repro.obs.tracing import span, step_span, trace_gc
 
 from .lifecycle import (EngineStallError, LifecycleMixin,
@@ -96,6 +97,12 @@ class EngineStats:
     moe_rows_held: Optional[np.ndarray] = None
     moe_rows_max_held: Optional[np.ndarray] = None
     moe_experts_touched: Optional[np.ndarray] = None
+    # paged decode kernel walk, summed over decode steps (paged engine
+    # with attention layers): table entries of the decoding rows' live
+    # compute blocks in one full-attention layer, and the entries of
+    # those rows' tables
+    kv_pages_walked: int = 0
+    kv_pages_table: int = 0
 
 
 class ServingEngine:
@@ -645,6 +652,8 @@ class PagedServingEngine(ServingEngine):
                                   rules=self.rules)
         # the engine owns the pools from here: every step donates them
         cache, self.paged.cache = self.paged.cache, None
+        self._walks_kv = any(spec[0] in ("attn", "attn_local")
+                             for spec, _ in self.model.groups)
         return cache
 
     def _tables(self):
@@ -910,6 +919,19 @@ class PagedServingEngine(ServingEngine):
         if self.obs is not None:
             self.obs.on_moe_load(load)
 
+    def _count_kv_walk(self, rows: list) -> None:
+        """Count one decode step's paged-kernel walk from the slots'
+        positions on the host (a sliding-window layer walks fewer)."""
+        if not self._walks_kv:
+            return
+        bs, nb = self.paged.block_size, self.paged.max_blocks
+        walked = int(paged_walk_pages(self.slot_pos[rows] + 1, bs, nb).sum())
+        table = nb * len(rows)
+        self.stats.kv_pages_walked += walked
+        self.stats.kv_pages_table += table
+        if self.obs is not None:
+            self.obs.on_kv_walk(walked, table)
+
     def _maybe_finish(self, slot: int, req: Request, tok: int) -> None:
         if ((req.eos_id is not None and tok == req.eos_id)
                 or len(req.generated) >= req.max_new_tokens
@@ -1009,6 +1031,7 @@ class PagedServingEngine(ServingEngine):
                 self.stats.batch_occupancy.append(len(ok) / self.n_slots)
                 mask = np.zeros(self.n_slots, bool)
                 mask[ok] = True
+                self._count_kv_walk(ok)
                 fetched, self.cache = self._decode_masked(
                     self.params, self.cache, jnp.asarray(self.slot_last),
                     jnp.asarray(mask), self._tables())

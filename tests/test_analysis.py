@@ -257,6 +257,32 @@ class TestVmemMutations:
         assert passes.vmem_audit(jt.pallas_sites(clean)) == []
 
 
+    def test_hbm_operands_and_semaphores_take_no_vmem(self):
+        """The paged decode kernel leaves its pools in HBM and DMAs their
+        pages itself: its footprint is the blocks it maps into VMEM and
+        its VMEM scratch, not the pools, DMA semaphores or SMEM scalars,
+        so a pool far over the budget still passes."""
+        B, KH, G, D, bs, nb, L, NB = 2, 2, 2, 128, 16, 4, 2, 4097
+        pool = jax.ShapeDtypeStruct((L, NB, bs, KH, D), jnp.int8)
+        scales = jax.ShapeDtypeStruct((L, NB, bs, KH), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda q, k, v, pos, bt, qp, ks, vs: ops.decode_attention_paged(
+                q, k, v, pos, bt, qp, 0, k_scale_pages=ks, v_scale_pages=vs,
+                interpret=True))(
+            jax.ShapeDtypeStruct((B, KH, G, D), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((L, NB, bs), jnp.int32),
+            jax.ShapeDtypeStruct((B, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32), scales, scales)
+        (site,) = jt.pallas_sites(jaxpr)
+        assert [b.nbytes for b in site.blocks][1:3] == [0, 0]
+        T = nb * bs                        # one compute block: the table
+        buffers = 2 * 2 * T * KH * D       # K and V, double-buffered int8
+        state = 4 * KH * G * (D + 2)       # m, l, acc in f32
+        assert site.scratch_bytes == buffers + state
+        budget = L * NB * bs * KH * D      # one pool's bytes
+        assert passes.vmem_audit([site], budget_bytes=budget) == []
+
+
 # ---------------------------------------------------------------------------
 # Pass 5: retrace guard
 # ---------------------------------------------------------------------------

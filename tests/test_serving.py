@@ -21,6 +21,7 @@ from hypothesis import strategies as st
 from conftest import run_forced_devices_subprocess as _run_subprocess
 from repro.configs import get_config, reduced_config
 from repro.kernels import ops as kops
+from repro.kernels.decode_attention import paged_block_pages
 from repro.kernels.ref import decode_attention_paged_ref, decode_attention_ref
 from repro.models import build_model
 from repro.models.model import Model, block_apply
@@ -410,16 +411,22 @@ def _ring_and_pages(B, S, KH, G, D, bs, seed, int8=False, n_empty=0,
     return q, q_pos, ring, paged
 
 
+def _compute_block(paged, bs):
+    """The paged kernel's compute block in tokens for these tables."""
+    nb = paged["block_tables"].shape[1]
+    return paged_block_pages(bs, nb) * bs
+
+
 class TestPagedDecodeKernel:
-    """The paged kernel shares the online-softmax body and skip mask
-    with the ring kernel, so at ``block_k == bs`` on equivalent layouts
-    the two are *bit-identical* — and both match the dense oracle."""
+    """The paged kernel shares the online-softmax step and keep mask
+    with the ring kernel, so at ``block_k`` = the paged kernel's compute
+    block on equivalent layouts the two are *bit-identical* — and both match the dense oracle."""
 
     def _run_both(self, q, q_pos, ring, paged, bs, window=None):
         ring_out = kops.decode_attention(
             q, ring["k"], ring["v"], ring["pos"], q_pos,
             k_scale=ring.get("k_scale"), v_scale=ring.get("v_scale"),
-            window=window, block_k=bs, n_splits=1)
+            window=window, block_k=_compute_block(paged, bs), n_splits=1)
         paged_out = kops.decode_attention_paged(
             q, paged["k_pages"], paged["v_pages"], paged["pos_pages"],
             paged["block_tables"], q_pos, 0,
@@ -518,17 +525,80 @@ class TestPagedDecodeKernel:
             q, ring["k"], ring["v"], ring["pos"], q_pos))
         np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("kv,window", [("bf16", None), ("bf16", 7),
-                                           ("int8", None), ("int8", 7)])
-    def test_stacked_pools_at_layer_equal_one_layer(self, kv, window):
-        """The kernel and its oracle read layer ``l`` of a 3-layer pool
-        stack exactly as they read that layer's pools alone, and the
-        kernel equals the ring kernel on the equivalent layout, bit for
-        bit.  The other layers hold other sequences' pools under other
-        tables, so a read of the wrong layer shows."""
+    # Many-page compute blocks: 64-token pages, so a compute block holds
+    # 8 pages (512 tokens) and these tables hold 2-3 blocks.
+    def test_final_block_mixes_live_and_null_pages(self):
+        """Rows end inside their last compute block, whose remaining
+        table entries are the null block: the walk copies and masks
+        them, bit for bit as the ring kernel's last block."""
+        q, q_pos, ring, paged = _ring_and_pages(
+            B=3, S=1024, KH=2, G=2, D=8, bs=64, seed=30, int8=True,
+            lengths=[700, 1024, 513])
+        tables = np.asarray(paged["block_tables"])
+        assert _compute_block(paged, 64) == 512
+        assert tables[0, 8] > 0 and (tables[0, 11:] == 0).all()
+        r, p = self._run_both(q, q_pos, ring, paged, bs=64)
+        assert (r == p).all()
+        oracle = np.asarray(decode_attention_ref(
+            q, ring["k"], ring["v"], ring["pos"], q_pos, k_scale=ring[
+                "k_scale"], v_scale=ring["v_scale"]))
+        np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [700, 1000])
+    def test_window_starts_mid_compute_block(self, window):
+        """A sliding window whose first visible position lies inside a
+        compute block (the second for 700, so the walk skips the first;
+        the first for 1000): the walk agrees with the ring kernel bit
+        for bit."""
+        q, q_pos, ring, paged = _ring_and_pages(
+            B=2, S=1536, KH=2, G=2, D=8, bs=64, seed=31,
+            lengths=[1400, 1536])
+        r, p = self._run_both(q, q_pos, ring, paged, bs=64, window=window)
+        assert (r == p).all()
+        oracle = np.asarray(decode_attention_ref(
+            q, ring["k"], ring["v"], ring["pos"], q_pos, window=window))
+        np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("kv", ["f32", "int8"])
+    def test_one_token_full_and_empty_rows(self, kv):
+        """One batch walks one block (a 1-token row), every block (a
+        full table) and every block over the null block (a row that sees
+        nothing keeps every block): all equal the ring kernel."""
+        q, q_pos, ring, paged = _ring_and_pages(
+            B=3, S=1024, KH=2, G=4, D=8, bs=64, seed=32,
+            int8=kv == "int8", lengths=[1, 1024, 0])
+        assert (np.asarray(paged["block_tables"])[2] == 0).all()
+        r, p = self._run_both(q, q_pos, ring, paged, bs=64)
+        assert np.isfinite(p).all()
+        assert (r == p).all()
+
+    @pytest.mark.parametrize("kv,window", [("f32", None), ("int8", None),
+                                           ("f32", 300)])
+    def test_table_not_a_multiple_of_compute_block(self, kv, window):
+        """20 pages of 64 tokens against 8-page compute blocks: the last
+        block pads the table with null entries.  The ring kernel needs
+        whole blocks, so the oracle judges."""
+        q, q_pos, ring, paged = _ring_and_pages(
+            B=3, S=1280, KH=2, G=2, D=8, bs=64, seed=33,
+            int8=kv == "int8", lengths=[1280, 1100, 40])
+        nb = paged["block_tables"].shape[1]
+        assert nb % paged_block_pages(64, nb) != 0
+        p = np.asarray(kops.decode_attention_paged(
+            q, paged["k_pages"], paged["v_pages"], paged["pos_pages"],
+            paged["block_tables"], q_pos, 0,
+            k_scale_pages=paged.get("k_scale_pages"),
+            v_scale_pages=paged.get("v_scale_pages"), window=window))
+        oracle = np.asarray(decode_attention_paged_ref(
+            q, paged["k_pages"], paged["v_pages"], paged["pos_pages"],
+            paged["block_tables"], q_pos, 0, window=window,
+            k_scale_pages=paged.get("k_scale_pages"),
+            v_scale_pages=paged.get("v_scale_pages")))
+        np.testing.assert_allclose(p, oracle, rtol=2e-5, atol=2e-5)
+
+    def _stacked_case(self, kv, window, S, bs, lengths):
         int8 = kv == "int8"
-        layers = [_ring_and_pages(B=3, S=32, KH=2, G=2, D=8, bs=8, seed=20 + l,
-                                  int8=int8, lengths=[32, 19, 9])
+        layers = [_ring_and_pages(B=3, S=S, KH=2, G=2, D=8, bs=bs,
+                                  seed=20 + l, int8=int8, lengths=lengths)
                   for l in range(3)]
         names = ["k_pages", "v_pages", "pos_pages"] + (
             ["k_scale_pages", "v_scale_pages"] if int8 else [])
@@ -556,7 +626,7 @@ class TestPagedDecodeKernel:
             ring_out = kops.decode_attention(
                 q, cast(ring["k"]), cast(ring["v"]), ring["pos"], q_pos,
                 k_scale=ring.get("k_scale"), v_scale=ring.get("v_scale"),
-                window=window, block_k=8, n_splits=1)
+                window=window, block_k=_compute_block(paged, bs), n_splits=1)
             assert (out == np.asarray(ring_out)).all()
             oracle = call(decode_attention_paged_ref, stack, l)
             assert (oracle == call(decode_attention_paged_ref, one, 0)).all()
@@ -564,6 +634,22 @@ class TestPagedDecodeKernel:
                 out.astype(np.float32), oracle.astype(np.float32),
                 rtol=2e-2 if kv == "bf16" else 2e-5,
                 atol=2e-2 if kv == "bf16" else 2e-5)
+
+    @pytest.mark.parametrize("kv,window", [("bf16", None), ("bf16", 7),
+                                           ("int8", None), ("int8", 7)])
+    def test_stacked_pools_at_layer_equal_one_layer(self, kv, window):
+        """The kernel and its oracle read layer ``l`` of a 3-layer pool
+        stack exactly as they read that layer's pools alone, and the
+        kernel equals the ring kernel on the equivalent layout, bit for
+        bit.  The other layers hold other sequences' pools under other
+        tables, so a read of the wrong layer shows."""
+        self._stacked_case(kv, window, S=32, bs=8, lengths=[32, 19, 9])
+
+    @pytest.mark.parametrize("kv,window", [("bf16", None), ("int8", 600)])
+    def test_stacked_pools_at_layer_many_blocks(self, kv, window):
+        """The same with two 8-page compute blocks per table."""
+        self._stacked_case(kv, window, S=1024, bs=64,
+                           lengths=[1024, 700, 60])
 
     def test_tp_paged_decode_parity(self):
         """Head-parallel paged flash-decode (quant/tp.py) == unsharded
@@ -643,6 +729,36 @@ class TestPagedServingEngine:
         assert eng.paged.allocator.n_used == 0
         assert (eng.paged.tables == 0).all()
         assert eng.stats.prefill_chunks >= eng.stats.prefills
+
+    def test_kv_walk_counter_sums_live_pages(self, small_model):
+        """The walked-pages counter is, over every decode row of a short
+        serve, the pages of the row's live compute blocks (64-token
+        pages, 8 to a block, 16-entry tables), and the table counter is
+        the decoding rows' table width; obs carries the same sums."""
+        from repro.obs import Observability
+        cfg, m, params = small_model
+        obs = Observability()
+        eng = self._engine(m, params, max_len=1024, block_size=64,
+                           prefill_chunk=256, obs=obs)
+        rng = np.random.default_rng(1)
+        reqs = [Request(uid=i, prompt=rng.integers(1, cfg.vocab, n).astype(
+                    np.int32), max_new_tokens=20)
+                for i, n in enumerate((500, 30, 1000))]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_done(max_iters=200)
+        assert all(r.status is RequestStatus.OK for r in reqs)
+        nb, block = 16, 8 * 64
+        assert paged_block_pages(64, nb) == 8
+        # decode step j of a request attends its prompt and j tokens
+        lens = [len(r.prompt) + j for r in reqs
+                for j in range(1, len(r.generated))]
+        walked = sum(min(nb, -(-n // block) * 8) for n in lens)
+        assert walked < nb * len(lens)          # row 1 walks one block
+        assert eng.stats.kv_pages_walked == walked
+        assert eng.stats.kv_pages_table == nb * len(lens)
+        assert obs.kv_pages_walked_total.value() == walked
+        assert obs.kv_pages_table_total.value() == nb * len(lens)
 
     def test_greedy_matches_stepwise_forward(self, small_model):
         """Paged-engine greedy decode == naive full-forward argmax."""
