@@ -30,15 +30,11 @@
 # installed, stdlib-AST fallback otherwise).
 # `make verify` is the pre-push check: lint + fast tests + docs-check +
 # the multi-device TP suite + the attention suite + the serving suite +
-# the DiT suite + the chaos/reliability suite + the contract audit,
-# plus a BENCH smoke run (simulator + serving
-# rows; merges into
-# BENCH_kernels.json without clobbering the kernel rows — a full
-# `make bench` additionally prunes rows for renamed/deleted benches and
-# measures the resilience_ber_* chaos rows).
+# the DiT suite + the chaos/reliability suite + the contract audit.
+# Speed is measured on the chip only, by `bench/` (see BENCHMARK.json).
 PY := PYTHONPATH=src python
 
-.PHONY: test test-fast test-tp test-dit test-chaos test-attn test-serving test-obs bench verify docs-check audit lint
+.PHONY: test test-fast test-tp test-dit test-chaos test-attn test-serving test-obs verify docs-check audit lint
 
 test:
 	$(PY) -m pytest -x -q
@@ -77,9 +73,4 @@ audit:
 lint:
 	$(PY) tools/lint.py
 
-bench:
-	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-	$(PY) -m benchmarks.run
-
 verify: lint test-fast docs-check test-tp test-attn test-serving test-obs test-dit test-chaos audit
-	$(PY) -m benchmarks.run --skip-kernels

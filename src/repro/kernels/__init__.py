@@ -6,8 +6,8 @@ ref.py            : pure-jnp oracles
 
 Kernels cover the compute hot-spots the paper optimizes: INT8
 weight-stationary GEMM (CIM-MXU mode), decode-GEMV attention, prefill
-flash attention, online softmax [27], and the SSD chunk scan for the
-SSM/hybrid assigned architectures.
+flash attention, and the SSD chunk scan for the SSM/hybrid assigned
+architectures.
 
 Fused INT8 epilogue pipeline (QuantPlan execution)
 --------------------------------------------------
@@ -66,8 +66,7 @@ tests pin grouped == looped bit-for-bit).  The grouped kernels take an
 optional scalar-prefetched ``counts`` skip list: zero-capacity experts
 (no tokens routed this step) run no MXU dot products in their grid
 cells instead of streaming all-zero rows, bit-identically.  The serving
-engine's ``quant_plan=`` turns it on for the decode path
-(``quantize_mlp=True`` remains as a deprecated MLP-only shim).
+engine's ``quant_plan=`` turns it on for the decode path.
 
 Tensor parallelism: under a model-axis sharding context the quantized
 apply sites shard_map these same kernels per device (repro.quant.tp) —

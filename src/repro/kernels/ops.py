@@ -22,7 +22,6 @@ from . import decode_attention as _da
 from .decode_attention import decode_attention as _decode_kernel
 from .decode_attention import decode_attention_splitkv as _decode_splitkv
 from .flash_attention import flash_attention as _flash_kernel
-from .online_softmax import online_softmax as _softmax_kernel
 from .ssd_scan import ssd_scan as _ssd_kernel
 
 
@@ -506,18 +505,11 @@ def decode_attention_splitkv(q, k, v, pos, q_pos, k_scale=None, v_scale=None,
 
 
 # ---------------------------------------------------------------------------
-# SSD scan / softmax
+# SSD scan
 # ---------------------------------------------------------------------------
 def ssd_scan(x, log_a, b, c, chunk=128, interpret: bool | None = None):
     interpret = _on_cpu() if interpret is None else interpret
     return _ssd_kernel(x, log_a, b, c, chunk=chunk, interpret=interpret)
-
-
-def online_softmax(x, block_r=256, block_c=2048,
-                   interpret: bool | None = None):
-    interpret = _on_cpu() if interpret is None else interpret
-    return _softmax_kernel(x, block_r=block_r, block_c=block_c,
-                           interpret=interpret)
 
 
 # re-export oracles for convenience

@@ -207,10 +207,6 @@ def ssd_scan_ref(x, log_a, b, c):
     return ys.swapaxes(0, 1), h
 
 
-def online_softmax_ref(x):
-    return jax.nn.softmax(x.astype(jnp.float32), axis=-1).astype(x.dtype)
-
-
 def mla_decode_paged_ref(q_lat, q_rope, latent_pages, c_scale_pages,
                          r_scale_pages, block_tables, q_pos, layer,
                          scale: float):

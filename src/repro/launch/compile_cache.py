@@ -1,4 +1,4 @@
-"""Persistent XLA compilation cache for the launchers and benchmarks.
+"""Persistent XLA compilation cache for the launchers and ``bench/``.
 
 Compiling the serving steps at published widths takes most of a cold
 run, and every process (and every call on a fresh chip host) starts

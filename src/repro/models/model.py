@@ -608,19 +608,6 @@ class Model:
                 qparams, make_shardings(mesh, qparams, axes, rules))
         return qparams
 
-    def quantize_mlps(self, params):
-        """Deprecated PR 1 entry point: MLP-only quantization.  Use
-        :meth:`quantize` with ``QuantPlan.mlp_only()`` (or the default
-        full plan) instead."""
-        import warnings
-
-        from repro.quant.plan import QuantPlan
-        warnings.warn(
-            "Model.quantize_mlps is deprecated; use "
-            "Model.quantize(params, QuantPlan.mlp_only())",
-            DeprecationWarning, stacklevel=2)
-        return self.quantize(params, QuantPlan.mlp_only())
-
     # -- caches ---------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int, kv_dtype=None):
         """``kv_dtype="int8"`` overrides ``cfg.kv_cache_dtype`` — the

@@ -20,9 +20,8 @@ one registry can span soak after soak with per-phase snapshots.
 Quantiles from fixed buckets are estimates (linear interpolation inside
 the covering bucket, clamped to the observed min/max); the exported
 ``sum``/``count`` are exact.  :func:`quantile_from_counts` is the one
-shared implementation — ``benchmarks/bench_serving.py`` and
-``tools/obs_report.py`` both call it, so a reported p50/p99 always means
-the same computation.
+shared implementation — :class:`Histogram` and ``tools/obs_report.py``
+both call it, so a reported p50/p99 always means the same computation.
 """
 from __future__ import annotations
 

@@ -136,8 +136,7 @@ class QuantPlan:
 
     @classmethod
     def mlp_only(cls) -> "QuantPlan":
-        """PR 1 behaviour: only dense-FFN MLPs quantized (the
-        ``quantize_mlp=True`` deprecation shim maps here)."""
+        """Only dense-FFN MLPs quantized."""
         return cls(mlp=True, attn_qkv=False, attn_out=False,
                    attn_kv=False, moe_experts=False, adaln=False,
                    mla_proj=False, mla_out=False)

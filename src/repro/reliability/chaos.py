@@ -9,8 +9,8 @@ A :class:`ChaosMonkey` attaches to a live engine and, fully seeded,
 * occasionally corrupts fetched logits with a NaN through the engine's
   ``fault_hook`` — the trigger for the non-finite health-check path.
 
-:func:`chaos_soak` is the shared soak loop (tests/test_reliability.py
-and benchmarks/bench_resilience.py): submit a workload, unleash the
+:func:`chaos_soak` is the soak loop of tests/test_reliability.py
+(``make test-chaos``): submit a workload, unleash the
 monkey at a swept bit-error rate, and audit the engine invariants —
 every request terminal, slots freed, token conservation, monotone
 stats, no hangs.  Everything is replayable bit-for-bit from the seeds.

@@ -108,7 +108,7 @@ class EngineStats:
 class ServingEngine:
     def __init__(self, model, params, n_slots: int = 4,
                  max_len: int = 512, prefill_bucket: int = 64,
-                 quant_plan=None, quantize_mlp: bool = False,
+                 quant_plan=None,
                  mesh=None, rules=None, max_queue: Optional[int] = None,
                  degraded: bool = False, health_checks: bool = True,
                  fault_hook: Optional[Callable] = None, clock=None,
@@ -150,17 +150,6 @@ class ServingEngine:
         self.model = model
         self.mesh = mesh
         self.rules = rules
-        if quantize_mlp:
-            # Deprecated PR 1 flag; maps to the MLP-only QuantPlan.
-            import warnings
-
-            from repro.quant import QuantPlan
-            warnings.warn(
-                "ServingEngine(quantize_mlp=True) is deprecated; pass "
-                "quant_plan=QuantPlan.mlp_only() (or QuantPlan.full())",
-                DeprecationWarning, stacklevel=2)
-            if quant_plan is None:
-                quant_plan = QuantPlan.mlp_only()
         if quant_plan is not None:
             # INT8 decode path (the paper's CIM serving mode): every
             # plan-covered weight matmul — attention QKV/out-projection,

@@ -577,32 +577,3 @@ class TestSSDScan:
         np.testing.assert_allclose(
             np.asarray(h_k.reshape(B, H, P, N)), np.asarray(h_m),
             rtol=2e-4, atol=2e-4)
-
-
-# ---------------------------------------------------------------------------
-# online_softmax
-# ---------------------------------------------------------------------------
-class TestOnlineSoftmax:
-    @pytest.mark.parametrize("r,c", [(256, 1024), (512, 512), (256, 4096)])
-    def test_vs_ref(self, r, c):
-        x = jax.random.normal(KEY, (r, c), jnp.float32) * 4
-        out = ops.online_softmax(x, block_r=128, block_c=1024,
-                                 interpret=True)
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(ref.online_softmax_ref(x)),
-                                   rtol=2e-5, atol=2e-6)
-
-    def test_extreme_values_stable(self):
-        x = jnp.array([[1e4, -1e4, 0.0, 1e4]] * 256, jnp.float32)
-        out = ops.online_softmax(x, interpret=True)
-        assert bool(jnp.isfinite(out).all())
-        np.testing.assert_allclose(np.asarray(jnp.sum(out, -1)),
-                                   np.ones(256), rtol=1e-5)
-
-    @given(scale=st.floats(0.1, 50.0))
-    @settings(max_examples=10, deadline=None)
-    def test_rows_sum_to_one(self, scale):
-        x = jax.random.normal(KEY, (128, 512), jnp.float32) * scale
-        out = ops.online_softmax(x, block_r=64, block_c=256, interpret=True)
-        np.testing.assert_allclose(np.asarray(jnp.sum(out, -1)),
-                                   np.ones(128), rtol=1e-4)

@@ -424,16 +424,6 @@ class TestQuantPlan:
         assert QuantPlan.none().layer_table(m.groups)[0]["fused"] == []
         assert "int8[" in QuantPlan.full().describe(m.groups)
 
-    def test_quantize_mlps_shim_warns_and_matches(self):
-        m, params = self._model()
-        with pytest.warns(DeprecationWarning):
-            shim = m.quantize_mlps(params)
-        assert plan_is_applied(m.groups, shim, QuantPlan.mlp_only())
-        x = {"inputs": jnp.ones((1, 4), jnp.int32)}
-        a, _, _ = m.forward(m.quantize(params, QuantPlan.mlp_only()), x)
-        b, _, _ = m.forward(shim, x)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b))
-
     def test_full_plan_decode_matches_manifest(self):
         """Acceptance bar: one decode step of a dense attention+MLP block
         executes exactly the manifest's dispatch schedule (6 fused Pallas

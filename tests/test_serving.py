@@ -206,14 +206,6 @@ class TestServingEngine:
         eng.run_until_done(max_iters=20)
         assert len(ok.generated) == 3
 
-    def test_quantize_mlp_flag_shim(self, small_model):
-        cfg, m, params = small_model
-        with pytest.warns(DeprecationWarning):
-            eng = ServingEngine(m, params, n_slots=1, max_len=32,
-                                prefill_bucket=4, quantize_mlp=True)
-        from repro.quant import plan_is_applied
-        assert plan_is_applied(m.groups, eng.params, QuantPlan.mlp_only())
-
 
 # ---------------------------------------------------------------------------
 # block allocator: property-style invariants
@@ -1011,20 +1003,55 @@ class TestCarriedPools:
 # ---------------------------------------------------------------------------
 # traffic harness
 # ---------------------------------------------------------------------------
-class TestTrafficHarness:
-    def _setup(self):
-        import sys
-        from pathlib import Path
-        sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-        from benchmarks.bench_serving import (StaticBatchEngine,
-                                              make_workload, run_traffic)
-        return make_workload, run_traffic, StaticBatchEngine
+def make_workload(n, load, seed, vocab, max_prompt=24, max_out=8):
+    """Seeded Poisson request stream: exponential inter-arrival gaps of
+    mean ``1/load`` engine steps, uniform prompt/output lengths.
+    Returns ``[(arrival_step, Request)]``."""
+    rng = np.random.default_rng(seed)
+    t = 0.0
+    out = []
+    for i in range(n):
+        t += rng.exponential(1.0 / load)
+        L = int(rng.integers(1, max_prompt + 1))
+        out.append((int(t), Request(
+            uid=i, prompt=rng.integers(1, vocab, L).astype(np.int32),
+            max_new_tokens=int(rng.integers(2, max_out + 1)), seed=7)))
+    return out
 
+
+def run_traffic(engine, workload, tick):
+    """Submit ``workload`` on its arrival schedule, stepping the engine
+    once per simulated step until everything drains.  ``tick`` is the
+    mutable step counter behind the engine's injected clock."""
+    pending = list(workload)
+    while pending or engine.pending():
+        while pending and pending[0][0] <= tick[0]:
+            engine.submit(pending.pop(0)[1])
+        engine.step()
+        tick[0] += 1
+        assert tick[0] <= 200_000, "traffic run did not drain"
+    ok = [r for _, r in workload if r.status is RequestStatus.OK]
+    return {"steps": tick[0], "completed": len(ok),
+            "goodput": sum(len(r.generated) for r in ok) / max(1, tick[0]),
+            "generations": {r.uid: r.generated for _, r in workload}}
+
+
+class StaticBatchEngine(PagedServingEngine):
+    """Head-of-line static batching over the same paged cache: admission
+    only when every slot is free, so the batch advances in lockstep and
+    drains fully before the next batch starts."""
+
+    def _admit(self, now):
+        if any(r is not None for r in self.slot_req):
+            return
+        super()._admit(now)
+
+
+class TestTrafficHarness:
     def test_deterministic_and_conserves_tokens(self, small_model):
         """Fixed seed => identical metrics and generations across runs,
         and completed-token conservation: every OK request carries
         exactly max_new_tokens tokens, goodput * steps sums them."""
-        make_workload, run_traffic, _ = self._setup()
         cfg, m, params = small_model
         results = []
         for _ in range(2):
@@ -1035,11 +1062,9 @@ class TestTrafficHarness:
                     block_size=8, prefill_chunk=16,
                     clock=lambda: float(tick[0]))
                 wl = make_workload(10, load=1.0, seed=17, vocab=cfg.vocab)
-                metrics = run_traffic(eng, wl, tick)
-            metrics.pop("us_per_step")       # the one wall-clock field
-            results.append((metrics, [r.generated for _, r in wl]))
+                results.append(run_traffic(eng, wl, tick))
         assert results[0] == results[1]
-        metrics, _ = results[0]
+        metrics = results[0]
         wl_reqs = [r for _, r in make_workload(10, load=1.0, seed=17,
                                                vocab=cfg.vocab)]
         assert metrics["completed"] == 10
@@ -1051,7 +1076,6 @@ class TestTrafficHarness:
         both, continuously-batched serving and head-of-line static
         batching produce bitwise-identical generations per request —
         there is no cross-row pollution through the shared pools."""
-        make_workload, run_traffic, StaticBatchEngine = self._setup()
         cfg, m, params = small_model
         gens = []
         for build in (PagedServingEngine, StaticBatchEngine):
@@ -1063,8 +1087,8 @@ class TestTrafficHarness:
                 wl = make_workload(8, load=2.0, seed=23, vocab=cfg.vocab)
                 metrics = run_traffic(eng, wl, tick)
             assert metrics["completed"] == 8
-            assert metrics["preemptions"] == 0
-            gens.append({r.uid: r.generated for _, r in wl})
+            assert eng.stats.preemptions == 0
+            gens.append(metrics["generations"])
         assert gens[0] == gens[1]
 
 

@@ -13,7 +13,7 @@ One repo-specific rule runs on **every** invocation, with or without
 ruff (ruff is not configured for it here):
 
 - T201  ``print(...)`` call inside ``src/repro/`` — library code must
-  not write to stdout (stray prints corrupt machine-read benchmark CSV
+  not write to stdout (stray prints corrupt machine-read benchmark
   and report output); the launch CLIs route terminal output through
   ``repro.launch.console.emit``.
 
@@ -22,7 +22,7 @@ line suppresses a finding, matching ruff's semantics closely enough
 that the two paths agree on this tree.
 
 Usage: PYTHONPATH=src python tools/lint.py [paths...]  (default: src
-tools benchmarks tests)
+tools tests)
 """
 from __future__ import annotations
 
@@ -32,7 +32,7 @@ import shutil
 import subprocess
 import sys
 
-DEFAULT_PATHS = ("src", "tools", "benchmarks", "tests")
+DEFAULT_PATHS = ("src", "tools", "tests")
 
 # Library tree where the T201 no-print rule applies (the launch CLIs
 # inside it use repro.launch.console.emit instead).

@@ -4,11 +4,11 @@
     PYTHONPATH=src python tools/obs_report.py snap.json [--requests N]
 
 The input is the dict :meth:`repro.obs.Observability.snapshot` produces
-(e.g. saved by ``benchmarks/bench_serving.py --snapshot PATH``): metric
-families under ``metrics``, per-request span summaries under
-``requests``.  The report shows non-zero counters and gauges, histogram
-p50/p99/mean via the same shared quantile implementation the benchmarks
-use (:func:`repro.obs.quantile_from_counts`), the energy split by
+(saved with ``json.dump(obs.snapshot(), f)``): metric families under
+``metrics``, per-request span summaries under ``requests``.  The report
+shows non-zero counters and gauges, histogram p50/p99/mean via the same
+shared quantile implementation the registry uses
+(:func:`repro.obs.quantile_from_counts`), the energy split by
 component, and the top-energy request spans.
 
 Everything here is read-side formatting over the snapshot dict; the
